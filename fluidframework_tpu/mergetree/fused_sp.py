@@ -38,10 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import pallas_apply as pa
 from .oppack import PackedOps

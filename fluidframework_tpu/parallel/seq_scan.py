@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def sharded_cumsum(x: jnp.ndarray, mesh: Mesh, axis_name: str = "sp",

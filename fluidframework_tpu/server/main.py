@@ -357,6 +357,10 @@ def build_worker(cfg: dict, stages: List[str]):
 def run_worker(cfg: dict, stages: List[str]) -> None:
     from ..telemetry import tracing
 
+    if "tpu-deli" in stages:
+        from ..core.platform import enable_compile_cache
+
+        enable_compile_cache()
     wcfg = cfg.get("worker", {})
     # Fleet identity BEFORE any span records: every span this process
     # exports carries the name the observatory joins timelines by.

@@ -20,7 +20,8 @@ from ..mergetree.pallas_ops import summary_lengths
 from . import ticket_kernel as tk
 
 
-def make_full_step(sp_shards: int = 1, fused_apply: bool = False):
+def make_full_step(sp_shards: int = 1, fused_apply: bool = False,
+                   mesh=None):
     """Build the fused pipeline step for a given sequence-parallel factor:
     with sp_shards > 1 the merge kernel's visibility prefix sums use the
     two-level collective-scan formulation (kernel._cumsum_sp), so a
@@ -34,6 +35,10 @@ def make_full_step(sp_shards: int = 1, fused_apply: bool = False):
     fused formulation with sequence-axis sharding (mergetree/fused_sp.py):
     per-shard lane tiles with two-level collective prefix sums, so long
     documents and the flagship kernel are no longer mutually exclusive.
+
+    mesh: the (dp, sp) mesh the inputs are placed on. The summary
+    reduction's Pallas kernel runs per shard on it (GSPMD cannot partition
+    a Mosaic kernel), so on a TPU a sharded step needs it.
 
     Naming note: this flag is the capacity-gated KERNEL experiment bench
     drives (stamped `fused_apply_kernel_exp` in BENCH records since
@@ -64,9 +69,9 @@ def make_full_step(sp_shards: int = 1, fused_apply: bool = False):
             # the scan×vmap kernel covers them.
             mstate = kernel._scan_ops(mstate, ops2, batched=True,
                                       sp_shards=sp_shards)
-        # Summary-length reduction: fused Pallas pass on TPU, jnp elsewhere
-        # (mergetree/pallas_ops.py; semantics == visibility(s, s.seq, ...)).
-        total_len = summary_lengths(mstate)
+        # Summary-length reduction (semantics == visibility(s, s.seq, ...)):
+        # the fused Pallas pass on the TPU (mergetree/pallas_ops.py).
+        total_len = summary_lengths(mstate, mesh=mesh)
         return tstate, mstate, ticketed, total_len
 
     return full_step

@@ -9,7 +9,7 @@ reported in µs/op) plus the internal perf counters surfaced by
 
 Prints one row per probe: name, ops, total ms, µs/op. The device-kernel
 probe reports throughput on whatever backend is active (set
-BENCH_PLATFORM=cpu to force the host backend)."""
+JAX_PLATFORMS=cpu to force the host backend)."""
 
 from __future__ import annotations
 

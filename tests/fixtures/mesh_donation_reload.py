@@ -5,8 +5,8 @@ donating jit (``donate_argnums=(0,)``) dispatched on dp-mesh-sharded
 state. This is what the real warm-reload corruption looked like — on
 jax 0.4.37 a donated dp-sharded lane-state plane reloaded from the
 persistent XLA compilation cache returns corrupt lane planes (repro:
-tests/test_mesh_serving.py warm vs cold after clearing
-``/tmp/fluid_tpu_xla_cache``; docs/serving_pipeline.md R6). The real
+tests/test_mesh_serving.py warm vs cold after clearing the compile
+cache; docs/serving_pipeline.md R6). The real
 ``tpu_sequencer`` selects the non-donating ``_keep`` dispatch whenever
 a mesh is present (``donate_lane_states = mesh is None``); this fixture
 is what the code would look like if someone "optimized" that back into

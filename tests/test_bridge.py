@@ -8,7 +8,7 @@ import pytest
 
 grpc = pytest.importorskip("grpc")
 
-from bench import gen_traces  # noqa: E402
+from fluidframework_tpu.testing.traces import gen_traces  # noqa: E402
 from fluidframework_tpu.mergetree.oppack import PackedOps  # noqa: E402
 from fluidframework_tpu.mergetree.state import make_state  # noqa: E402
 from fluidframework_tpu.server import ticket_kernel as tk  # noqa: E402

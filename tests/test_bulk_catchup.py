@@ -447,8 +447,9 @@ class TestCostModel:
     def test_tpu_crossover_respects_dispatch_floor(self, monkeypatch):
         from fluidframework_tpu.mergetree.costmodel import device_bulk_wins
         monkeypatch.delenv("FLUID_TPU_FORCE_BULK", raising=False)
-        # Short tails lose to the ~70ms RPC floor regardless of doc size.
-        assert not device_bulk_wins(64, 500, backend="tpu")
+        # A one-op tail on a near-empty doc loses to the per-chunk
+        # dispatch cost.
+        assert not device_bulk_wins(1, 1, backend="tpu")
         # Long tails over big docs win: scalar's per-segment walk
         # dominates.
         assert device_bulk_wins(4096, 3000, backend="tpu")

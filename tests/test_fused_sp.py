@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import gen_traces
+from fluidframework_tpu.testing.traces import gen_traces
 from fluidframework_tpu.mergetree import fused_sp, kernel, pallas_apply
 from fluidframework_tpu.mergetree.host import OpBuilder
 from fluidframework_tpu.mergetree.oppack import PackedOps, pack_ops
@@ -182,9 +182,12 @@ class TestPipelineFusedSp:
         np.testing.assert_array_equal(np.asarray(len_scan),
                                       np.asarray(len_fsp))
 
-    def test_full_step_fused_sp_on_sharded_mesh_inputs(self):
+    @pytest.mark.parametrize("pass_mesh", [False, True])
+    def test_full_step_fused_sp_on_sharded_mesh_inputs(self, pass_mesh):
         """The composed step executes under real dp×sp placements — the
-        dryrun_multichip configuration (GSPMD inserts the collectives)."""
+        dryrun_multichip configuration (GSPMD inserts the collectives).
+        With the mesh passed, the summary runs per shard (shard_map), as
+        it must on a TPU."""
         from fluidframework_tpu.server.pipeline import make_full_step
         from fluidframework_tpu.server import ticket_kernel as tk
 
@@ -203,7 +206,8 @@ class TestPipelineFusedSp:
         mstate_s = shard_docs(mesh, mstate, seq_sharded=True)
         raw_s = shard_docs(mesh, raw)
         ops_s = shard_docs(mesh, ops)
-        out = jax.jit(make_full_step(sp_shards=2, fused_apply=True))(
+        out = jax.jit(make_full_step(
+            sp_shards=2, fused_apply=True, mesh=mesh if pass_mesh else None))(
             tstate_s, mstate_s, raw_s, ops_s)
         assert_states_equal(ref[1], out[1])
         np.testing.assert_array_equal(np.asarray(ref[3]),

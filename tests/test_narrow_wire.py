@@ -40,7 +40,7 @@ def _traced_state(docs=32, n_ops=16, capacity=64, seed=11, anno_slots=2):
     kernel compile, which dominates this file's runtime on CPU."""
     key = (docs, n_ops, capacity, seed, anno_slots)
     if key not in _STATE_CACHE:
-        from bench import gen_traces
+        from fluidframework_tpu.testing.traces import gen_traces
 
         cols = gen_traces(docs, n_ops, seed=seed)
         ops = PackedOps(

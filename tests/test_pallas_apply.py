@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import gen_traces
+from fluidframework_tpu.testing.traces import gen_traces
 from fluidframework_tpu.mergetree import kernel, pallas_apply
 from fluidframework_tpu.mergetree.host import OpBuilder
 from fluidframework_tpu.mergetree.oppack import PackedOps, pack_ops

@@ -4,16 +4,19 @@ the CPU backend; the real Mosaic path engages on TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from fluidframework_tpu.mergetree import kernel
 from fluidframework_tpu.mergetree.oppack import PackedOps
 from fluidframework_tpu.mergetree.pallas_ops import (_jnp_summary_lengths,
+                                                     _summary_cols,
                                                      summary_lengths)
 from fluidframework_tpu.mergetree.state import make_state
+from fluidframework_tpu.parallel.mesh import make_mesh, shard_docs
 
 
 def batched_state_after_ops(batch=5, capacity=64, steps=30, seed=0):
-    from bench import gen_traces
+    from fluidframework_tpu.testing.traces import gen_traces
     cols = gen_traces(batch, steps, seed=seed)
     ops = PackedOps(**{f: jnp.asarray(cols[f]) for f in PackedOps._fields})
     state = make_state(capacity, 1, batch=batch)
@@ -23,7 +26,7 @@ def batched_state_after_ops(batch=5, capacity=64, steps=30, seed=0):
 class TestSummaryLengths:
     def test_interpret_matches_jnp(self):
         state = batched_state_after_ops()
-        ref = np.asarray(_jnp_summary_lengths(state))
+        ref = np.asarray(_jnp_summary_lengths(*_summary_cols(state)))
         out = np.asarray(summary_lengths(state, interpret=True))
         np.testing.assert_array_equal(out, ref)
 
@@ -45,5 +48,20 @@ class TestSummaryLengths:
     def test_dispatch_cpu_uses_jnp(self):
         state = batched_state_after_ops(batch=3)
         out = np.asarray(summary_lengths(state))  # cpu backend -> jnp path
-        ref = np.asarray(_jnp_summary_lengths(state))
+        ref = np.asarray(_jnp_summary_lengths(*_summary_cols(state)))
         np.testing.assert_array_equal(out, ref)
+
+    @pytest.mark.parametrize("dp,sp,interpret", [(2, 2, False), (2, 2, True),
+                                                 (1, 4, True), (4, 1, True)])
+    def test_sharded_matches_one_device(self, dp, sp, interpret):
+        """On a mesh each shard reduces its own capacity slice (the live
+        count shifted to shard-local slots) and the sums meet over 'sp':
+        the same totals as one device, Pallas body or jnp."""
+        state = batched_state_after_ops(batch=8, capacity=64, steps=40,
+                                        seed=5)
+        ref = np.asarray(_jnp_summary_lengths(*_summary_cols(state)))
+        mesh = make_mesh(dp=dp, sp=sp, devices=jax.devices()[:dp * sp])
+        placed = shard_docs(mesh, state, seq_sharded=True)
+        out = jax.jit(lambda s: summary_lengths(s, interpret=interpret,
+                                                mesh=mesh))(placed)
+        np.testing.assert_array_equal(np.asarray(out), ref)

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import gen_traces
+from fluidframework_tpu.testing.traces import gen_traces
 from fluidframework_tpu.mergetree import kernel
 from fluidframework_tpu.mergetree.oppack import PackedOps
 from fluidframework_tpu.mergetree.state import make_state
