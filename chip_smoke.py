@@ -52,37 +52,23 @@ def emit(record: dict) -> None:
     print(json.dumps(record), flush=True)
 
 
-class CompileClock:
-    """Backend-compile seconds and persistent-cache hits/misses, read off
-    JAX's monitoring events (compile seconds include cache loads)."""
+def compile_mark():
+    """Where the compile ledger's counters stand (the program's one JAX
+    compile listener: backend compiles, persistent-cache loads included,
+    and cache hits/misses)."""
+    from fluidframework_tpu.telemetry import counters
 
-    def __init__(self):
-        from jax import monitoring
+    return (time.perf_counter(), counters.get("compile.backend_ms"),
+            counters.get("compile.cache_hits"),
+            counters.get("compile.cache_misses"))
 
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event, duration_secs, **_kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration_secs
-
-    def _on_event(self, event, **_kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def mark(self):
-        return (time.perf_counter(), self.seconds, self.hits, self.misses)
-
-    def since(self, mark) -> dict:
-        return {"seconds": time.perf_counter() - mark[0],
-                "compile_s": self.seconds - mark[1],
-                "cache_hits": self.hits - mark[2],
-                "cache_misses": self.misses - mark[3]}
+def compile_since(mark) -> dict:
+    now = compile_mark()
+    return {"seconds": now[0] - mark[0],
+            "compile_s": (now[1] - mark[1]) / 1e3,
+            "cache_hits": int(now[2] - mark[2]),
+            "cache_misses": int(now[3] - mark[3])}
 
 
 # ---------------------------------------------------------------------------
@@ -409,14 +395,17 @@ def _sample_docs(docs: int, seed: int):
                   + rng.sample(plain, min(SAMPLE, len(plain))))
 
 
-def _serving_faults() -> dict:
+def _serving_faults(base=None) -> dict:
+    """Fallback and degrade counts since the ``base`` snapshot."""
     from fluidframework_tpu.telemetry import counters
 
     snap = counters.snapshot()
-    faults = {name: snap.get(name, 0.0) for name in (
+    base = base or {}
+    faults = {name: snap.get(name, 0.0) - base.get(name, 0.0) for name in (
         "serving.megakernel_fallbacks", "serving.burst_fallbacks",
         "sequencer.fused_degrades")}
-    faults.update({k: v for k, v in snap.items() if v and (
+    faults.update({k: v - base.get(k, 0.0) for k, v in snap.items()
+                   if v != base.get(k, 0.0) and (
         k.startswith("swallowed.pallas.") or k in (
             "swallowed.oppack.native_fallback",
             "swallowed.sequencer.pump_unavailable",
@@ -438,16 +427,19 @@ def served_phase(docs: int = DOCS, seed: int = SEED,
     waves = served_traffic(docs, seed)
     build_s = time.perf_counter() - t0
 
-    counters.reset()
+    # Deltas, not a reset: the compile counters run across phases.
+    base = counters.snapshot()
     t0 = time.perf_counter()
     lam, streams = serve(waves)
     serve_s = time.perf_counter() - t0
-    rings = counters.get("serving.megakernel_rings")
-    ring_windows = counters.get("serving.megakernel_windows")
+    rings = counters.get("serving.megakernel_rings") \
+        - base.get("serving.megakernel_rings", 0.0)
+    ring_windows = counters.get("serving.megakernel_windows") \
+        - base.get("serving.megakernel_windows", 0.0)
     mode = lam._mega_fused_mode()
     check(mode == expect_fused,
           f"megakernel op-phase mode {mode!r}, expected {expect_fused!r}")
-    faults = _serving_faults()
+    faults = _serving_faults(base)
     check(not any(faults.values()), f"fallbacks fired: {faults}")
     check(rings > 0, "no serving megakernel ring was dispatched")
 
@@ -704,7 +696,10 @@ def main(argv=None) -> int:
     os.environ.pop("FLUID_TPU_FORCE_BULK", None)
     try:
         device = device_phase(args.chips)
-        clock = CompileClock()
+        from fluidframework_tpu.telemetry.compile_ledger import \
+            install_jax_listener
+
+        install_jax_listener()
         emit({"phase": "device", "ok": True, **device})
         if args.chips == 4:
             phases = [("mesh_served", mesh_served_phase),
@@ -713,15 +708,15 @@ def main(argv=None) -> int:
             phases = [("kernel", kernel_phase), ("served", served_phase),
                       ("loader", loader_phase)]
         for name, run in phases:
-            mark = clock.mark()
+            mark = compile_mark()
             try:
                 result = run()
             except Exception as err:
                 emit({"phase": name, "ok": False,
                       "error": f"{type(err).__name__}: {err}"[:2000],
-                      **clock.since(mark)})
+                      **compile_since(mark)})
                 raise
-            emit({"phase": name, "ok": True, **clock.since(mark), **result})
+            emit({"phase": name, "ok": True, **compile_since(mark), **result})
     except Exception as err:  # noqa: BLE001 — any failure fails the smoke
         print(f"chip_smoke: FAILED: {type(err).__name__}: {err}",
               file=sys.stderr)

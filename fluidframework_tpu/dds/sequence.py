@@ -19,6 +19,7 @@ from ..mergetree.constants import SEG_MARKER, SNAPSHOT_CHUNK_SIZE
 from ..mergetree.costmodel import device_bulk_wins
 from ..mergetree.oracle import REF_SLIDE_ON_REMOVE, LocalReference
 from ..protocol.summary import SummaryTree
+from ..telemetry import tracing
 from .shared_object import SharedObject
 
 _interval_uid = itertools.count(1)
@@ -269,6 +270,13 @@ class SharedSegmentSequence(SharedObject):
         self._client = value
 
     def _materialize_body(self) -> None:
+        # A root: a late client's first touch realizes the body it
+        # loaded lazily, a step of its time to text after the load.
+        with tracing.span("sequence.realize", root=True,
+                          chunks=self._lazy[1]["chunkCount"]):
+            self._materialize_body_traced()
+
+    def _materialize_body_traced(self) -> None:
         tree, header = self._lazy
         self._lazy = None
         segments: List[dict] = []

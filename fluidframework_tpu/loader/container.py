@@ -19,6 +19,7 @@ from ..protocol.protocol_handler import ProtocolOpHandler, ProtocolState
 from ..protocol.summary import SummaryTree
 from ..runtime.container_runtime import ContainerRuntime
 from ..runtime.datastore_runtime import ChannelRegistry
+from ..telemetry import tracing
 from .delta_manager import DeltaManager
 from .drivers.base import IDocumentService, IDocumentServiceFactory
 
@@ -109,26 +110,53 @@ class Container(TypedEventEmitter):
 
         client_details={"mode": "read"} loads a READ-ONLY observer: it
         follows the live op/signal streams but never joins the quorum,
-        never holds back the MSN, and never submits."""
-        container = Container(document_id, service, registry, code_loader,
-                              client_details)
-        try:
-            summary, artifact = container.storage.get_catchup()
-        except Exception:  # noqa: BLE001 — a dead read tier must not fail loads
-            from ..telemetry.counters import record_swallow
-            record_swallow("container.get_catchup")
-            summary, artifact = container.storage.get_summary(), None
-        if summary is None:
-            raise FileNotFoundError(f"document {document_id!r} has no summary")
-        container._load_from_summary(summary)
-        versions = container.storage.get_versions(1)
-        container._last_summary_handle = versions[0] if versions else None
-        if artifact is not None:
-            container._try_adopt_catchup(artifact)
-        container.attached = True
-        container._instantiate_code(existing=True)
-        container.connect()
-        return container
+        never holds back the MSN, and never submits.
+
+        Traced as one ``load.resolve`` trace (its histogram always on):
+        ``load.fetch``, ``load.summary``, ``load.adopt`` and
+        ``load.connect`` are its steps, and the server-side stages they
+        reach (the read tier's refresh, the join op's pump) join it as
+        children."""
+        with tracing.span("load.resolve", root=True, hist="load.resolve",
+                          document=document_id):
+            container = Container(document_id, service, registry,
+                                  code_loader, client_details)
+            with tracing.span("load.fetch") as sp:
+                try:
+                    summary, artifact = container.storage.get_catchup()
+                except Exception:  # noqa: BLE001 — a dead read tier must not fail loads
+                    from ..telemetry.counters import record_swallow
+                    record_swallow("container.get_catchup")
+                    summary, artifact = container.storage.get_summary(), None
+                sp.set(artifact=artifact is not None,
+                       sha=(artifact or {}).get("summarySha"))
+            if summary is None:
+                raise FileNotFoundError(
+                    f"document {document_id!r} has no summary")
+            with tracing.span("load.summary"):
+                container._load_from_summary(summary)
+                versions = container.storage.get_versions(1)
+            container._last_summary_handle = versions[0] if versions \
+                else None
+            if artifact is not None:
+                with tracing.span("load.adopt") as sp:
+                    sp.set(adopted=container._try_adopt_catchup(artifact))
+                    # The narrow blobs: entry counts, text and base64
+                    # columns.
+                    blobs = [c[3] for c in artifact.get("channels", ())]
+                    sp.set(channels=len(blobs),
+                           entries=sum(int(b.get("n", 0)) for b in blobs),
+                           blob_bytes=sum(len(v) for b in blobs
+                                          for v in b.values()
+                                          if isinstance(v, str)))
+            container.attached = True
+            container._instantiate_code(existing=True)
+            with tracing.span("load.connect") as sp:
+                seq = container.protocol.sequence_number
+                container.connect()
+                sp.set(residue_ops=container.delta_manager
+                       .last_sequence_number - seq)
+            return container
 
     # -- code loading (web-code-loader + quorum "code" proposal) -----------
     def set_code_details(self, details: dict) -> None:

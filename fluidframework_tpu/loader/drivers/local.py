@@ -47,9 +47,13 @@ class LocalDocumentStorageService(IDocumentStorageService):
         # historian, the caching proxy, never to gitrest directly).
         # lazy: blob contents resolve on first access, so a lazy-loading
         # channel (sequence body chunks) defers their transfer entirely.
-        return self.server.historian.read_summary(
-            self.server.tenant_id, self.document_id, commit_sha=version,
-            lazy=True)
+        return self._read_summary(version)
+
+    def _read_summary(self, sha: Optional[str]):
+        with tracing.span("driver.read_summary", sha=sha):
+            return self.server.historian.read_summary(
+                self.server.tenant_id, self.document_id, commit_sha=sha,
+                lazy=True)
 
     def upload_summary(self, summary: SummaryTree,
                        parent: Optional[str] = None,
@@ -73,9 +77,7 @@ class LocalDocumentStorageService(IDocumentStorageService):
             # Load the EXACT summary the artifact was published against:
             # a client summary committed after the refresh would
             # otherwise race ahead of the delta's baseline.
-            summary = self.server.historian.read_summary(
-                self.server.tenant_id, self.document_id,
-                commit_sha=artifact["summarySha"], lazy=True)
+            summary = self._read_summary(artifact["summarySha"])
         if summary is None:
             summary = self.get_summary()
         return summary, artifact

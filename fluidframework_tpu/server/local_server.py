@@ -612,7 +612,11 @@ class LocalServer:
                    to_seq: Optional[int] = None) -> List[dict]:
         """Catch-up range query (alfred delta REST API over the scriptorium
         collection): ops with from_seq < seq <= to_seq, ordered."""
-        return query_deltas(self.deltas, document_id, from_seq, to_seq)
+        with tracing.span("server.get_deltas", document=document_id,
+                          from_seq=from_seq) as sp:
+            rows = query_deltas(self.deltas, document_id, from_seq, to_seq)
+            sp.set(rows=len(rows))
+            return rows
 
     def storage(self, document_id: str):
         return self.historian.store(self.tenant_id, document_id)

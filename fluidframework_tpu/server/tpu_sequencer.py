@@ -6351,9 +6351,6 @@ class TpuSequencerLambda(IPartitionLambda):
 
     def _catchup_snapshot_traced(self, only_docs: Optional[set],
                                  chunk_chars: int) -> Dict[str, dict]:
-        from ..mergetree.catchup import (pack_entries_narrow,
-                                         translate_entry_clients)
-
         self.drain()
         by_doc, unsupported = self.catchup_docs_supported()
         refresh: Dict[str, int] = {}  # doc -> gen this epoch covers
@@ -6370,10 +6367,23 @@ class TpuSequencerLambda(IPartitionLambda):
         if not refresh:
             return {}
         want = {k for d in refresh for k in by_doc[d]}
-        jobs, cached = self.merge.extract_dispatch(only=want,
-                                                   chunk_chars=chunk_chars)
-        increment("catchup.refresh_dispatches", len(jobs))
-        snaps = self.merge.extract_assemble(jobs, chunk_chars, cached)
+        with tracing.span("catchup.extract", docs=len(refresh)) as sp:
+            jobs, cached = self.merge.extract_dispatch(
+                only=want, chunk_chars=chunk_chars)
+            increment("catchup.refresh_dispatches", len(jobs))
+            snaps = self.merge.extract_assemble(jobs, chunk_chars, cached)
+            sp.set(dispatches=len(jobs))
+        with tracing.span("catchup.pack"):
+            return self._catchup_pack(refresh, by_doc, snaps)
+
+    def _catchup_pack(self, refresh: Dict[str, int],
+                      by_doc: Dict[str, List[tuple]],
+                      snaps: Dict[tuple, dict]) -> Dict[str, dict]:
+        """Per refreshed document: client fields translated to wire-table
+        indices and every channel's entries narrow-packed."""
+        from ..mergetree.catchup import (pack_entries_narrow,
+                                         translate_entry_clients)
+
         out: Dict[str, dict] = {}
         for doc_id, doc_gen in refresh.items():
             dl = self.docs[doc_id]

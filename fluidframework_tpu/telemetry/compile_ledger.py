@@ -42,6 +42,7 @@ import time
 from typing import Callable, Dict, Optional
 
 from . import counters as _counters
+from . import tracing as _tracing
 
 
 class _Entry:
@@ -236,12 +237,38 @@ class _Tracked:
 ledger = CompileLedger()
 
 # -- jax backend-compile listener (best effort) ------------------------------
-# jax.monitoring publishes duration events for backend compilation; when
-# the running jax exposes the hook, cumulative backend-compile wall time
-# accumulates into the compile.backend_ms counter (the ledger's per-call
-# attribution is the per-symbol view; this is the ground-truth total).
+# The program's one jax.monitoring compile listener (the ledger's per-call
+# attribution is the per-symbol view; this is the ground truth). Each
+# backend compile (a persistent-cache load included: it is a compile-path
+# stall too) counts compile.backend, adds its wall time to
+# compile.backend_ms, and lands in the flight recorder as a `compile`
+# span under the ambient span, so /trace says which stage compiled.
+# Persistent-cache lookups count compile.cache_hits / compile.cache_misses.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "compile.cache_hits",
+                 "/jax/compilation_cache/cache_misses": "compile.cache_misses"}
 _listener_installed = False
 _listener_lock = threading.Lock()
+
+
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    _counters.increment("compile.backend")
+    _counters.increment("compile.backend_ms", duration_secs * 1000.0)
+    parent = _tracing.current()
+    if parent is not None:
+        # The event fires as the compile returns: the span ends now.
+        t1 = time.perf_counter()
+        _tracing.record_span("compile", parent, t1 - duration_secs, t1,
+                             fun_name=str(kw.get("fun_name", "?")),
+                             seconds=duration_secs)
+
+
+def _on_event(event: str, **_kw) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        _counters.increment(name)
 
 
 def install_jax_listener() -> bool:
@@ -252,12 +279,8 @@ def install_jax_listener() -> bool:
         try:
             from jax import monitoring as _mon
 
-            def _on_duration(event: str, duration_secs: float, **_kw):
-                if "compile" in event:
-                    _counters.increment("compile.backend_ms",
-                                        duration_secs * 1000.0)
-
             _mon.register_event_duration_secs_listener(_on_duration)
+            _mon.register_event_listener(_on_event)
             _listener_installed = True
             return True
         except Exception:  # noqa: BLE001 — observatory is best-effort

@@ -21,7 +21,9 @@ outliers never escape the recorder just because the sampler skipped
 them.
 
 Kept dependency-free (stdlib only) so every layer — mergetree, loader,
-server — can import it without cycles, exactly like counters.py.
+server — can import it without cycles, exactly like counters.py. The
+profiler bridge (``configure(profiler=True)``) imports JAX when it is
+turned on, and not before.
 """
 
 from __future__ import annotations
@@ -99,6 +101,11 @@ class _Config:
 
 
 _cfg = _Config()
+# The profiler bridge: jax.profiler.TraceAnnotation while
+# configure(profiler=True) holds, else None. A span used as a context
+# manager then also annotates the profiler's host line, on the same clock
+# as the device ops.
+_annotation = None
 # Fleet identity: every exported span names the process that recorded
 # it, so the observatory's cross-process join can tell alfred's ingest
 # span from the tpu-deli worker's ticket span. Workers set this at
@@ -123,15 +130,27 @@ _epoch = time.time() - time.perf_counter()
 
 def configure(sample: Optional[int] = None,
               slow_ms: Optional[float] = None,
-              capacity: Optional[int] = None) -> None:
+              capacity: Optional[int] = None,
+              profiler: Optional[bool] = None) -> None:
     """Set the sampling rate (0 = tracing off, 1 = every op, N = 1-in-N),
-    the always-record slow threshold, and/or the recorder capacity."""
+    the always-record slow threshold, the recorder capacity, and/or the
+    profiler bridge: with ``profiler=True`` every span entered as a
+    context manager also holds a ``jax.profiler.TraceAnnotation`` named
+    ``"fluid." + name`` (JAX is imported here, only then)."""
+    global _annotation
     if sample is not None:
         _cfg.sample = int(sample)
     if slow_ms is not None:
         _cfg.slow_ms = float(slow_ms)
     if capacity is not None:
         recorder.resize(int(capacity))
+    if profiler is not None:
+        if profiler:
+            import jax.profiler
+
+            _annotation = jax.profiler.TraceAnnotation
+        else:
+            _annotation = None
 
 
 def enabled() -> bool:
@@ -301,7 +320,7 @@ class Span:
     """
 
     __slots__ = ("name", "ctx", "attrs", "hist", "_t0",
-                 "_done", "_token")
+                 "_done", "_token", "_note")
 
     def __init__(self, name: str, ctx: TraceContext,
                  hist: Optional[str] = None,
@@ -313,6 +332,7 @@ class Span:
         self._t0 = time.perf_counter()
         self._done = False
         self._token = None
+        self._note = None
 
     def set(self, **attrs) -> None:
         if self.attrs is None:
@@ -348,6 +368,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        if _annotation is not None:
+            self._note = _annotation("fluid." + self.name)
+            self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, _exc, _tb) -> None:
@@ -355,6 +378,9 @@ class Span:
             self.cancel()
         else:
             self.end()
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
 
 
 def current() -> Optional[TraceContext]:
@@ -555,9 +581,10 @@ def chrome_trace_json(spans: Optional[List[dict]] = None) -> str:
 
 def reset() -> None:
     """Test isolation only: drop recorded spans and disable tracing."""
-    global _process_name
+    global _process_name, _annotation
     _cfg.sample = 0
     _cfg.slow_ms = 50.0
+    _annotation = None
     recorder.resize(len(recorder._buf))
     _tls.op_ctx = None
     _process_name = None

@@ -37,7 +37,9 @@ ALLOWED: Dict[str, Set[str]] = {
     # queued-message types (the reference's librdkafka binding lives inside
     # the services package the same way).
     "native": {"core", "server"},
-    "dds": {"core", "protocol", "mergetree"},
+    # dds spans its own stages (a lazy body's realization), as mergetree's
+    # client spans its local edits.
+    "dds": {"core", "protocol", "mergetree", "telemetry"},
     "runtime": {"core", "protocol", "dds"},
     "server": {"core", "protocol", "mergetree", "native", "telemetry",
                "parallel"},

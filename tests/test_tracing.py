@@ -321,3 +321,198 @@ class TestEndToEndPipeline:
         assert "serving.flush" in snap
         assert SERVING_SUBSPANS <= set(snap)
         assert len(tracing.recorder) == self_spans_before  # no spans
+
+
+class TestOffPath:
+    def test_off_returns_the_shared_no_op(self):
+        assert not tracing.enabled()
+        assert tracing.span("x", root=True) is tracing.NULL_SPAN
+        assert tracing.span("x", parent=tracing.TraceContext("t", "s")) \
+            is tracing.NULL_SPAN
+        assert isinstance(tracing.span("x", root=True, hist="h"),
+                          tracing._HistTimer)
+        assert tracing._annotation is None
+
+    def test_telemetry_imports_no_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys\n"
+                "import fluidframework_tpu.telemetry\n"
+                "from fluidframework_tpu.telemetry import tracing\n"
+                "tracing.configure(sample=1)\n"
+                "with tracing.span('x', root=True):\n"
+                "    pass\n"
+                "print(sorted(m for m in sys.modules\n"
+                "             if m == 'jax' or m.startswith('jax.')))\n")
+        out = subprocess.run([sys.executable, "-c", code], check=True,
+                             capture_output=True, text=True, timeout=120)
+        assert out.stdout.strip() == "[]"
+
+
+def _host_events(path):
+    """{event name} on the host planes of the trace under ``path``."""
+    import glob
+    import os
+
+    import jax
+
+    files = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files
+    profile = jax.profiler.ProfileData.from_file(files[0])
+    return {e.name for plane in profile.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+class TestProfilerBridge:
+    def test_spans_land_on_the_profilers_host_line(self, tmp_path):
+        import jax
+
+        tracing.configure(sample=1, profiler=True)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tracing.span("bridge.outer", root=True):
+                with tracing.span("bridge.inner"):
+                    pass
+            ended = tracing.span("bridge.ended", root=True)
+            ended.end()
+        finally:
+            jax.profiler.stop_trace()
+        names = _host_events(str(tmp_path))
+        assert {"fluid.bridge.outer", "fluid.bridge.inner"} <= names
+        # A span ended by end() is not annotated after the fact.
+        assert "fluid.bridge.ended" not in names
+        assert {s["name"] for s in tracing.recorder.snapshot()} == {
+            "bridge.outer", "bridge.inner", "bridge.ended"}
+
+    def test_bridge_turns_off(self):
+        tracing.configure(sample=1, profiler=True)
+        assert tracing._annotation is not None
+        tracing.configure(profiler=False)
+        assert tracing._annotation is None
+        with tracing.span("x", root=True) as sp:
+            assert sp._note is None
+
+
+class TestReadPathTrace:
+    def _doc(self):
+        from fluidframework_tpu.dds.sequence import SharedString
+        from fluidframework_tpu.loader.container import Loader
+        from fluidframework_tpu.loader.drivers.local import (
+            LocalDocumentServiceFactory)
+        from fluidframework_tpu.server.local_server import TpuLocalServer
+
+        server = TpuLocalServer()
+        loader = Loader(LocalDocumentServiceFactory(server))
+        writer = loader.create_detached("doc")
+        text = writer.runtime.create_datastore("s").create_channel(
+            "t", SharedString.TYPE)
+        text.insert_text(0, "base")
+        writer.attach()
+        for i in range(40):
+            text.insert_text(text.get_length(), f"<{i}>")
+        server.pump()
+        return loader, text.get_text()
+
+    def test_resolve_is_one_trace_with_its_steps(self):
+        loader, want = self._doc()
+        tracing.configure(sample=1)
+        tracing.recorder.drain()
+        late = loader.resolve("doc")
+        assert late.runtime.get_datastore("s").get_channel("t") \
+            .get_text() == want
+        spans = tracing.recorder.drain()
+        roots = [s for s in spans if s["name"] == "load.resolve"]
+        assert len(roots) == 1 and roots[0]["parent_id"] is None
+        root = roots[0]
+        trace = [s for s in spans if s["trace_id"] == root["trace_id"]]
+        by_id = {s["span_id"]: s for s in trace}
+        children = {s["name"] for s in trace
+                    if s["parent_id"] == root["span_id"]}
+        assert {"load.fetch", "load.summary", "load.adopt",
+                "load.connect"} <= children
+        named = {s["name"]: s for s in trace}
+        refresh, extract = named["catchup.refresh"], named["catchup.extract"]
+        assert by_id[refresh["parent_id"]]["name"] == "load.fetch"
+        assert extract["parent_id"] == refresh["span_id"]
+        assert by_id[named["catchup.pack"]["parent_id"]] is refresh
+        assert {"catchup.get", "catchup.publish",
+                "server.get_deltas"} <= set(named)
+        assert named["load.adopt"]["attrs"]["adopted"] is True
+        assert named["load.adopt"]["attrs"]["entries"] > 0
+        assert named["load.fetch"]["attrs"]["artifact"] is True
+        assert named["load.connect"]["attrs"]["residue_ops"] >= 1
+        # The late client's first read realizes its body: a root.
+        assert any(s["name"] == "sequence.realize"
+                   and s["parent_id"] is None for s in spans)
+
+    def test_load_histogram_fills_with_tracing_off(self):
+        loader, _ = self._doc()
+        loader.resolve("doc")
+        assert "load.resolve" in counters.latency_snapshot()
+        assert len(tracing.recorder) == 0
+
+
+class TestCompileSpan:
+    def test_a_compile_inside_a_span_is_its_child(self):
+        import jax
+        import jax.numpy as jnp
+
+        from fluidframework_tpu.telemetry.compile_ledger import \
+            install_jax_listener
+
+        assert install_jax_listener()
+        tracing.configure(sample=1)
+        before = counters.get("compile.backend")
+        with tracing.span("stage", root=True):
+            parent = tracing.current()
+            # A function of its own: its first call compiles (or loads).
+            jax.jit(lambda x: x * 7 - 3)(jnp.arange(11)).block_until_ready()
+        assert counters.get("compile.backend") >= before + 1
+        compiles = [s for s in tracing.recorder.snapshot()
+                    if s["name"] == "compile"]
+        assert compiles
+        assert all(s["parent_id"] == parent.span_id
+                   and s["trace_id"] == parent.trace_id for s in compiles)
+        assert any("lambda" in s["attrs"]["fun_name"] for s in compiles)
+        assert all(s["attrs"]["seconds"] >= 0 for s in compiles)
+
+    def test_no_span_outside_a_trace(self):
+        import jax
+        import jax.numpy as jnp
+
+        from fluidframework_tpu.telemetry.compile_ledger import \
+            install_jax_listener
+
+        install_jax_listener()
+        tracing.configure(sample=1)
+        before = counters.get("compile.backend")
+        jax.jit(lambda x: x * 5 + 1)(jnp.arange(13)).block_until_ready()
+        assert counters.get("compile.backend") >= before + 1
+        assert not [s for s in tracing.recorder.snapshot()
+                    if s["name"] == "compile"]
+
+    def test_chip_smoke_reads_compiles_off_the_ledger(self):
+        import os
+        import sys
+
+        import jax
+        import jax.numpy as jnp
+
+        from fluidframework_tpu.telemetry.compile_ledger import \
+            install_jax_listener
+
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        import chip_smoke
+
+        install_jax_listener()
+        mark = chip_smoke.compile_mark()
+        jax.jit(lambda x: x * 9 + 2)(jnp.arange(17)).block_until_ready()
+        since = chip_smoke.compile_since(mark)
+        assert since["compile_s"] > 0 and since["seconds"] > 0
+        assert since["cache_hits"] >= 0 and since["cache_misses"] >= 0
