@@ -17,6 +17,7 @@ Message flow (reference docker-compose pipeline):
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import uuid
@@ -252,7 +253,6 @@ class LocalServer:
         the serial runner stays the deterministic default."""
         self.tenant_id = tenant_id
         self.auto_pump = auto_pump
-        self.overlapped = overlapped
         # Front-door op-size ceiling (alfred.maxMessageSize; 0 disables).
         self.max_op_bytes = 1024 * 1024
         # Per-connection op-rate throttling (reference alfred throttler):
@@ -318,6 +318,9 @@ class LocalServer:
         self._signal_rooms: Dict[str, List] = {}
         self._client_counter = itertools.count(1)
         self._pump_lock = threading.RLock()
+        # Set by every pump() call, cleared by the lock holder per round
+        # (pump() docstring).
+        self._pump_wanted = False
         # Optional pre-pump gate (multi-node fencing): called before the
         # lambdas run; returning False aborts the pump — the node lost its
         # reservation and must not sequence another op (server/nodes.py).
@@ -628,24 +631,42 @@ class LocalServer:
         return None
 
     def pump(self) -> int:
-        """Drive every lambda stage to quiescence (synchronous pipeline)."""
-        if self.overlapped:
-            # Stage workers can re-enter pump (a broadcaster listener
-            # submitting an op -> auto_pump): never block on the active
-            # pump — its round loop runs until quiescence, so the newly
-            # queued message is drained by the pump already in flight.
-            if not self._pump_lock.acquire(blocking=False):
-                return 0
+        """Drive every lambda stage to quiescence (synchronous pipeline).
+
+        Never blocks on a pump another thread has in flight: that pump
+        delivers into client locks (DeltaManager.lock) whose holders may
+        be submitting, which calls pump(), so a blocking acquire here is
+        a lock-order deadlock. The caller flags its queued message and
+        returns; the holder re-runs its rounds while the flag is set, and
+        re-checks it after release, so nothing queued is stranded. Every
+        other holder of the lock does the same (_pump_excluded), and a
+        round that raises leaves the flag set for the next holder.
+        Same-thread re-entry (a listener submitting during a pump) nests:
+        the lock is an RLock."""
+        self._pump_wanted = True
+        total = 0
+        while self._pump_wanted and self._pump_lock.acquire(blocking=False):
             try:
-                if self.pump_gate is not None and not self.pump_gate():
-                    return 0
-                return self.runner.pump()
+                while self._pump_wanted:
+                    self._pump_wanted = False
+                    if self.pump_gate is not None and not self.pump_gate():
+                        return total
+                    total += self.runner.pump()
+            except BaseException:
+                self._pump_wanted = True  # this round's work is not done
+                raise
             finally:
                 self._pump_lock.release()
+        return total
+
+    @contextlib.contextmanager
+    def _pump_excluded(self):
+        """Holds off the pump without pumping. A pump() skipped meanwhile
+        (it never blocks, see pump()) runs on release."""
         with self._pump_lock:
-            if self.pump_gate is not None and not self.pump_gate():
-                return 0
-            return self.runner.pump()
+            yield
+        if self._pump_wanted:
+            self.pump()
 
     # -- introspection ----------------------------------------------------
     def sequence_number(self, document_id: str) -> int:
@@ -761,52 +782,56 @@ class TpuLocalServer(LocalServer):
         correct: adoption + residue replay) and the publish retries next
         refresh. Serialized against the pump (artifact consistency needs
         the lanes at a flush boundary)."""
-        from .readpath import build_artifact
-
         if self.catchup is None:
             return {"published": 0, "skipped": 0, "refreshed": 0}
-        with self._pump_lock:
-            # One epoch spans every partition's sequencer: documents are
-            # partition-disjoint (md5 homes), so the per-partition bodies
-            # merge without collision; each publish advances the OWNING
-            # lambda's watermark.
-            bodies: Dict[str, dict] = {}
-            owner: Dict[str, Any] = {}
-            for seq_lambda in self.ingest.sequencers():
-                for doc_id, body in seq_lambda.catchup_snapshot(
-                        only_docs).items():
-                    bodies[doc_id] = body
-                    owner[doc_id] = seq_lambda
-            if not bodies:
-                return {"published": 0, "skipped": 0, "refreshed": 0}
-            # One scan of the checkpoint collection for the whole epoch
-            # (a per-doc find_one would make the epoch O(dirty x docs)).
-            by_doc = {row["documentId"]: row
-                      for row in self.scribe_checkpoints.find(
-                          lambda d: d.get("documentId") in bodies)}
-            published = skipped = 0
-            for doc_id, body in bodies.items():
-                row = by_doc.get(doc_id)
-                if row is None \
-                        or int(row["sequenceNumber"]) != body["seq"]:
-                    skipped += 1
-                    increment("catchup.publish_skipped")
-                    continue
-                sha = self.historian.store(
-                    self.tenant_id, doc_id).get_ref("main")
-                artifact = build_artifact(
-                    body, row["minimumSequenceNumber"], row["quorum"], sha)
-                if self.catchup.publish(self.tenant_id, doc_id, artifact):
-                    published += 1
-                    owner[doc_id].catchup_mark_published(doc_id,
-                                                         body["gen"])
-                    for listener in list(self.catchup_listeners):
-                        try:
-                            listener(self.tenant_id, doc_id, artifact)
-                        except Exception:  # noqa: BLE001 — observers never break the refresh
-                            record_swallow("server.catchup_listener")
-            return {"published": published, "skipped": skipped,
-                    "refreshed": len(bodies)}
+        with self._pump_excluded():
+            return self._refresh_catchup(only_docs)
+
+    def _refresh_catchup(self, only_docs: Optional[set]) -> dict:
+        """refresh_catchup's epoch; the caller holds the pump off."""
+        from .readpath import build_artifact
+
+        # One epoch spans every partition's sequencer: documents are
+        # partition-disjoint (md5 homes), so the per-partition bodies
+        # merge without collision; each publish advances the OWNING
+        # lambda's watermark.
+        bodies: Dict[str, dict] = {}
+        owner: Dict[str, Any] = {}
+        for seq_lambda in self.ingest.sequencers():
+            for doc_id, body in seq_lambda.catchup_snapshot(
+                    only_docs).items():
+                bodies[doc_id] = body
+                owner[doc_id] = seq_lambda
+        if not bodies:
+            return {"published": 0, "skipped": 0, "refreshed": 0}
+        # One scan of the checkpoint collection for the whole epoch
+        # (a per-doc find_one would make the epoch O(dirty x docs)).
+        by_doc = {row["documentId"]: row
+                  for row in self.scribe_checkpoints.find(
+                      lambda d: d.get("documentId") in bodies)}
+        published = skipped = 0
+        for doc_id, body in bodies.items():
+            row = by_doc.get(doc_id)
+            if row is None \
+                    or int(row["sequenceNumber"]) != body["seq"]:
+                skipped += 1
+                increment("catchup.publish_skipped")
+                continue
+            sha = self.historian.store(
+                self.tenant_id, doc_id).get_ref("main")
+            artifact = build_artifact(
+                body, row["minimumSequenceNumber"], row["quorum"], sha)
+            if self.catchup.publish(self.tenant_id, doc_id, artifact):
+                published += 1
+                owner[doc_id].catchup_mark_published(doc_id,
+                                                     body["gen"])
+                for listener in list(self.catchup_listeners):
+                    try:
+                        listener(self.tenant_id, doc_id, artifact)
+                    except Exception:  # noqa: BLE001 — observers never break the refresh
+                        record_swallow("server.catchup_listener")
+        return {"published": published, "skipped": skipped,
+                "refreshed": len(bodies)}
 
     def get_catchup(self, document_id: str) -> Optional[dict]:
         """The serving side of `summary + delta in one round trip`: the
@@ -816,12 +841,12 @@ class TpuLocalServer(LocalServer):
         connects before the next flush dirties it)."""
         if self.catchup is None:
             return None
-        with self._pump_lock:
+        with self._pump_excluded():
             head = self.sequencer_for(document_id).document_seq(
                 document_id)
             art_seq = self.catchup.peek_seq(self.tenant_id, document_id)
             if art_seq is None or art_seq < head:
-                self.refresh_catchup(only_docs={document_id})
+                self._refresh_catchup(only_docs={document_id})
             return self.catchup.get(self.tenant_id, document_id,
                                     head_seq=head)
 

@@ -2,6 +2,7 @@
 processes over gRPC + shared durable storage — the docker-compose topology
 (reference server/docker-compose.yml) driven end to end."""
 
+import itertools
 import json
 import os
 import socket
@@ -29,11 +30,26 @@ def _free_port():
     return port
 
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spawned = itertools.count()
+
+
 def _spawn(args, cwd):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
-    return subprocess.Popen(
-        [sys.executable, "-m", "fluidframework_tpu.server.main", *args],
-        cwd=cwd, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+    """One server process of this tree, its output in a file under cwd
+    (a pipe nobody reads would block a chatty process)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO_ROOT)
+    log_path = os.path.join(cwd, f"{args[0]}-{next(_spawned)}.log")
+    with open(log_path, "wb") as log:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "fluidframework_tpu.server.main", *args],
+            cwd=cwd, env=env, stdout=log, stderr=subprocess.STDOUT)
+    proc.log_path = log_path
+    return proc
+
+
+def _output(proc):
+    with open(proc.log_path, "rb") as log:
+        return log.read().decode(errors="replace")[-2000:]
 
 
 class TestMultiProcessPipeline:
@@ -72,11 +88,11 @@ class TestMultiProcessPipeline:
                     break
                 except OSError:
                     if broker.poll() is not None:
-                        raise AssertionError(
-                            broker.stdout.read().decode()[-2000:])
+                        raise AssertionError(_output(broker))
                     time.sleep(0.1)
             else:
-                raise AssertionError("broker never listened")
+                raise AssertionError(
+                    "broker never listened\n" + _output(broker))
 
             worker = _spawn(["worker", "--config", str(cfg_path)], tmp_path)
             procs.append(worker)
@@ -108,10 +124,10 @@ class TestMultiProcessPipeline:
                 if len(rows) >= 6:  # join + 5 ops
                     break
                 if worker.poll() is not None:
-                    raise AssertionError(
-                        worker.stdout.read().decode()[-2000:])
+                    raise AssertionError(_output(worker))
                 time.sleep(0.2)
-            assert len(rows) >= 6, f"only {len(rows)} deltas persisted"
+            assert len(rows) >= 6, \
+                f"only {len(rows)} deltas persisted\n{_output(worker)}"
             seqs = [r["sequence_number"] for r in rows]
             assert seqs == sorted(seqs) and seqs[0] == 1
             op_rows = [r for r in rows
@@ -128,10 +144,10 @@ class TestMultiProcessPipeline:
             deadline = max(deadline, time.time() + 30)
             while time.time() < deadline and len(raw) < 6:
                 if worker.poll() is not None:
-                    raise AssertionError(
-                        worker.stdout.read().decode()[-2000:])
+                    raise AssertionError(_output(worker))
                 time.sleep(0.2)
-            assert len(raw) >= 6, f"only {len(raw)} raw messages copied"
+            assert len(raw) >= 6, \
+                f"only {len(raw)} raw messages copied\n{_output(worker)}"
             # The historian tier is alive in the topology and serving.
             deadline = time.time() + 30
             while time.time() < deadline:
@@ -142,11 +158,11 @@ class TestMultiProcessPipeline:
                     break
                 except OSError:
                     if historian.poll() is not None:
-                        raise AssertionError(
-                            historian.stdout.read().decode()[-2000:])
+                        raise AssertionError(_output(historian))
                     time.sleep(0.2)
             else:
-                raise AssertionError("historian never listened")
+                raise AssertionError("historian never listened\n"
+                                     + _output(historian))
             assert ping.get("service") == "historian"
         finally:
             for p in procs:
@@ -202,11 +218,11 @@ class TestHistorianProcess:
                     break
                 except OSError:
                     if historian.poll() is not None:
-                        raise AssertionError(
-                            historian.stdout.read().decode()[-2000:])
+                        raise AssertionError(_output(historian))
                     time.sleep(0.1)
             else:
-                raise AssertionError("historian never listened")
+                raise AssertionError("historian never listened\n"
+                                     + _output(historian))
 
             rest = RestWrapper(url)
             first = rest.get("/repos/local/doc/summaries/latest")["summary"]
@@ -264,10 +280,9 @@ class TestBrokerRestart:
                     return p
                 except OSError:
                     if p.poll() is not None:
-                        raise AssertionError(
-                            p.stdout.read().decode()[-2000:])
+                        raise AssertionError(_output(p))
                     time.sleep(0.1)
-            raise AssertionError("broker never listened")
+            raise AssertionError("broker never listened\n" + _output(p))
 
         def submit(log, i):
             log.send(RAW_TOPIC, "doc", Boxcar(
@@ -286,11 +301,10 @@ class TestBrokerRestart:
                 if len(rows) >= n:
                     return rows
                 if worker.poll() is not None:
-                    raise AssertionError(
-                        worker.stdout.read().decode()[-2000:])
+                    raise AssertionError(_output(worker))
                 time.sleep(0.2)
             raise AssertionError(f"only {len(query_deltas(deltas, 'doc'))} "
-                                 f"rows after {timeout}s")
+                                 f"rows after {timeout}s\n{_output(worker)}")
 
         broker = start_broker()
         worker = None

@@ -319,6 +319,72 @@ class TestThrottling:
             _time.sleep(0.05)
         assert dict(m2.items()) == want
 
+    def test_load_during_recovery_resubmit_does_not_deadlock(self):
+        """The interleaving behind a lock-order deadlock, forced: c1's
+        throttle-recovery thread resubmits under c1's DeltaManager.lock
+        (-> server pump) while a late loader's connect is inside the
+        server pump delivering its join to c1 (-> c1's lock). Both must
+        finish and converge. Every wait is bounded."""
+        import threading
+        import time as _time
+        server = self._server(rate=200, burst=5)
+        loader, c1, ds1 = make_doc(server)
+        m1 = ds1.create_channel("map", SharedMap.TYPE)
+        c1.attach()
+
+        resubmitting = threading.Event()
+        delivering = threading.Event()
+        held = []
+        resubmit_all = c1.runtime._resubmit_all
+
+        def held_resubmit():
+            # Recovery thread, holding c1's lock: wait until the loader's
+            # pump is delivering into c1, then resubmit.
+            if not held and threading.current_thread() \
+                    is not threading.main_thread():
+                held.append(True)
+                resubmitting.set()
+                delivering.wait(10)
+            resubmit_all()
+
+        enqueue = c1.delta_manager._enqueue
+
+        def watched_enqueue(msg):
+            if threading.current_thread().name == "late-loader":
+                delivering.set()
+            enqueue(msg)
+
+        c1.runtime._resubmit_all = held_resubmit
+        c1.delta_manager._enqueue = watched_enqueue
+        # Under c1's lock: the recovery thread's reconnect waits until
+        # every edit is parked, so its resubmit is the one held above.
+        with c1.op_lock:
+            for i in range(12):  # exceeds burst: 429 -> recovery thread
+                m1.set(f"k{i}", i)
+        assert resubmitting.wait(10), "throttle recovery never resubmitted"
+
+        loaded = {}
+
+        def load():
+            loaded["c2"] = loader.resolve("doc")
+
+        late = threading.Thread(target=load, name="late-loader",
+                                daemon=True)
+        late.start()
+        late.join(timeout=10)
+        assert not late.is_alive(), \
+            "late loader deadlocked against the resubmitting container"
+        assert delivering.is_set(), "the interleaving was not forced"
+
+        want = {f"k{i}": i for i in range(12)}
+        m2 = loaded["c2"].runtime.get_datastore("default").get_channel("map")
+        deadline = _time.time() + 20
+        while _time.time() < deadline and not (
+                dict(m1.items()) == dict(m2.items()) == want):
+            _time.sleep(0.05)
+        assert dict(m1.items()) == want
+        assert dict(m2.items()) == want
+
     def test_per_document_bucket_survives_reconnect(self):
         """Reconnecting must not mint a fresh throttle budget (the bucket
         is keyed by document on the server)."""
