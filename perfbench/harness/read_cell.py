@@ -14,6 +14,7 @@ join's catch-up time.
 """
 
 import time
+from contextlib import contextmanager
 from typing import List
 
 import numpy as np
@@ -263,6 +264,32 @@ def _diag(record: dict) -> None:
     print("perfbench diag " + json.dumps(record), file=sys.stderr, flush=True)
 
 
+@contextmanager
+def _program_spans(rc):
+    """The program's own tracing on for the body: every span recorded,
+    and each ``with`` span also a ``fluid.<name>`` annotation on the
+    profiler's host line. Afterwards the recorder is drained into the
+    harness's spans, as ``fluid.<name>`` beside ``join`` on
+    ``time.perf_counter``, and the program's tracing is off again."""
+    from fluidframework_tpu.telemetry import compile_ledger, tracing
+
+    compile_ledger.install_jax_listener()
+    tracing.configure(sample=1, capacity=1 << 17, profiler=True)
+    try:
+        yield
+    finally:
+        dropped = tracing.recorder.dropped
+        spans = tracing.recorder.drain()
+        tracing.configure(sample=0, profiler=False)
+    # A span's ts and dur are microseconds, ts on the wall clock.
+    wall = time.time() - time.perf_counter()
+    for s in spans:
+        start = s["ts"] / 1e6 - wall
+        rc.spans.spans["fluid." + s["name"]].append(
+            (start, start + s["dur"] / 1e6))
+    _diag({"program_spans": len(spans), "dropped": dropped})
+
+
 def _measure(rc, traced, clock, t_start, memory_peak, rehearsal_s,
              warm_s) -> dict:
     import jax
@@ -275,7 +302,7 @@ def _measure(rc, traced, clock, t_start, memory_peak, rehearsal_s,
     captured = {}
     setup_s = time.perf_counter() - t_start
     if traced:
-        with trace_mod.capture(captured):
+        with _program_spans(rc), trace_mod.capture(captured):
             with jax.profiler.TraceAnnotation(trace_mod.WINDOW):
                 window = rc.window()
     else:

@@ -83,10 +83,11 @@ def _line(plane: dict, *names: str) -> List[Event]:
 
 
 def annotations(planes: List[dict]) -> List[Event]:
-    """Host ``bench.*`` TraceAnnotations, from every host thread."""
+    """Host TraceAnnotations of the harness (``bench.*``) and of the
+    program's traced stages (``fluid.*``), from every host thread."""
     return [e for p in planes if p["name"].startswith("/host:")
             for line in p["lines"] for e in line["events"]
-            if e[0].startswith("bench.")]
+            if e[0].startswith(("bench.", "fluid."))]
 
 
 def reduce(planes: List[dict], programs: Dict[str, str] = None
@@ -103,7 +104,8 @@ def reduce(planes: List[dict], programs: Dict[str, str] = None
     - ``device_ops``: the 10 programs (module events; op events where a
       plane has no module line) with the most device time;
     - ``idle_gaps``: the 10 longest gaps between busy intervals, each
-      named by the innermost ``bench.*`` annotation over its middle."""
+      named by the innermost ``bench.*`` or ``fluid.*`` annotation over
+      its middle."""
     notes = annotations(planes)
     window = [e for e in notes if e[0] == WINDOW]
     devices = device_planes(planes)

@@ -15,8 +15,8 @@ READ = "hotdoc100k.catchup"
 ENDS_RUN = {"read.half_batch"}
 
 
-def test_unbroken_run_is_correct(tiny_root, capsys):
-    line = run_cell(tiny_root, READ, capsys)
+def test_unbroken_run_is_correct(tiny_root):
+    line = run_cell(tiny_root, READ)
     assert line["correct"] is True, line["checks"]
     assert line["rehearsal"] is True
     assert line["device"]["platform"] == "cpu"
@@ -25,10 +25,10 @@ def test_unbroken_run_is_correct(tiny_root, capsys):
 
 
 @pytest.mark.parametrize("fault", sorted(faults.INSTALL))
-def test_broken_run_is_not_correct(tiny_root, capsys, fault):
+def test_broken_run_is_not_correct(tiny_root, fault):
     undo = faults.INSTALL[fault]()
     try:
-        line = run_cell(tiny_root, READ, capsys, must_report=False)
+        line = run_cell(tiny_root, READ, must_report=False)
     finally:
         undo()
     if fault in ENDS_RUN:

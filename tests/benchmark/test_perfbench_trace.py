@@ -2,21 +2,9 @@
 small synthetic trace."""
 
 from perfbench.harness import trace
+from perfbench_tiny import synthetic_trace as _trace
 
 MS = 1e6  # ns
-
-
-def _trace(window=(0, 100), ops=(), modules=(), notes=()):
-    host = [("bench.window", window[0] * MS, (window[1] - window[0]) * MS)]
-    host += [(n, a * MS, (b - a) * MS) for n, a, b in notes]
-    return [
-        {"name": "/host:CPU", "lines": [{"name": "python", "events": host}]},
-        {"name": "/device:TPU:0", "lines": [
-            {"name": "XLA Ops", "events": [(n, a * MS, (b - a) * MS)
-                                           for n, a, b in ops]},
-            {"name": "XLA Modules", "events": [(n, a * MS, (b - a) * MS)
-                                               for n, a, b in modules]}]},
-    ]
 
 
 def test_busy_union_idle_share_and_programs():
@@ -36,6 +24,16 @@ def test_busy_union_idle_share_and_programs():
     # Longest gaps: [70,95] under pump_round, [40,60] under flush.
     assert out["idle_gaps"][0] == ["bench.pump_round", 0.025]
     assert out["idle_gaps"][1] == ["bench.flush", 0.02]
+
+
+def test_gaps_are_named_by_the_innermost_program_stage():
+    planes = _trace(ops=[("a", 0, 10), ("b", 50, 100)],
+                    notes=[("bench.join", 5, 60),
+                           ("fluid.load.connect", 20, 45),
+                           ("fluid.server.get_deltas", 30, 40),
+                           ("other.annotation", 25, 35)])
+    out = trace.reduce(planes)
+    assert out["idle_gaps"] == [["fluid.server.get_deltas", 0.04]]
 
 
 def test_busy_is_averaged_over_devices():
