@@ -20,6 +20,7 @@ from ..mergetree.client import MergeTreeClient, OP_INSERT, OP_REMOVE
 from ..mergetree.constants import SEG_TEXT, UNASSIGNED_SEQ
 from ..mergetree.runs import Run, id_key as _id_key
 from ..protocol.summary import SummaryTree
+from ..telemetry import tracing
 from .shared_object import SharedObject, collect_handles
 
 
@@ -171,12 +172,52 @@ class SharedMatrix(SharedObject):
 
     def __init__(self, object_id: str, runtime=None):
         super().__init__(object_id, runtime)
-        self.rows = PermutationVector(self.local_client_id)
-        self.cols = PermutationVector(self.local_client_id)
+        self._rows = PermutationVector(self.local_client_id)
+        self._cols = PermutationVector(self.local_client_id)
         # cell key "(rowid,colid)" -> value; pending LWW shadow counts
-        self.cells: Dict[str, Any] = {}
+        self._cells: Dict[str, Any] = {}
         self._pending_cells: Dict[str, int] = {}
         self._consumers: List[Any] = []
+        # A loaded summary is parsed at the first touch (_realize): a late
+        # client that adopts a catch-up artifact never parses the body the
+        # artifact replaces. (summary tree, the ordinal at load time)
+        self._lazy: Optional[Tuple[SummaryTree, int]] = None
+        self._lazy_ordinal: Optional[int] = None
+
+    # -- state (realized from a loaded summary at the first touch) ----------
+    def _realize(self) -> None:
+        tree, client_id = self._lazy
+        self._lazy = None
+        self._load_parts({name: json.loads(tree.entries[name].content)
+                          for name in ("rows", "cols", "cells")}, client_id)
+        if self._lazy_ordinal is not None:
+            self.adopt_client_ordinal(self._lazy_ordinal)
+            self._lazy_ordinal = None
+
+    def _load_parts(self, parts: dict, client_id: int) -> None:
+        """The one path from a summary's parts (two axis snapshots, the
+        cell map) to live state: summaries and catch-up artifacts."""
+        self._rows.load(parts["rows"], client_id)
+        self._cols.load(parts["cols"], client_id)
+        self._cells = parts["cells"]
+
+    @property
+    def rows(self) -> PermutationVector:
+        if self._lazy is not None:
+            self._realize()
+        return self._rows
+
+    @property
+    def cols(self) -> PermutationVector:
+        if self._lazy is not None:
+            self._realize()
+        return self._cols
+
+    @property
+    def cells(self) -> Dict[str, Any]:
+        if self._lazy is not None:
+            self._realize()
+        return self._cells
 
     # -- IMatrixProducer ----------------------------------------------------
     def open_matrix(self, consumer: Any) -> "SharedMatrix":
@@ -199,6 +240,9 @@ class SharedMatrix(SharedObject):
 
     # -- lifecycle ---------------------------------------------------------
     def adopt_client_ordinal(self, ordinal: int) -> None:
+        if self._lazy is not None:
+            self._lazy_ordinal = ordinal  # applied at realization
+            return
         self.rows.client.update_client_id(ordinal)
         self.cols.client.update_client_id(ordinal)
 
@@ -390,11 +434,46 @@ class SharedMatrix(SharedObject):
         return tree
 
     def load_core(self, tree: SummaryTree) -> None:
-        self.rows.load(json.loads(tree.entries["rows"].content),
-                       self.local_client_id)
-        self.cols.load(json.loads(tree.entries["cols"].content),
-                       self.local_client_id)
-        self.cells = json.loads(tree.entries["cells"].content)
+        self._lazy = (tree, self.local_client_id)
+        self._lazy_ordinal = None
+
+    # -- read-path catch-up adoption (docs/read_path.md) -------------------
+    catchup_kind = "matrix"  # the artifact channel form this adopts
+
+    def can_adopt_catchup(self) -> bool:
+        """Whether this matrix may be REPLACED wholesale by a server
+        catch-up artifact: no pending (unacked) axis edit or cell write.
+        A lazy, untouched body trivially qualifies."""
+        if self._lazy is not None:
+            return True
+        return not (self._rows.tree.pending_groups
+                    or self._cols.tree.pending_groups or self._pending_cells)
+
+    def adopt_catchup_core(self, parts: dict, seq: int, min_seq: int,
+                           total_length: int) -> None:
+        """Swap in a server-materialized matrix at the artifact's seq:
+        ``parts`` holds the two axis snapshots and the cell map of the
+        summary's layout (rows, cols, cells), already decoded, and they
+        load through the same ``_load_parts`` as a summary's, so there is
+        no second deserialization path. A body still lazy from the
+        summary is dropped unparsed. seq, min_seq and total_length are
+        the sequence channels' header fields; each axis carries its
+        own."""
+        if not self.can_adopt_catchup():
+            raise ValueError("channel has live local state")
+        with tracing.span("matrix.adopt"):
+            if self._lazy is not None:
+                client_id, ordinal = self._lazy[1], self._lazy_ordinal
+                self._lazy = self._lazy_ordinal = None
+            else:
+                client_id, ordinal = self._rows.client.client_id, None
+            # The artifact is shared by every client it serves: the cell
+            # map is copied, the axis entries are this client's own.
+            self._load_parts({"rows": parts["rows"], "cols": parts["cols"],
+                              "cells": dict(parts["cells"])}, client_id)
+            if ordinal is not None:
+                self.adopt_client_ordinal(ordinal)
+        self.change_epoch += 1  # adopted state is NOT durably summarized
 
     def get_gc_data(self) -> List[str]:
         routes: List[str] = []

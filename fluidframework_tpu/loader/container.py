@@ -250,15 +250,33 @@ class Container(TypedEventEmitter):
                 store = self.runtime.datastores.get(store_id)
                 channel = store.channels.get(channel_id) \
                     if store is not None else None
+                kind = header.get("kind", "sequence")
                 if channel is None \
                         or not hasattr(channel, "adopt_catchup_core") \
+                        or getattr(channel, "catchup_kind",
+                                   "sequence") != kind \
                         or not channel.can_adopt_catchup():
                     increment("catchup.client.unadoptable")
                     return None
-                entries = unpack_entries_narrow(blob)
-                # KeyError here = a contended row references a client the
-                # quorum no longer knows: untranslatable, fall back.
-                entries = translate_entry_clients(entries, idx_to_ordinal)
+
+                def entries_of(packed):
+                    # KeyError here = a contended row references a client
+                    # the quorum no longer knows: untranslatable, fall
+                    # back.
+                    return translate_entry_clients(
+                        unpack_entries_narrow(packed), idx_to_ordinal)
+
+                if kind == "matrix":
+                    # Both axes as the summary's axis snapshots, beside
+                    # the cell map: SharedMatrix.load_core's layout.
+                    entries = {axis: {
+                        "segments": entries_of(blob[axis]),
+                        "seq": int(header[axis]["sequenceNumber"]),
+                        "minSeq": int(header[axis]["minimumSequenceNumber"])}
+                        for axis in ("rows", "cols")}
+                    entries["cells"] = blob["cells"]
+                else:
+                    entries = entries_of(blob)
                 plan.append((channel, entries, header))
         except (KeyError, ValueError, TypeError):
             increment("catchup.client.undecodable")

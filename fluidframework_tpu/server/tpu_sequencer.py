@@ -2128,40 +2128,71 @@ def looks_like_lww_op(op: Any) -> bool:
     return t in ("setCell", "deleteCell")
 
 
+# Key-slot capacity ladder of the LWW lanes. The top rung holds a full
+# 1k x 1k SharedMatrix cell store (2**20 keys); the rungs between step by
+# 4x so a lane never scans much more table than it fills (every op of the
+# apply kernel is one pass over its lane's slots).
+LWW_CAPACITIES = (64, 1024, 16384, 65536, 262144, 1 << 20)
+# Key slots a bucket preallocates over all its lanes: small buckets start
+# with 8 lanes, big ones with fewer (one at 2**17 keys and up); grow()
+# doubles a bucket that runs out.
+_LWW_BUCKET_SLOTS = 1 << 17
+
+
+def lww_lanes_for(capacity: int) -> int:
+    return max(1, min(8, _LWW_BUCKET_SLOTS // capacity))
+
+
 class _LwwBucket:
     """A batch of LWW lanes sharing one key-slot capacity (mirrors
     _MergeBucket: per-capacity buckets instead of one global table, so one
-    hot channel cannot inflate device memory for every lane)."""
+    hot channel cannot inflate device memory for every lane). The device
+    table is built on first use: the big rungs cost nothing until a lane
+    needs them."""
 
     def __init__(self, lk, capacity: int, lanes: int = 8):
         self.lk = lk
         self.capacity = capacity
         self.lanes = lanes
-        self.state = lk.make_lww_state(capacity, batch=lanes)
+        self._state = None
+        self.placer = None  # optional dp-mesh placement callable
         self.used: List[Optional[tuple]] = [None] * lanes
         self._blank_row = None  # built lazily, reused across frees
         self._free: List[int] = []
         self._next = 0
-        self.placer = None  # optional dp-mesh placement callable
         # Upper bound of each lane's occupied key slots (the donation
         # gate's host-side fit proof; see _MergeBucket.count_hint for
         # the confirmed-base / in-flight-pending split).
         self.count_hint = np.zeros(lanes, np.int64)
         self.hint_pending = np.zeros(lanes, np.int64)
 
+    @property
+    def state(self):
+        if self._state is None:
+            self._state = self.lk.make_lww_state(self.capacity,
+                                                 batch=self.lanes)
+            if self.placer is not None:
+                self._state = self.placer(self._state)
+        return self._state
+
+    @state.setter
+    def state(self, value) -> None:
+        self._state = value
+
     def grow(self) -> None:
         old = self.lanes
-        grown = self.lk.make_lww_state(self.capacity, batch=old * 2)
-        self.state = jax.tree_util.tree_map(
-            lambda g, s: g.at[:old].set(s), grown, self.state)
+        if self._state is not None:
+            grown = self.lk.make_lww_state(self.capacity, batch=old * 2)
+            self._state = jax.tree_util.tree_map(
+                lambda g, s: g.at[:old].set(s), grown, self._state)
+            if self.placer is not None:
+                self._state = self.placer(self._state)
         self.used.extend([None] * old)
         self.lanes = old * 2
         self.count_hint = np.concatenate(
             [self.count_hint, np.zeros(old, np.int64)])
         self.hint_pending = np.concatenate(
             [self.hint_pending, np.zeros(old, np.int64)])
-        if self.placer is not None:
-            self.state = self.placer(self.state)
 
     def alloc(self, key: tuple) -> int:
         # Free-list + frontier (see _MergeBucket.alloc).
@@ -2204,15 +2235,16 @@ class LwwLaneStore:
     key-capacity buckets; a lane whose key set outgrows its bucket promotes
     to the next one and its window re-applies from the retained pre-state."""
 
-    def __init__(self, capacities: Tuple[int, ...] = (64, 1024, 16384),
-                 lanes_per_bucket: int = 8,
+    def __init__(self, capacities: Tuple[int, ...] = LWW_CAPACITIES,
+                 lanes_per_bucket: Optional[int] = None,
                  t_buckets: Tuple[int, ...] = DEFAULT_T_BUCKETS):
         from . import lww_kernel as lk
 
         self.lk = lk
         self.capacities = tuple(capacities)
         self.t_buckets = tuple(t_buckets)
-        self.buckets = [_LwwBucket(lk, c, lanes_per_bucket)
+        self.buckets = [_LwwBucket(lk, c, lanes_per_bucket
+                                   or lww_lanes_for(c))
                         for c in self.capacities]
         self.where: Dict[tuple, Tuple[int, int]] = {}
         self.opaque: set = set()  # channels dropped after bucket exhaustion
@@ -2381,8 +2413,7 @@ class LwwLaneStore:
                         self.t_buckets)
             ops_dev = self._pack(bucket.lanes, lane_ops, t)
             pre = bucket.state
-            new = self.lk.apply_lww_batched(pre, ops_dev)
-            over = np.asarray(new.overflow)
+            new, over = self._dispatch(pre, ops_dev)
             flagged = [i for i in range(bucket.lanes)
                        if over[i] and i in lane_ops]
             if flagged:
@@ -2396,6 +2427,21 @@ class LwwLaneStore:
             for i in flagged:
                 self._promote(b, i, lane_ops[i], t)
 
+    def _dispatch(self, state, ops):
+        """One apply_lww_batched dispatch and its overflow readback, as
+        the ``lww.apply`` span. ``lww.apply.bytes`` counts what the apply
+        must move whatever implements it: one read and one write of every
+        dispatched lane's slot table (key, val and seq as int32) plus the
+        five int32 op columns."""
+        lanes, capacity = state.key.shape
+        steps = ops.kind.shape[-1]
+        increment("lww.apply.bytes",
+                  lanes * capacity * 12 * 2 + 5 * 4 * lanes * steps)
+        with tracing.span("lww.apply", lanes=lanes, capacity=capacity,
+                          steps=steps):
+            new = self.lk.apply_lww_batched(state, ops)
+            return new, np.asarray(new.overflow)
+
     def _promote(self, b: int, lane: int, ops: List[tuple], t: int) -> None:
         """Overflowed lane: move to the next capacity bucket and re-apply
         its window from the retained pre-state row."""
@@ -2408,12 +2454,13 @@ class LwwLaneStore:
                 jax.tree_util.tree_map(lambda x: x[None], row),
                 target.capacity)
             ops_dev = self._pack(1, {0: ops}, t)
-            redone = self.lk.apply_lww_batched(wide, ops_dev)
-            if not bool(np.asarray(redone.overflow)[0]):
+            redone, over = self._dispatch(wide, ops_dev)
+            if not bool(over[0]):
                 new_lane = target.alloc(key)
                 target.put_row(new_lane, jax.tree_util.tree_map(
                     lambda x: x[0], redone))
                 self.where[key] = (nb, new_lane)
+                increment("lww.promotions")
                 return
             row = jax.tree_util.tree_map(lambda x: x[0], wide)
         # Exhausted every key-capacity bucket: degrade this ONE channel to
@@ -2424,30 +2471,30 @@ class LwwLaneStore:
         del self.where[key]
         self.opaque.add(key)
         self.overflow_drops += 1
+        increment("lww.overflow_drops")
 
     def compact_values(self) -> None:
         """Reclaim unreferenced payloads: memory must track LIVE state, not
-        total op count (the merge side's zamboni analog for values)."""
-        referenced: set = set()
-        for bucket in self.buckets:
-            if any(k is not None for k in bucket.used):
-                vals = np.asarray(bucket.state.val)
-                referenced.update(int(v) for v in np.unique(vals) if v >= 0)
-        remap = {old: new for new, old in enumerate(sorted(referenced))}
+        total op count (the merge side's zamboni analog for values). One
+        host pass over each used bucket's val plane."""
+        used = [b for b in self.buckets
+                if any(k is not None for k in b.used)]
+        planes = [np.asarray(b.state.val) for b in used]
+        referenced = np.unique(np.concatenate(
+            [p.ravel() for p in planes] + [np.zeros(0, np.int32)]))
+        referenced = referenced[referenced >= 0]
         # Materialize through value(): block entries must decode before
         # the id space is renumbered (resolve() keys off the old base).
-        self.values = [self.value(old) for old in sorted(referenced)]
-        for bucket in self.buckets:
-            if not any(k is not None for k in bucket.used):
-                continue
-            vals = np.asarray(bucket.state.val)
+        self.values = [self.value(int(old)) for old in referenced]
+        for bucket, vals in zip(used, planes):
             # Exact key-slot occupancy refresh while the plane is on the
             # host anyway (donation-gate hints; see _MergeBucket).
             bucket.count_hint = np.count_nonzero(
                 np.asarray(bucket.state.key) >= 0, axis=-1).astype(np.int64)
-            out = np.full_like(vals, -1)
-            for old, new in remap.items():
-                out[vals == old] = new
+            # Every id >= 0 in a used bucket is in `referenced`, so its
+            # rank there is its new id.
+            out = np.where(vals >= 0, np.searchsorted(referenced, vals),
+                           -1).astype(vals.dtype)
             bucket.state = bucket.state._replace(val=jnp.asarray(out))
             if bucket.placer is not None:
                 # Same dp-mesh rule as the merge side's major collection:
@@ -2465,12 +2512,11 @@ class LwwLaneStore:
         b, lane = self.where[lane_key]
         state = self.buckets[b].state
         keys = np.asarray(state.key[lane])
-        vals = np.asarray(state.val[lane])
-        entries = {}
-        for kid, vid in zip(keys, vals):
-            if int(kid) >= 0:
-                entries[self.key_names[int(kid)]] = (
-                    self.value(int(vid)) if int(vid) >= 0 else None)
+        live = np.flatnonzero(keys >= 0)
+        vals = np.asarray(state.val[lane])[live].tolist()
+        names, value = self.key_names, self.value
+        entries = {names[kid]: (value(vid) if vid >= 0 else None)
+                   for kid, vid in zip(keys[live].tolist(), vals)}
         return {
             "entries": entries,
             "counter": int(np.asarray(state.counter[lane])),
@@ -2962,6 +3008,8 @@ class TpuSequencerLambda(IPartitionLambda):
                 while bucket.lanes % dp != 0 or bucket.lanes < dp:
                     bucket.grow()
                 bucket.placer = self._place
+                if isinstance(bucket, _LwwBucket):
+                    continue  # placed when its table is first built
                 bucket.state = self._place(bucket.state)
         self._pending_offset: Optional[int] = None
         # Fast-path (raw wire bytes) ingest state: the native pump + its
@@ -6310,22 +6358,31 @@ class TpuSequencerLambda(IPartitionLambda):
     # -- read-path catch-up artifacts (server/readpath.py) -----------------
     def catchup_docs_supported(self) -> Tuple[Dict[str, List[tuple]], set]:
         """Partition the resident documents for the delta publisher:
-        (doc -> its merge lane keys, unsupported doc ids). A document
-        rides the delta path only when EVERY channel of it is a plain
-        merge-tree sequence lane the publisher can translate — any LWW/
-        matrix/directory lane, any opaque (unmodelable-op) channel, or
-        any catchup_unsafe seed excludes the whole document: a partial
-        artifact would desync the client's per-doc seq bookkeeping, so
-        those documents keep the tail-replay fallback."""
+        (doc -> its lane keys, unsupported doc ids). A document rides the
+        delta path only when EVERY channel of it is either a plain
+        merge-tree sequence lane or a whole SharedMatrix (its two axis
+        merge lanes and its cell lane). Any other LWW/directory lane, a
+        matrix missing a sub-lane, any opaque (unmodelable-op) lane or
+        sub-lane, or any catchup_unsafe seed excludes the whole document:
+        a partial artifact would desync the client's per-doc seq
+        bookkeeping, so those documents keep the tail-replay fallback."""
         by_doc: Dict[str, List[tuple]] = {}
         unsupported: set = set()
-        for key in list(self.merge.where):
+        matrices: set = set()
+        for key in list(self.merge.where) + list(self.lww.where):
             by_doc.setdefault(key[0], []).append(key)
-            chan = key[2]
-            if (isinstance(chan, str) and "\x00" in chan) \
-                    or key in self.merge.catchup_unsafe:
+            base = matrix_base_key(key)
+            if base is not None:
+                matrices.add(base)
+            elif key in self.lww.where or "\x00" in str(key[2]):
                 unsupported.add(key[0])
-        for key in list(self.lww.where):
+        for doc, store, chan in matrices:
+            rows, cols, cells = ((doc, store, chan + sfx)
+                                 for sfx, _ in _MATRIX_SUFFIXES)
+            if not (rows in self.merge.where and cols in self.merge.where
+                    and cells in self.lww.where):
+                unsupported.add(doc)
+        for key in self.merge.catchup_unsafe & set(self.merge.where):
             unsupported.add(key[0])
         for key in list(self.merge.opaque) + list(self.lww.opaque):
             unsupported.add(key[0])
@@ -6337,7 +6394,8 @@ class TpuSequencerLambda(IPartitionLambda):
         whose change generations advanced past its published artifact —
         ONE batched device dispatch per capacity bucket / page group for
         ALL of them together (extract_dispatch; clean lanes ride the
-        summarize blob cache) — and return the per-doc artifact bodies
+        summarize blob cache; matrix axes are merge lanes and ride the
+        same dispatch) — and return the per-doc artifact bodies
         {doc_id: {"seq", "gen", "clients", "channels"}}. Channel entries
         are narrow-wire packed (mergetree.catchup.pack_entries_narrow)
         with client fields translated from this lambda's interned
@@ -6359,14 +6417,20 @@ class TpuSequencerLambda(IPartitionLambda):
                 continue
             if doc_id in unsupported or doc_id not in self.docs:
                 continue
+            # The merge and LWW stores count generations apart; each
+            # per-store maximum only grows, so their sum moves exactly
+            # when either store's lanes of the document changed.
             doc_gen = max((self.merge.change_gen.get(k, 0) for k in keys),
-                          default=0)
+                          default=0) \
+                + max((self.lww.change_gen.get(k, 0) for k in keys),
+                      default=0)
             if doc_gen <= self._catchup_gen.get(doc_id, -1):
                 continue  # published artifact already covers this state
             refresh[doc_id] = doc_gen
         if not refresh:
             return {}
-        want = {k for d in refresh for k in by_doc[d]}
+        want = {k for d in refresh for k in by_doc[d]
+                if k in self.merge.where}
         with tracing.span("catchup.extract", docs=len(refresh)) as sp:
             jobs, cached = self.merge.extract_dispatch(
                 only=want, chunk_chars=chunk_chars)
@@ -6380,7 +6444,9 @@ class TpuSequencerLambda(IPartitionLambda):
                       by_doc: Dict[str, List[tuple]],
                       snaps: Dict[tuple, dict]) -> Dict[str, dict]:
         """Per refreshed document: client fields translated to wire-table
-        indices and every channel's entries narrow-packed."""
+        indices and every channel's entries narrow-packed. A matrix is one
+        channel: its axes narrow-packed (Run payloads ride the packer's
+        payload list) beside its cell map, under a ``matrix`` header."""
         from ..mergetree.catchup import (pack_entries_narrow,
                                          translate_entry_clients)
 
@@ -6394,29 +6460,45 @@ class TpuSequencerLambda(IPartitionLambda):
             clients = [dl.ordinals[o] for o in sorted(dl.ordinals)]
             mapping = {o: i for i, o in enumerate(sorted(dl.ordinals))}
             doc_seq = self.document_seq(doc_id)
-            channels: List[list] = []
-            ok = True
-            for key in by_doc[doc_id]:
-                snap = snaps.get(key)
-                if snap is None:
-                    ok = False
-                    break
+
+            def pack(key):
+                snap = snaps[key]
                 entries = [e for chunk in snap["chunks"] for e in chunk]
-                try:
-                    entries = translate_entry_clients(entries, mapping)
-                    blob = pack_entries_narrow(entries, base_seq=doc_seq)
-                except (KeyError, ValueError):
-                    ok = False
-                    break
-                channels.append([key[1], key[2], dict(snap["header"]),
-                                 blob])
-            if not ok:
+                return dict(snap["header"]), pack_entries_narrow(
+                    translate_entry_clients(entries, mapping),
+                    base_seq=doc_seq)
+
+            channels: List[list] = []
+            try:
+                for key in by_doc[doc_id]:
+                    base = matrix_base_key(key)
+                    if base is None:
+                        channels.append([key[1], key[2], *pack(key)])
+                    elif key[2].endswith(MATRIX_CELLS_SUFFIX):
+                        channels.append(self._catchup_matrix(base, pack))
+            except (KeyError, ValueError):
                 increment("catchup.refresh_unsupported")
                 continue
             out[doc_id] = {"seq": doc_seq, "gen": doc_gen,
                            "clients": clients, "channels": channels}
         increment("catchup.refresh_docs", len(out))
         return out
+
+    def _catchup_matrix(self, base: tuple, pack) -> list:
+        """One SharedMatrix's artifact channel: the cell lane's entries
+        and the two axes packed, as the ``catchup.matrix`` span."""
+        with tracing.span("catchup.matrix"):
+            cells = self.lww.snapshot(
+                (base[0], base[1], base[2] + MATRIX_CELLS_SUFFIX))
+            header: Dict[str, Any] = {"kind": "matrix"}
+            blob: Dict[str, Any] = {"cells": cells["entries"]}
+            seq = cells["sequenceNumber"]
+            for suffix, axis in _MATRIX_SUFFIXES[:2]:
+                header[axis], blob[axis] = pack(
+                    (base[0], base[1], base[2] + suffix))
+                seq = max(seq, header[axis]["sequenceNumber"])
+            header["sequenceNumber"] = seq
+            return [base[1], base[2], header, blob]
 
     def catchup_mark_published(self, doc_id: str, gen: int) -> None:
         """Advance the publish watermark — called only after the joined
