@@ -4034,7 +4034,8 @@ def fleet_smoke() -> int:
                     type=MessageType.OPERATION, contents={"n": i}), "c1")
 
             db = SqliteDatabaseManager(cfg["storage"]["db"])
-            deltas = db.collection("deltas", unique_key=delta_key)
+            deltas = db.collection("deltas", unique_key=delta_key,
+                                   range_key=delta_key)
             deadline = time.time() + 120
             rows = []
             while time.time() < deadline:

@@ -38,15 +38,20 @@ class SqliteCollection:
     """services-core ICollection over one sqlite table. Documents are JSON
     rows; the unique key (when configured) is a computed TEXT column with a
     UNIQUE index, so replayed inserts are dropped exactly like the
-    reference's ignored dup-key errors."""
+    reference's ignored dup-key errors. range_key gives `find_range` the
+    in-memory Collection's contract, answered here by a filtered, ordered
+    scan of the table."""
 
     def __init__(self, conn: sqlite3.Connection, lock: threading.Lock,
                  name: str,
-                 unique_key: Optional[Callable[[dict], Any]] = None):
+                 unique_key: Optional[Callable[[dict], Any]] = None,
+                 range_key: Optional[Callable[[dict], Tuple[Any, Any]]]
+                 = None):
         self._conn = conn
         self._lock = lock
         self._table = f'col_{name}'
         self._unique_key = unique_key
+        self._range_key = range_key
         with self._lock:
             self._conn.execute(
                 f'CREATE TABLE IF NOT EXISTS "{self._table}" '
@@ -114,6 +119,26 @@ class SqliteCollection:
                 return d
         return None
 
+    def find_range(self, partition: Any, lo_exclusive: Any,
+                   hi_inclusive: Any = None,
+                   stats: Optional[dict] = None) -> List[dict]:
+        """Collection.find_range's contract: rows of the partition with
+        lo < order <= hi (no upper bound when hi is None), ordered by
+        order, insertion order among equals."""
+        if self._range_key is None:
+            raise TypeError("find_range needs a collection with a range_key")
+        rows = self._rows()
+        hits = []
+        for _, d in rows:
+            part, order = self._range_key(d)
+            if part == partition and order > lo_exclusive \
+                    and (hi_inclusive is None or order <= hi_inclusive):
+                hits.append((order, d))
+        hits.sort(key=lambda h: h[0])  # stable: insertion order among equals
+        if stats is not None:
+            stats["scanned"] = len(rows)
+        return [d for _, d in hits]
+
     def upsert(self, match: Callable[[dict], bool], doc: dict) -> None:
         with self._lock:
             for rid, d in self._rows_locked():
@@ -148,12 +173,13 @@ class SqliteDatabaseManager:
         self._meta_lock = threading.Lock()
 
     def collection(self, name: str,
-                   unique_key: Optional[Callable[[dict], Any]] = None
-                   ) -> SqliteCollection:
+                   unique_key: Optional[Callable[[dict], Any]] = None,
+                   range_key: Optional[Callable[[dict], Tuple[Any, Any]]]
+                   = None) -> SqliteCollection:
         with self._meta_lock:
             if name not in self._collections:
                 self._collections[name] = SqliteCollection(
-                    self._conn, self._lock, name, unique_key)
+                    self._conn, self._lock, name, unique_key, range_key)
             return self._collections[name]
 
     def close(self) -> None:

@@ -5,9 +5,10 @@ stripped before persisting)."""
 from __future__ import annotations
 
 from dataclasses import asdict
-from typing import List
+from typing import List, Optional
 
 from ...protocol.messages import SequencedDocumentMessage
+from ...telemetry.counters import increment
 from ..database import Collection
 from ..log import QueuedMessage
 from .base import IPartitionLambda, LambdaContext
@@ -43,16 +44,19 @@ class ScriptoriumLambda(IPartitionLambda):
 
 
 def delta_key(doc: dict):
+    """The delta store's unique key, and its range key: (partition,
+    order) for `Collection.find_range`."""
     return (doc["documentId"], doc["sequence_number"])
 
 
 def query_deltas(deltas: Collection, document_id: str, from_seq: int = 0,
-                 to_seq=None) -> List[dict]:
+                 to_seq=None, stats: Optional[dict] = None) -> List[dict]:
     """Catch-up range query over the delta store: rows with
-    from_seq < seq <= to_seq, ordered (alfred's delta REST semantics)."""
-    hi = to_seq if to_seq is not None else 2 ** 62
-    out = deltas.find(
-        lambda d: d["documentId"] == document_id
-        and from_seq < d["sequence_number"] <= hi)
-    out.sort(key=lambda d: d["sequence_number"])
-    return out
+    from_seq < seq <= to_seq, ordered (alfred's delta REST semantics).
+    One range read of the store's (documentId, sequence_number) index:
+    its cost is the rows it returns. stats, if given, receives "scanned"
+    (the rows the store looked at)."""
+    rows = deltas.find_range(document_id, from_seq, to_seq, stats)
+    increment("deltas.range_reads")
+    increment("deltas.range_rows", len(rows))
+    return rows

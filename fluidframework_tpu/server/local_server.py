@@ -277,7 +277,8 @@ class LocalServer:
                                     native=native_log)
         self.db = db if db is not None else DatabaseManager()
         self.historian = historian if historian is not None else Historian()
-        self.deltas = self.db.collection("deltas", unique_key=delta_key)
+        self.deltas = self.db.collection("deltas", unique_key=delta_key,
+                                         range_key=delta_key)
         self.raw_deltas = self.db.collection("rawdeltas")
         self.deli_checkpoints = self.db.collection("deliCheckpoints")
         self.scribe_checkpoints = self.db.collection("scribeCheckpoints")
@@ -617,8 +618,10 @@ class LocalServer:
         collection): ops with from_seq < seq <= to_seq, ordered."""
         with tracing.span("server.get_deltas", document=document_id,
                           from_seq=from_seq) as sp:
-            rows = query_deltas(self.deltas, document_id, from_seq, to_seq)
-            sp.set(rows=len(rows))
+            stats: dict = {}
+            rows = query_deltas(self.deltas, document_id, from_seq, to_seq,
+                                stats)
+            sp.set(rows=len(rows), scanned=stats["scanned"])
             return rows
 
     def storage(self, document_id: str):

@@ -208,7 +208,8 @@ def build_worker(cfg: dict, stages: List[str]):
     db = SqliteDatabaseManager(cfg["storage"]["db"])
     historian = FileHistorian(cfg["storage"]["git"])
     tenant = cfg["worker"].get("tenant", "local")
-    deltas = db.collection("deltas", unique_key=delta_key)
+    deltas = db.collection("deltas", unique_key=delta_key,
+                           range_key=delta_key)
     raw_deltas = db.collection("rawdeltas")
     deli_ckpt = db.collection("deliCheckpoints")
     scribe_ckpt = db.collection("scribeCheckpoints")

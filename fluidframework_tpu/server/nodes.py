@@ -301,7 +301,8 @@ class Cluster:
     @property
     def deltas(self) -> Collection:
         from .lambdas.scriptorium import delta_key
-        return self.db.collection("deltas", unique_key=delta_key)
+        return self.db.collection("deltas", unique_key=delta_key,
+                                  range_key=delta_key)
 
     def deli_checkpoint(self, document_id: str) -> Optional[dict]:
         """Checkpointed sequencing state for a doc, normalized to the

@@ -116,7 +116,8 @@ class TestMultiProcessPipeline:
 
             # Sequenced deltas must land in the shared sqlite store.
             db = SqliteDatabaseManager(str(tmp_path / "fluid.sqlite"))
-            deltas = db.collection("deltas", unique_key=delta_key)
+            deltas = db.collection("deltas", unique_key=delta_key,
+                                   range_key=delta_key)
             deadline = time.time() + 120
             rows = []
             while time.time() < deadline:
@@ -292,7 +293,8 @@ class TestBrokerRestart:
                     type=MessageType.OPERATION, contents={"n": i})]))
 
         db = SqliteDatabaseManager(str(tmp_path / "fluid.sqlite"))
-        deltas = db.collection("deltas", unique_key=delta_key)
+        deltas = db.collection("deltas", unique_key=delta_key,
+                               range_key=delta_key)
 
         def wait_rows(n, worker, timeout=120):
             deadline = time.time() + timeout

@@ -553,11 +553,13 @@ class TestSequencedWindow:
         )
         from fluidframework_tpu.server.lambdas.scriptorium import (
             ScriptoriumLambda,
+            delta_key,
             query_deltas,
         )
         w = self._window()
         db = DatabaseManager()
-        deltas = db.collection("deltas")
+        deltas = db.collection("deltas", unique_key=delta_key,
+                               range_key=delta_key)
         sc = ScriptoriumLambda(_Ctx(), deltas)
         sc.handler(QueuedMessage("deltas", 0, 0, "__window__", w))
         rows = query_deltas(deltas, "d0")
