@@ -2,7 +2,7 @@
 # must be a one-liner anyone can repeat).
 
 .PHONY: test soak bench dryrun record-corpus historian-smoke \
-	summarize-smoke trace-smoke pipeline-smoke fused-smoke \
+	summarize-smoke trace-smoke pipeline-smoke \
 	paged-smoke catchup-smoke obs-smoke ingest-smoke e2e-smoke \
 	mega-smoke fleet-smoke bench-trend \
 	lint-analysis \
@@ -90,28 +90,19 @@ trace-smoke:
 	JAX_PLATFORMS=cpu python bench.py trace-smoke
 
 # CPU smoke of the deep-pipelined serving path (docs/serving_pipeline.md):
-# identical raw-wire waves through a synchronous and a ring-pipelined
+# identical raw-wire waves through a synchronous and a pipelined
 # sequencer must emit a BIT-IDENTICAL stream with identical lane state,
-# the in-flight ring must actually run deeper than one window, and warm
-# steady-state ingest must clear 1.3x the pinned BENCH_r05 CPU figure.
+# the flushes' megakernel rings must really defer to the next flush,
+# and warm steady-state ingest must clear 1.3x the pinned BENCH_r05 CPU
+# figure.
 pipeline-smoke:
 	JAX_PLATFORMS=cpu python bench.py pipeline-smoke
 
-# CPU smoke of the fused serving-burst path (docs/serving_pipeline.md
-# R8): identical raw-wire waves through a synchronous and a burst-
-# pipelined sequencer must emit an ORDER-identical stream, bursts must
-# actually form with <= 2 dispatches per burst (one scan + at most one
-# recovery) and < 1.0 dispatches per served window, and warm ingest at
-# the 512-doc shape must clear 1.15x the pinned BENCH_r06 ring figure.
-fused-smoke:
-	JAX_PLATFORMS=cpu python bench.py fused-smoke
-
-# CPU smoke of paged lane memory (docs/paged_memory.md): the storm-doc
-# ragged fleet must produce assembled snapshots BIT-IDENTICAL through
-# the paged and the bucketed (oracle-conformant) stores, fold/rescue
-# dispatches on that scenario must drop >= 5x (capacity ceremony gone),
-# and the warm gather-by-page-id ragged fleet must clear 1.5x the
-# pinned BENCH_r07 bucketed figure (9,687 ops/s) at the same shapes.
+# CPU smoke of paged lane memory (docs/paged_memory.md): every document
+# of the storm-doc ragged fleet must read back exactly the text its ops
+# wrote with no rescue or overflow drop, and the warm gather-by-page-id
+# ragged fleet must clear 1.5x the pinned BENCH_r07 bucketed figure
+# (9,687 ops/s) at the same shapes.
 paged-smoke:
 	JAX_PLATFORMS=cpu python bench.py paged-smoke
 
@@ -131,7 +122,7 @@ catchup-smoke:
 # to telemetry-off (emit stream + lane planes), the stats plane must ride
 # the existing readback (0 extra dispatches per window/burst), device-
 # counted op totals must reconcile EXACTLY with the host-side counts,
-# stats overhead must stay < 2% on the warm 512-doc fused shape, and the
+# stats overhead must stay < 2% on the warm 512-doc serving shape, and the
 # compile ledger (per-symbol compiles + cumulative compile ms) must be
 # stamped top-level in BENCH_OBS_LAST.json.
 obs-smoke:
@@ -202,12 +193,12 @@ fleet-smoke:
 	JAX_PLATFORMS=cpu python bench.py fleet-smoke
 
 # The pre-merge gate: layering/cycles + static analysis (incl. the
-# focused race and placement gates) + the summarize/trace/pipeline/fused/paged/catchup/
+# focused race and placement gates) + the summarize/trace/pipeline/paged/catchup/
 # overload/obs/ingest/e2e/mega/fleet smokes + the bench trend
 # (report-only here) + the full test suite.
 check: layer-check lint-analysis lint-races lint-placement \
 		summarize-smoke trace-smoke \
-		pipeline-smoke fused-smoke paged-smoke catchup-smoke \
+		pipeline-smoke paged-smoke catchup-smoke \
 		overload-smoke obs-smoke ingest-smoke e2e-smoke mega-smoke \
 		fleet-smoke test
 	python bench.py trend --report-only

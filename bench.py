@@ -105,13 +105,12 @@ def _serving_ingest_rate(docs: int = 4096, ops_per_doc: int = 32) -> dict:
 
     from fluidframework_tpu.telemetry import counters as _counters
 
-    # Fused-burst accounting rides process counters (cumulative): delta
-    # everything against this point so earlier bench groups can't leak
-    # into the serving stamps.
+    # Megakernel-ring accounting rides process counters (cumulative):
+    # delta everything against this point so earlier bench groups can't
+    # leak into the serving stamps.
     _b0 = {name: _counters.get(name) for name in (
         "serving.bursts", "serving.burst_windows",
-        "serving.window_dispatches", "serving.recovery_dispatches",
-        "serving.burst_fallbacks")}
+        "serving.recovery_dispatches", "serving.megakernel_fallbacks")}
 
     class _Ctx:
         def checkpoint(self, *_):
@@ -210,10 +209,10 @@ def _serving_ingest_rate(docs: int = 4096, ops_per_doc: int = 32) -> dict:
         raise RuntimeError(
             f"steady windows emitted {emitted} of {total} messages")
     # Lane-health counters snapshot FIRST: cumulative, and the latency
-    # waves below may legitimately fold — these must describe only the
-    # measured throughput waves (folds there mean the steady state
+    # waves below may legitimately rescue — these must describe only the
+    # measured throughput waves (rescues there mean the steady state
     # wasn't steady).
-    steady_folds = lam.merge.folds
+    steady_rescues = lam.merge.paged_rescues
     steady_drops = lam.merge.overflow_drops
     # Flush-latency distribution (the reference tracks op round-trip
     # latency, connectionTelemetry.ts): waves 6-8 re-drive the same
@@ -303,44 +302,34 @@ def _serving_ingest_rate(docs: int = 4096, ops_per_doc: int = 32) -> dict:
             _counters.get("serving.ring_donated_windows")),
         "serving_kept_windows": int(
             _counters.get("serving.ring_kept_windows")),
-        "serving_donation_enabled": bool(lam.donate_lane_states),
+        "serving_donation_enabled": bool(lam.merge.pages.donate),
         "serving_adaptive_window": bool(lam.adaptive_window),
     }
-    # Fused serving bursts (docs/serving_pipeline.md R8): the REAL
+    # Serving megakernel rings (docs/serving_pipeline.md R10): the REAL
     # serving-path fused_apply flag — true iff at least one scanned
-    # multi-window burst actually dispatched — plus dispatches-per-
-    # window across the whole run (scan + per-window + recovery
-    # dispatches over fast windows served; < 1.0 means bursts amortized
-    # the per-window round-trip). r06 stamped `fused_apply: false` from
-    # the capacity-gated kernel experiment; nothing on the serving path
-    # could ever set it.
+    # multi-window ring actually dispatched — plus dispatches-per-
+    # window across the whole run (ring + recovery dispatches over fast
+    # windows served; < 1.0 means rings amortized the per-window
+    # round-trip).
     bursts = int(_counters.get("serving.bursts") - _b0["serving.bursts"])
     burst_windows = int(_counters.get("serving.burst_windows")
                         - _b0["serving.burst_windows"])
-    solo_windows = int(_counters.get("serving.window_dispatches")
-                       - _b0["serving.window_dispatches"])
     recoveries = int(_counters.get("serving.recovery_dispatches")
                      - _b0["serving.recovery_dispatches"])
-    fast_windows = burst_windows + solo_windows
     burst_stats = {
-        "fused_apply": bursts > 0,
+        "fused_apply": burst_windows > bursts,
         "serving_fused_windows": burst_windows,
         "serving_bursts": bursts,
-        "serving_burst_fallbacks": int(
-            _counters.get("serving.burst_fallbacks")
-            - _b0["serving.burst_fallbacks"]),
-        # Dispatches per served WINDOW (< 1.0 = bursts amortized the
-        # per-window round-trip). `serving_dispatches_per_burst` is the
-        # ISSUE-7-mandated key for the same value; fused-smoke's
-        # `dispatches_per_burst` (scan + recovery per burst, graded
-        # <= 2) is a DIFFERENT quantity — compare per-window to
-        # per-window.
+        "serving_megakernel_fallbacks": int(
+            _counters.get("serving.megakernel_fallbacks")
+            - _b0["serving.megakernel_fallbacks"]),
+        # Dispatches per served WINDOW (< 1.0 = rings amortized the
+        # per-window round-trip); `serving_dispatches_per_burst` is the
+        # same value under its older key.
         "serving_dispatches_per_window": round(
-            (bursts + solo_windows + recoveries) / max(1, fast_windows),
-            4),
+            (bursts + recoveries) / max(1, burst_windows), 4),
         "serving_dispatches_per_burst": round(
-            (bursts + solo_windows + recoveries) / max(1, fast_windows),
-            4),
+            (bursts + recoveries) / max(1, burst_windows), 4),
     }
     return {"serving_ingest_ops_per_sec": round(total / elapsed, 1),
             "serving_ingest_warm_waves": warm_waves,
@@ -359,7 +348,7 @@ def _serving_ingest_rate(docs: int = 4096, ops_per_doc: int = 32) -> dict:
             "serving_flush_slo_budget": _slo.budget,
             "serving_flush_p99_over_p50": slo_ratio,
             "serving_flush_slo_ok": _slo.check(slo_p50, slo_p99),
-            "serving_ingest_folds": steady_folds,
+            "serving_ingest_rescues": steady_rescues,
             "serving_ingest_overflow_drops": steady_drops}
 
 
@@ -1270,9 +1259,8 @@ def main() -> None:
     # `paged` block.
     if ragged_buckets:
         pr = _paged_ragged_kernel_rate(ragged_buckets)
-        pstore, _, _ = _paged_store_scenario(
-            paged=True, waves=6, keystroke=64, storms=2, key_ops=8,
-            storm_ops=40)
+        pstore, _, _, _ = _paged_store_scenario(
+            waves=6, keystroke=64, storms=2, key_ops=8, storm_ops=40)
         pstats = pstore.paged_stats()
         checkpoint_partial(
             paged_ragged_ops_per_sec=pr["ragged_ops_per_sec"],
@@ -1280,7 +1268,7 @@ def main() -> None:
             paged_ragged_fill_frac=pr["page_fill_frac"],
             paged_pages_in_use=pstats["pages_in_use"],
             paged_page_fill_frac=pstats["page_fill_frac"],
-            paged_fold_count=pstore.folds + pstore.paged_rescues,
+            paged_fold_count=pstore.paged_rescues,
             paged_fold_rescue_dispatches=pstore.fold_rescue_dispatches,
             paged_pool_pages=pstats["pool_pages"],
             paged_page_compactions=pstats["page_compactions"])
@@ -1393,7 +1381,7 @@ def summarize_smoke() -> int:
     # Blob-cache pass through a real MergeLaneStore: a clean second
     # summarize is pure cache hits; an edit re-extracts only that lane.
     from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-    store = MergeLaneStore(capacities=(64,), lanes_per_bucket=8)
+    store = MergeLaneStore()
     keys = [("doc", "s", f"c{i}") for i in range(8)]
     store.apply({k: [store.builder.insert_text(0, f"text-{i} " * 4,
                                                0, 0, 1)]
@@ -1622,11 +1610,11 @@ def pipeline_smoke() -> int:
     pipeline-smoke`): drives identical raw-wire waves through a
     synchronous (pipelined=False) and a ring-pipelined sequencer and
     asserts the acceptance properties — the sequenced stream and final
-    lane state are BIT-IDENTICAL, the in-flight ring actually ran deeper
-    than one window, and warm steady-state ingest clears 1.3x the pinned
-    BENCH_r05 CPU figure. The throughput gate measures fully-warm shapes
-    (the promotion burst and its one-time XLA compiles land in the
-    warm-up waves), so the comparison against the r05 cold-campaign
+    lane state are BIT-IDENTICAL, the flushes' megakernel rings really
+    deferred (the next flush joined them), and warm steady-state ingest
+    clears 1.3x the pinned BENCH_r05 CPU figure. The throughput gate
+    measures fully-warm shapes (the page growth and its one-time XLA
+    compiles land in the warm-up waves), so the comparison against the r05 cold-campaign
     number is conservative on fast hosts and still meaningful on slow
     ones. Prints one JSON line; exit 0 iff every check passes."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -1729,7 +1717,8 @@ def pipeline_smoke() -> int:
         # Order included: an out-of-order drain would keep the multiset.
         "emits_bit_identical": sync_emits == ring_emits,
         "lane_state_bit_identical": sync_texts == ring_texts,
-        "ring_depth_exercised": peak > 1 and deferred > 0,
+        "ring_deferred": deferred > 0 and int(
+            _counters.get("serving.megakernel_rings")) > 0,
         "steady_rate_vs_r05_pin": ring_rate >= target,
     }
     print(json.dumps({
@@ -1755,11 +1744,6 @@ def pipeline_smoke() -> int:
     }))
     return 0 if all(checks.values()) else 1
 
-
-# The pinned BENCH_r06 CPU serving-ingest figure the fused smoke grades
-# against (serving_ingest_ops_per_sec from the committed BENCH_r06.json,
-# the honest warm-protocol ring figure at the 512-doc shape).
-R06_SERVING_INGEST_OPS = 13602.0
 
 # The pinned BENCH_r07 CPU ragged-fleet figure (ragged_ops_per_sec from
 # the committed BENCH_r07.json): the BUCKETED ragged workload — 10k docs
@@ -1936,19 +1920,17 @@ def _bucketed_ragged_kernel_rate(ragged_buckets) -> dict:
     }
 
 
-def _paged_store_scenario(paged: bool, waves: int = 10,
+def _paged_store_scenario(waves: int = 10,
                           keystroke: int = 128, storms: int = 4,
                           key_ops: int = 8, storm_ops: int = 60):
     """The storm-doc ragged fleet at STORE level (MergeLaneStore.apply,
     windowed): `keystroke` one-page documents type a few chars per
-    window while `storms` documents type deep — the shape that drives
-    the bucket grid's promote/fold/rescue ceremony (every keystroke doc
-    eventually overflows its 64-bucket; every storm doc climbs the grid
-    and refolds) and that paged storage absorbs with page appends.
-    Returns (store, elapsed_s, total_ops)."""
+    window while `storms` documents type deep — the shape paged storage
+    absorbs with page appends. Returns (store, elapsed_s, total_ops,
+    {key: expected text})."""
     from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
 
-    store = MergeLaneStore(paged=paged)
+    store = MergeLaneStore()
     b = store.builder
     seqs: dict = {}
 
@@ -1971,22 +1953,19 @@ def _paged_store_scenario(paged: bool, waves: int = 10,
             streams[("doc", "s", f"S{d}")] = stream(f"S{d}", storm_ops)
         total += keystroke * key_ops + storms * storm_ops
         store.apply(streams)
-    return store, time.perf_counter() - t0, total
+    expect = {("doc", "s", name): "x" * n for name, n in seqs.items()}
+    return store, time.perf_counter() - t0, total, expect
 
 
 def paged_smoke() -> int:
     """CPU smoke for paged lane memory (`make paged-smoke`,
     docs/paged_memory.md). Asserts the acceptance properties:
 
-      * bit-identity: the storm-doc ragged fleet produces IDENTICAL
-        assembled snapshots through the paged store and the bucketed
-        store (whose kernel is conformance-locked to mergetree/oracle.py
-        by tests/test_kernel.py — emit-order identity across engines is
-        locked by tests/test_paged_memory.py);
-      * the fold/rescue ceremony is actually gone: device recovery +
-        fold dispatches on the ragged scenario drop >= 5x vs the
-        bucketed run (paged capacity events are structurally
-        impossible — growth pre-proves page fit);
+      * content: every document of the storm-doc ragged fleet reads
+        back (text and assembled snapshot) exactly the text its ops
+        wrote;
+      * no capacity ceremony: no rescue and no overflow drop on the
+        ragged scenario (growth pre-proves page fit);
       * the warm paged ragged fleet — measured WINDOWED, the way the
         paged store serves: T-grid windows with exact-count page growth
         between them — clears 1.5x the pinned BENCH_r07 bucketed figure
@@ -2008,23 +1987,15 @@ def paged_smoke() -> int:
     br = _bucketed_ragged_kernel_rate(
         [(rb // 4, rt, rc) for rb, rt, rc in ragged_buckets])
 
-    store_b, b_s, total = _paged_store_scenario(paged=False)
-    store_p, p_s, _ = _paged_store_scenario(paged=True)
-    snaps_b = store_b.extract_all()
+    store_p, p_s, total, expect = _paged_store_scenario()
     snaps_p = store_p.extract_all()
 
-    # Per-char content comparison: engine-internal segmentation (folds,
-    # zamboni cadence) may differ; the flattened content must not —
-    # mergetree.host.flatten_snapshot_content docstring has the full
-    # rationale.
     from fluidframework_tpu.mergetree.host import flatten_snapshot_content
 
-    content_equal = set(snaps_b) == set(snaps_p) and all(
-        flatten_snapshot_content(snaps_p[k])
-        == flatten_snapshot_content(snaps_b[k]) for k in snaps_b)
-    texts_equal = all(store_p.text(k) == store_b.text(k)
-                      for k in snaps_b)
-    bucketed_disp = store_b.fold_rescue_dispatches
+    content_equal = set(snaps_p) == set(expect) and all(
+        flatten_snapshot_content(snaps_p[k]) == [(ch, ()) for ch in text]
+        for k, text in expect.items())
+    texts_equal = all(store_p.text(k) == text for k, text in expect.items())
     paged_disp = store_p.fold_rescue_dispatches
     st = store_p.paged_stats()
 
@@ -2036,13 +2007,11 @@ def paged_smoke() -> int:
     target = 1.5 * baseline
     checks = {
         "content_bit_identical": content_equal and texts_equal,
-        "fold_rescue_cut_ge_5x":
-            bucketed_disp >= 5 * max(1, paged_disp),
         "ragged_rate_ge_1_5x_bucketed":
             pr["ragged_ops_per_sec"] >= target,
         "ragged_no_overflow": not pr["overflow"],
         "no_capacity_ceremony_paged":
-            store_p.folds == 0 and store_p.overflow_drops == 0,
+            store_p.paged_rescues == 0 and store_p.overflow_drops == 0,
     }
     record = {
         "metric": "paged-smoke",
@@ -2058,12 +2027,8 @@ def paged_smoke() -> int:
         "gate_baseline_ops_per_sec": round(baseline, 1),
         "target_ops_per_sec": round(target, 1),
         "scenario_ops": total,
-        "scenario_bucketed_s": round(b_s, 3),
         "scenario_paged_s": round(p_s, 3),
-        "bucketed_fold_rescue_dispatches": bucketed_disp,
         "paged_fold_rescue_dispatches": paged_disp,
-        "fold_rescue_cut": round(bucketed_disp / max(1, paged_disp), 1),
-        "bucketed_folds": store_b.folds,
         "paged_rescues": store_p.paged_rescues,
         "pages_in_use": st["pages_in_use"],
         "page_fill_frac": st["page_fill_frac"],
@@ -2301,203 +2266,6 @@ def catchup_smoke() -> int:
     return 0 if all(checks.values()) else 1
 
 
-def fused_smoke() -> int:
-    """CPU smoke for the fused serving-burst path (`make fused-smoke`,
-    docs/serving_pipeline.md R8): drives identical raw-wire waves at the
-    512-doc BENCH shape through a synchronous (pipelined=False) and a
-    burst-pipelined sequencer and asserts the acceptance properties —
-
-      * the sequenced emit stream is ORDER-identical to the sync path
-        (a burst that reordered across its scanned windows would keep
-        the multiset and still fail here);
-      * bursts actually formed, and dispatch cost stayed fused: the
-        average dispatches per burst (one scan + any recovery re-runs
-        its windows triggered) is <= 2, and dispatches per served fast
-        window is < 1.0 — the per-window host round-trip amortized
-        instead of merely overlapping;
-      * warm steady-state ingest clears 1.15x the pinned BENCH_r06 CPU
-        figure (the ring path's honest warm-protocol number at this
-        exact shape), so the burst route is a measured win over the
-        ring baseline, not a refactor-neutral rewire.
-
-    Prints one JSON line; exit 0 iff every check passes."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import json as _json
-    import random as _random
-
-    import jax
-
-    from fluidframework_tpu.mergetree.client import OP_INSERT
-    from fluidframework_tpu.protocol.messages import (Boxcar,
-                                                      DocumentMessage,
-                                                      MessageType)
-    from fluidframework_tpu.server.log import QueuedMessage
-    from fluidframework_tpu.server.tpu_sequencer import TpuSequencerLambda
-    from fluidframework_tpu.server.wire import boxcar_to_wire
-    from fluidframework_tpu.telemetry import counters as _counters
-
-    # The 512-doc CPU BENCH shape, warm past EVERY lockstep cliff: the
-    # 64->256 promotion (~wave 6), the first 3/4-threshold fold (192
-    # rows, wave 12) — and, unlike the r06-era warm formula, the
-    # 256->1024 promotion at 256 rows (wave 16): at this shape that
-    # cliff's recovery + one-time XLA compiles landed INSIDE r06's
-    # measured waves (observed here: one 2.8 s wave in a ~5 s window),
-    # which is part of why the committed 13602 pin is conservative
-    # against an honestly-warm steady state.
-    docs, ops_per_doc, steady_waves = 512, 16, 3
-    warm_waves = -(-256 // ops_per_doc) + 2
-
-    class _Ctx:
-        def checkpoint(self, *_):
-            pass
-
-        def error(self, err, restart=False):
-            raise err
-
-    def build_wave(wave: int):
-        rng = _random.Random(31 + wave)
-        out = []
-        base = wave * ops_per_doc
-        for d in range(docs):
-            doc = f"f{d}"
-            contents = []
-            if wave == 0:
-                contents.append(DocumentMessage(
-                    client_sequence_number=0,
-                    reference_sequence_number=-1,
-                    type=MessageType.CLIENT_JOIN,
-                    data=_json.dumps({"clientId": f"c{d}", "detail": {}})))
-            for i in range(ops_per_doc):
-                contents.append(DocumentMessage(
-                    client_sequence_number=base + i + 1,
-                    reference_sequence_number=base,
-                    type=MessageType.OPERATION,
-                    contents={"address": "s", "contents": {
-                        "address": "t", "contents": {
-                            "type": OP_INSERT, "pos1": 0,
-                            "seg": {"text": "z" * rng.randrange(1, 3)}}}}))
-            out.append(QueuedMessage(
-                topic="rawdeltas", partition=0, offset=wave * docs + d,
-                key=doc,
-                value=boxcar_to_wire(Boxcar(
-                    tenant_id="b", document_id=doc, client_id=f"c{d}",
-                    contents=contents))))
-        return out
-
-    # Shape-warm cycles after the bulk warm-up: the steady region's
-    # drain pattern (a K=2 scan + one solo window per 3-wave cycle)
-    # must have compiled BEFORE measurement, same contract as the bulk
-    # warm-up's promotion/fold cliffs.
-    shape_cycles = 2
-    total_waves = warm_waves + 3 * shape_cycles + steady_waves
-    waves = {w: build_wave(w) for w in range(total_waves)}
-
-    def run(pipelined: bool):
-        emitted = []
-
-        def on_window(window):
-            for doc_id, msg in window.messages():
-                emitted.append((doc_id, msg.sequence_number,
-                                msg.minimum_sequence_number,
-                                msg.client_id,
-                                msg.client_sequence_number))
-
-        lam = TpuSequencerLambda(_Ctx(), emit=lambda *a: None,
-                                 nack=lambda *a: None,
-                                 client_timeout_s=0.0)
-        lam.emit_window = on_window
-        lam.pipelined = pipelined
-        for w in range(warm_waves):
-            for qm in waves[w]:
-                lam.handler(qm)
-            lam.flush()
-        lam.drain()
-        for cyc in range(shape_cycles):
-            for w in range(warm_waves + 3 * cyc,
-                           warm_waves + 3 * (cyc + 1)):
-                for qm in waves[w]:
-                    lam.handler(qm)
-                lam.flush()
-            lam.drain()
-        base = warm_waves + 3 * shape_cycles
-        # Deterministic GC-phase alignment: the lane-compaction cadence
-        # (compact_every flushes) is identical steady-state cost in both
-        # modes, but WHERE the tick lands is mode-dependent bookkeeping
-        # — the sync path pays it spread across warm flush boundaries
-        # while the pipelined path defers it to a drain, and a 3-wave
-        # region cannot amortize a multi-second tick landing inside
-        # only one mode's window. Settle any due tick here and zero the
-        # cadence so the next one falls beyond the measured flushes for
-        # BOTH runs.
-        if lam._gc_due:
-            lam._run_fast_gc()
-        lam.merge.flushes_since_compact = 0
-        lam.lww.windows_since_value_compact = 0
-        t0 = time.perf_counter()
-        for w in range(base, base + steady_waves):
-            for qm in waves[w]:
-                lam.handler(qm)
-            lam.flush()
-        lam.drain()
-        elapsed = time.perf_counter() - t0
-        return emitted, steady_waves * docs * ops_per_doc / elapsed
-
-    _counters.reset()
-    sync_emits, sync_rate = run(False)
-    _counters.reset()
-    burst_emits, burst_rate = run(True)
-
-    bursts = int(_counters.get("serving.bursts"))
-    burst_windows = int(_counters.get("serving.burst_windows"))
-    solo_windows = int(_counters.get("serving.window_dispatches"))
-    recoveries = int(_counters.get("serving.recovery_dispatches"))
-    fast_windows = burst_windows + solo_windows
-    dispatches_per_window = (bursts + solo_windows + recoveries) \
-        / max(1, fast_windows)
-    # Average dispatches a drained burst actually cost (1 scan + any
-    # recovery re-runs its windows' finish triggered), accumulated at
-    # drain time into serving.burst_dispatch_total.
-    dispatches_per_burst = _counters.get("serving.burst_dispatch_total") \
-        / max(1, bursts)
-    target = 1.15 * R06_SERVING_INGEST_OPS
-    checks = {
-        # Order included: an out-of-order burst drain would keep the
-        # multiset.
-        "emits_bit_identical": sync_emits == burst_emits,
-        "bursts_formed": bursts > 0 and burst_windows >= 2 * bursts,
-        "dispatches_per_burst_le_2": 0 < dispatches_per_burst <= 2.0,
-        "dispatches_per_window_lt_1": dispatches_per_window < 1.0,
-        "steady_rate_vs_r06_pin": burst_rate >= target,
-    }
-    record = {
-        "metric": "fused-smoke",
-        "backend": jax.default_backend(),
-        "docs": docs, "ops_per_doc": ops_per_doc,
-        "waves_warm": warm_waves, "waves_measured": steady_waves,
-        "steady_state_warm": True,
-        "sync_ops_per_sec": round(sync_rate, 1),
-        "burst_ops_per_sec": round(burst_rate, 1),
-        "burst_vs_sync": round(burst_rate / sync_rate, 2)
-        if sync_rate else 0.0,
-        "r06_pinned_ops_per_sec": R06_SERVING_INGEST_OPS,
-        "target_ops_per_sec": round(target, 1),
-        "bursts": bursts,
-        "burst_windows": burst_windows,
-        "window_dispatches": solo_windows,
-        "recovery_dispatches": recoveries,
-        "burst_fallbacks": int(_counters.get("serving.burst_fallbacks")),
-        "dispatches_per_burst": round(dispatches_per_burst, 3),
-        "dispatches_per_window": round(dispatches_per_window, 4),
-        "checks": checks,
-        "ok": all(checks.values()),
-    }
-    _write_json_atomic(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "BENCH_FUSED_LAST.json"), record)
-    print(json.dumps(record))
-    return 0 if all(checks.values()) else 1
-
-
 def mega_smoke() -> int:
     """CPU smoke for the R10 serving megakernel (`make mega-smoke`,
     docs/serving_pipeline.md R10): a ragged contended fleet (one storm
@@ -2612,7 +2380,8 @@ def mega_smoke() -> int:
         # Best of `reps` measured regions: the smoke grades warm
         # steady-state capability; a container scheduling hiccup in one
         # region must not fail a 2x architecture gate. GC settles
-        # before each region (fused_smoke's cadence-alignment rule).
+        # before each region, so every region starts on the same
+        # compaction cadence.
         best = 0.0
         for rep in range(reps):
             if lam._gc_due:
@@ -3651,14 +3420,15 @@ def e2e_smoke() -> int:
 def obs_smoke() -> int:
     """CPU smoke for the device-resident telemetry planes + compile
     observatory (`make obs-smoke`, docs/observability.md v2). Drives
-    identical raw-wire waves at the warm 512-doc fused-smoke shape
-    through a burst-pipelined sequencer with device stats OFF and ON
-    and gates the tentpole's contracts:
+    identical raw-wire waves at a warm 512-doc shape through a
+    pipelined sequencer with device stats OFF and ON and gates the
+    tentpole's contracts:
 
       * telemetry-on serving is BIT-IDENTICAL to telemetry-off: the
-        sequenced emit stream AND the post-run lane planes (every
-        merge/LWW bucket's device state + the ticket state) hash equal;
-      * zero extra dispatches: window/burst dispatch counters are
+        sequenced emit stream AND the post-run lane planes (the page
+        pool + tables, every LWW bucket's device state, the ticket
+        state) hash equal;
+      * zero extra dispatches: ring dispatch counters are
         identical between the runs (the stats plane rides the existing
         flat16 readback);
       * device-vs-host reconciliation is EXACT: every countable
@@ -3690,7 +3460,7 @@ def obs_smoke() -> int:
     from fluidframework_tpu.telemetry import device_stats
     from fluidframework_tpu.telemetry.compile_ledger import ledger
 
-    docs, ops_per_doc = 512, 16  # the fused-smoke shape
+    docs, ops_per_doc = 512, 16
     warm_waves = -(-256 // ops_per_doc) + 2
     steady_waves = 2
     # 8 pairs: a 6-pair median was thin enough for scheduler noise to
@@ -3741,12 +3511,13 @@ def obs_smoke() -> int:
 
     def lane_digest(lam) -> str:
         """SHA-256 over every lane plane the serving tier owns: the
-        merge/LWW bucket states and the ticket state, fetched to host.
-        Bit-identity means EQUAL DIGESTS, not merely equal emits."""
+        page pool and its tables, the LWW bucket states and the ticket
+        state, fetched to host. Bit-identity means EQUAL DIGESTS, not
+        merely equal emits."""
         h = hashlib.sha256()
-        for bucket in lam.merge.buckets:
-            for leaf in jax.tree_util.tree_leaves(bucket.state):
-                h.update(np.asarray(leaf).tobytes())
+        for leaf in jax.tree_util.tree_leaves(lam.merge.pages.pool):
+            h.update(np.asarray(leaf).tobytes())
+        h.update(repr(sorted(lam.merge.pages.tables.items())).encode())
         for bucket in lam.lww.buckets:
             for leaf in jax.tree_util.tree_leaves(bucket.state):
                 h.update(np.asarray(leaf).tobytes())
@@ -3778,8 +3549,6 @@ def obs_smoke() -> int:
             lam.flush()
         lam.drain()
         dispatch_counts = {
-            "window_dispatches": int(
-                _counters.get("serving.window_dispatches")),
             "bursts": int(_counters.get("serving.bursts")),
             "burst_windows": int(_counters.get("serving.burst_windows")),
             "recovery_dispatches": int(
@@ -4492,8 +4261,6 @@ if __name__ == "__main__":
         sys.exit(trace_smoke())
     if len(sys.argv) > 1 and sys.argv[1] == "pipeline-smoke":
         sys.exit(pipeline_smoke())
-    if len(sys.argv) > 1 and sys.argv[1] == "fused-smoke":
-        sys.exit(fused_smoke())
     if len(sys.argv) > 1 and sys.argv[1] == "paged-smoke":
         sys.exit(paged_smoke())
     if len(sys.argv) > 1 and sys.argv[1] == "catchup-smoke":

@@ -323,16 +323,15 @@ def _stream_recorder():
 
 
 def serve(waves, mesh=None):
-    """Feed the waves through TpuSequencerLambda.handler_raw, pipelined on
-    paged lanes, flushing each wave and draining only at the end."""
+    """Feed the waves through TpuSequencerLambda.handler_raw, pipelined,
+    flushing each wave and draining only at the end."""
     from fluidframework_tpu.server.tpu_sequencer import TpuSequencerLambda
 
     streams, record = _stream_recorder()
     nacks = []
     lam = TpuSequencerLambda(_Ctx(), emit=record,
                              nack=lambda *a: nacks.append(a),
-                             client_timeout_s=0.0, paged_lanes=True,
-                             mesh=mesh)
+                             client_timeout_s=0.0, mesh=mesh)
     lam.pipelined = True
     for wave in waves:
         for qm in wave:
@@ -402,8 +401,7 @@ def _serving_faults(base=None) -> dict:
     snap = counters.snapshot()
     base = base or {}
     faults = {name: snap.get(name, 0.0) - base.get(name, 0.0) for name in (
-        "serving.megakernel_fallbacks", "serving.burst_fallbacks",
-        "sequencer.fused_degrades")}
+        "serving.megakernel_fallbacks",)}
     faults.update({k: v - base.get(k, 0.0) for k, v in snap.items()
                    if v != base.get(k, 0.0) and (
         k.startswith("swallowed.pallas.") or k in (
@@ -518,7 +516,7 @@ def loader_phase(min_segments: int = 1000, tail_ops: int = 3000,
     from fluidframework_tpu.server.local_server import TpuLocalServer
     from fluidframework_tpu.telemetry import counters
 
-    server = TpuLocalServer(paged_lanes=True)
+    server = TpuLocalServer()
     loader = Loader(LocalDocumentServiceFactory(server))
     c1 = loader.create_detached("doc")
     ds = c1.runtime.create_datastore("default")

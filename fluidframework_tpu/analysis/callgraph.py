@@ -3,10 +3,10 @@
 fluidlint v1 rules were single-module by design: a rule saw one
 ``ModuleContext`` and pattern-matched names. The donated-buffer
 lifecycle rules (lifecycle_rules.py) need more — at a call site in
-``tpu_sequencer.py`` they must know that ``serve_step.serve_burst``
+``tpu_sequencer.py`` they must know that ``serve_step.serve_megakernel``
 donates its first three arguments, even though that fact lives in a
-``functools.partial(jax.jit, donate_argnums=(0, 1, 2))(_serve_burst)``
-assignment two modules away. This module builds that map once per run:
+``functools.partial(jax.jit, donate_argnums=(0, 1, 2), …)``
+``(_serve_megakernel)`` assignment two modules away. This module builds that map once per run:
 
 * every function/method def in the analyzed tree, keyed by qualname
   (``module:func`` / ``module:Class.method``);

@@ -149,7 +149,7 @@ def _jnp_calls(node: ast.AST):
                 "compile time) scales with the trip count, and a "
                 "data-dependent count retraces per shape. Use lax.scan/"
                 "fori_loop, or suppress when the unroll is deliberately "
-                "bounded (e.g. the per-bucket serve_window unroll).")
+                "bounded (e.g. the per-group serving-window unroll).")
 def retrace_hazard(ctx: ModuleContext) -> Iterator[Violation]:
     for node in ast.walk(ctx.tree):
         if not isinstance(node, (ast.For, ast.While)):
@@ -248,14 +248,14 @@ def dtype_drift(ctx: ModuleContext) -> Iterator[Violation]:
             f"({', '.join(sorted(canonical))})")
 
 
-# Host-callback hazards inside scan/while_loop bodies: the fused burst
-# program (serve_step.serve_burst) runs pack→apply→extract for K windows
-# inside ONE lax.scan precisely to remove per-window host round-trips —
-# an io_callback/debug.callback re-entering the host per scan step (or a
-# block_until_ready forcing a device sync at trace/staging time) would
-# silently reintroduce the serialized RPC the fusion exists to delete,
-# K times per burst. The R10 megakernel raises the stakes: a
-# pl.pallas_call kernel body IS the persistent device program — a host
+# Host-callback hazards inside scan/while_loop bodies: the serving
+# megakernel (serve_step.serve_megakernel) runs ticket→apply→extract for
+# K windows inside ONE lax.scan precisely to remove per-window host
+# round-trips — an io_callback/debug.callback re-entering the host per
+# scan step (or a block_until_ready forcing a device sync at
+# trace/staging time) would silently reintroduce the serialized RPC the
+# fusion exists to delete, K times per ring. A pl.pallas_call kernel
+# body raises the stakes: it IS the persistent device program — a host
 # callback there cannot lower at all on TPU (the fallback would eat the
 # whole kernel, silently), and a sync at trace time stalls the one-in-
 # flight megakernel ring.
@@ -397,9 +397,9 @@ def scan_host_callback(ctx: ModuleContext) -> Iterator[Violation]:
                         f"round-trip, serializing the scanned program")
 
 
-# serve/window joined step/apply when serve_window gained lane-state
-# donation (donate_argnums=(0, 2, 4)): the serving window threads the
-# ticket state plus every merge/LWW lane plane per flush, so a dropped
+# serve/window joined step/apply when the serving program gained
+# lane-state donation: the serving megakernel threads the ticket state,
+# the page pool and every LWW lane plane per flush, so a dropped
 # donation there doubles peak HBM on the hottest path in the system.
 # Word-ish anchoring so names that merely CONTAIN a keyword (observe,
 # reserved, stepper-adjacent helpers like `misapply`) don't fire.

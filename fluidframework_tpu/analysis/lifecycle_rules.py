@@ -20,8 +20,8 @@ that has produced this repo's costliest bug class three PRs running
   blind. Scope, triggers, and message shape are unchanged from v1.
 
 Sanctioned patterns are modeled as guards, not blanket suppressions:
-``serve_window_keep``-style non-donating variants simply resolve to a
-smaller donation signature; the burst fallback's
+``serve_megakernel_keep``-style non-donating variants simply resolve to
+a smaller donation signature; the megakernel fallback's
 liveness-probe-then-reraise (``tree_leaves``/``.is_deleted()``, also
 through ``map(_gone, states)``) is recognized as a metadata read; and
 the canonical ``state, ys = step(state, xs)`` rebind kills the donation
@@ -78,8 +78,8 @@ def _enclosing_class(ctx: ModuleContext, fn: ast.AST) -> Optional[str]:
 
 def _jit_wrapped_defs(ctx: ModuleContext, program) -> Set[str]:
     """Names of module functions consumed by an assignment jit wrapper
-    (``serve_burst = partial(jax.jit, …)(_serve_burst)``): their bodies
-    are traced code exactly like decorator-jitted ones."""
+    (``serve_megakernel = partial(jax.jit, …)(_serve_megakernel)``): their
+    bodies are traced code exactly like decorator-jitted ones."""
     from .callgraph import module_name_for_path
     mod = program.index.modules.get(module_name_for_path(ctx.path))
     if mod is None:

@@ -447,12 +447,13 @@ def apply_if_any(apply_fn, state: DocState, active) -> DocState:
     ``apply_fn(state)`` when ``active`` (any real op in the block), else
     return ``state`` unchanged.
 
-    This is the burst scan's padding shortcut (serve_step.serve_burst):
-    stacking K serving windows into one scanned program pads every
-    window to the union of staged buckets, so a window that staged
-    nothing for a bucket carries an all-NOOP op plane there — and a
-    NOOP stream is an exact identity on DocState (every phase masks on
-    the op kind; locked by the burst bit-identity tests), so skipping
+    This is the megakernel scan's padding shortcut
+    (serve_step.serve_megakernel): stacking K serving windows into one
+    scanned program pads every window to the union of staged LWW
+    buckets, so a window that staged nothing for a bucket carries an
+    all-NOOP op plane there — and a NOOP stream is an exact identity on
+    the lane state (every phase masks on the op kind; locked by the ring
+    bit-identity tests), so skipping
     the T-step apply is free correctness-wise and saves the full
     scan-kernel cost of the padded window. NOT jitted here: it traces
     inside the caller's program (the scan body)."""

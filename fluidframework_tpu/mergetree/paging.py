@@ -1,16 +1,15 @@
 """Paged lane memory: fixed-size segment pages + per-doc page tables.
 
-The capacity-bucket grid (tpu_sequencer._MergeBucket) pads every lane to
-a bucket depth, so one storm document drags its whole bucket up the grid
-and long documents trigger fold/rescue/promotion ceremonies whose only
-reason to exist is that buckets are fixed-size. This module stores
-segment rows in fixed-size PAGES instead (Ragged Paged Attention's
-recipe, PAPERS.md): a device-resident pool of `[n_pages, PAGE_ROWS]`
-flat16-column pages, a host-side per-doc page table of int32 page ids,
-and a refcounted free-list allocator. Document growth is "append a page
-+ one page-table row write" — no row ever moves on growth, because the
-apply-time view is GATHERED from the doc's own pages
-(kernel.gather_pages) rather than stored contiguously.
+The merge lanes' storage (tpu_sequencer.MergeLaneStore): segment rows
+live in fixed-size PAGES (Ragged Paged Attention's recipe, PAPERS.md)
+rather than in one contiguous row per document padded to a fixed
+capacity, so a storm document pads nothing but its own last page and
+growth needs no promotion or fold — a device-resident pool of
+`[n_pages, PAGE_ROWS]` flat16-column pages, a host-side per-doc page
+table of int32 page ids, and a refcounted free-list allocator. Document
+growth is "append a page + one page-table row write" — no row ever moves
+on growth, because the apply-time view is GATHERED from the doc's own
+pages (kernel.gather_pages) rather than stored contiguously.
 
 Invariants (asserted, docs/paged_memory.md):
 - page 0 is the reserved BLANK page: never allocated, always zeroed;
@@ -27,8 +26,7 @@ Invariants (asserted, docs/paged_memory.md):
 Zamboni becomes page-granular: trailing pages wholly past the live row
 count release immediately after every apply (`release_trailing`), and
 only fragmented documents pay a gather-compact-scatter pass, budgeted
-per tick (MergeLaneStore._compact_tick_paged) exactly like the bucketed
-path's fold_budget_per_tick.
+per tick (MergeLaneStore._compact_tick_paged, compact_budget_per_tick).
 """
 
 from __future__ import annotations
@@ -161,9 +159,8 @@ def pages_for(rows: int, page_rows: int = PAGE_ROWS) -> int:
 
 def pow2_pages(n: int) -> int:
     """The page-count bucket: page-table widths pad to powers of two so
-    the compiled (B, P, T) apply shapes stay bounded at log2 variants —
-    the paged analog of the capacity-bucket grid, except only the
-    GATHERED VIEW pads; storage stays O(actual pages)."""
+    the compiled (B, P, T) apply shapes stay bounded at log2 variants.
+    Only the GATHERED VIEW pads; storage stays O(actual pages)."""
     return 1 << max(n - 1, 0).bit_length()
 
 
@@ -448,9 +445,9 @@ class PagedMergeStore:
 
     def page_fill_frac(self) -> float:
         """Live rows / allocated page rows across all documents — the
-        anti-padding headline: the bucketed grid's analog (rows /
-        bucket capacity) decays toward 0 as one storm doc drags its
-        whole bucket up the grid; pages keep it near 1."""
+        anti-padding headline: a fixed-capacity layout's analog (rows /
+        capacity) decays toward 0 as one storm doc drags every lane up
+        to its depth; pages keep it near 1."""
         rows = sum(len(t) for t in self.tables.values()) * self.page_rows
         if not rows:
             return 1.0

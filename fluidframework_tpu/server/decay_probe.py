@@ -2,18 +2,17 @@
 flat as documents age?
 
 Drives waves of same-document typing boxcars through the REAL
-TpuSequencerLambda raw fast path and reports per-wave rates. Before the
-host zamboni pack (PERF.md round-5 addendum 2), steady-state throughput
-decayed 139k -> 75k -> 17k ops/s on the CPU host as lanes climbed
-capacity buckets (apply cost scales with C); with the overflow-time fold
-it stays flat forever, with a bounded fold wave every ~capacity/window
-waves.
+TpuSequencerLambda raw fast path and reports per-wave rates. Merge lanes
+live in the page pool (docs/paged_memory.md): a document grows by
+appending pages and the compaction tick defrags the busiest documents
+under a per-tick budget, so steady-state throughput should stay flat,
+with a bounded maintenance wave at each compaction tick.
 
     python -m fluidframework_tpu.server.decay_probe               # quick
     python -m fluidframework_tpu.server.decay_probe --docs 256 \
         --ops 16 --waves 40
 
-Prints one JSON line: fast-wave median rate, fold-wave stats, sustained
+Prints one JSON line: fast-wave median rate, maintenance-wave count, sustained
 rate, and lane-health counters. Exit nonzero if the LAST quartile of
 fast waves is >2x slower than the first (decay = the regression this
 tool exists to catch).
@@ -101,12 +100,12 @@ def run(docs: int, ops: int, waves: int) -> dict:
         # of the reading living only in this process's stdout.
         counters.gauge("decay_probe.wave_ops_s", rates[-1])
         counters.increment("decay_probe.waves")
-    # Warmup (compiles, first promotions) = first quarter; classify the
-    # rest into fast waves vs maintenance (fold) waves by median gap.
+    # Warmup (compiles, first page growth) = first quarter; classify the
+    # rest into fast waves vs maintenance (compaction) waves by median gap.
     tail = rates[waves // 4:]
     med = sorted(tail)[len(tail) // 2]
     fast = [r for r in tail if r >= med / 3]
-    folds = [r for r in tail if r < med / 3]
+    slow = [r for r in tail if r < med / 3]
     total_ops = docs * ops * len(tail)
     sustained = total_ops / sum(docs * ops / r for r in tail)
     q = max(1, len(fast) // 4)
@@ -125,9 +124,10 @@ def run(docs: int, ops: int, waves: int) -> dict:
         "fast_wave_median_ops_per_sec": round(med, 1),
         "fast_wave_first_quartile_median": round(first_q, 1),
         "fast_wave_last_quartile_median": round(last_q, 1),
-        "maintenance_waves": len(folds),
+        "maintenance_waves": len(slow),
         "sustained_ops_per_sec": round(sustained, 1),
-        "folds": lam.merge.folds,
+        "page_compactions": lam.merge.page_compactions,
+        "paged_rescues": lam.merge.paged_rescues,
         "payload_compactions": lam.merge.payload_compactions,
         "blocks_aged": lam.merge.blocks_aged,
         "overflow_drops": lam.merge.overflow_drops,

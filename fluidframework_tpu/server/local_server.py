@@ -692,15 +692,18 @@ class TpuLocalServer(LocalServer):
     and merge/LWW channel lanes shard over its 'dp' axis (multi-chip
     serving; parallel/mesh.py).
 
-    paged_lanes: store merge segment rows in the refcounted page pool
-    (per-doc page tables, gather-by-page-id applies) instead of the
-    capacity-bucket grid — document growth appends pages, no
-    promote/fold/rescue (docs/paged_memory.md). Single-chip only.
+    paged_lanes: accepted only as True (False raises ValueError). Merge
+    lanes always live in the page pool (docs/paged_memory.md); the
+    keyword remains because the benchmark's harness and configs still
+    pass it, and it goes when a benchmark change drops it from them.
     """
 
-    def __init__(self, *args, mesh=None, paged_lanes=False, **kwargs):
+    def __init__(self, *args, mesh=None, paged_lanes=True, **kwargs):
+        if not paged_lanes:
+            raise ValueError(
+                "paged_lanes must be True: merge lanes always live in the "
+                "page pool")
         self.mesh = mesh
-        self.paged_lanes = paged_lanes
         super().__init__(*args, **kwargs)
         # Read-path catch-up artifacts (server/readpath.py): ON by
         # default — the cache is empty until a refresh or a read-miss
@@ -732,8 +735,7 @@ class TpuLocalServer(LocalServer):
             storage=lambda doc_id: self.historian.read_summary(
                 self.tenant_id, doc_id),
             config=self.config,
-            send_system=self._send_system,
-            paged_lanes=getattr(self, "paged_lanes", False))
+            send_system=self._send_system)
         self.tpu_sequencers.append(lam)
         return lam
 

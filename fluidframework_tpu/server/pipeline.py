@@ -44,8 +44,8 @@ def make_full_step(sp_shards: int = 1, fused_apply: bool = False,
     drives (stamped `fused_apply_kernel_exp` in BENCH records since
     round 8). The PRODUCTION serving path's `fused_apply` stamp means
     something stronger — the sequencer actually ran scanned multi-window
-    serving bursts (serve_step.serve_burst,
-    docs/serving_pipeline.md R8)."""
+    serving megakernels (serve_step.serve_megakernel,
+    docs/serving_pipeline.md R10)."""
 
     def full_step(tstate, mstate, raw, ops):
         """(ticket_state, merge_state, RawOps, PackedOps) ->

@@ -1,23 +1,30 @@
 """The fast serving flush's device program (tpu_sequencer._flush_raw).
 
-One fused jit per window: [B, T] deli ticketing for the whole partition,
-then per capacity bucket the merge/LWW apply — each op's assigned seq/msn
-gathered from the ticket output by (doc lane, step), the admitted-ops-only
+ONE jitted program per ring of K fast windows — the serving megakernel
+(``serve_megakernel``, docs/serving_pipeline.md R10): gather each page
+group's documents from the page pool once, then scan the K windows, each
+doing [B, T] deli ticketing for the whole partition, then per page group
+and LWW bucket the merge/LWW apply — each op's assigned seq/msn gathered
+from the ticket output by (doc lane, step), the admitted-ops-only
 discipline of pipeline.full_step generalized to channel lanes that live in
 a different lane space than documents — and finally everything the host
-needs packed into ONE int32 vector (per-op seq/msn, nack flags, per-doc
-next_seq, overflow summary bits). Every dispatch and every fetch pays a
-fixed host cost, so the window is exactly one dispatch and one D2H.
+needs packed into ONE int16 vector per window (per-op seq/msn, nack flags,
+per-doc next_seq, overflow summary bits, exact post scalars); then
+scatter the group views back. Every dispatch and every fetch pays a fixed
+host cost, so a ring is exactly one dispatch and one D2H.
+
+``serve_paged_burst`` is the object path's K-chunk apply over the same
+pool (tpu_sequencer.MergeLaneStore._apply_paged).
 
 Reference analog: deli/lambda.ts:142 ticket() feeding downstream lambdas;
 the merge/LWW applies play Scribe's materialization role fused into the
-same device window.
+same device program.
 
 Observability: the WHOLE program is one dispatch by design, so host-side
 tracing (telemetry/tracing.py) cannot subdivide it — the serving flush's
 named sub-spans bracket it instead: ``serving.pack`` (staging the cols
 this function consumes), ``serving.dispatch`` (this jit call),
-``serving.readback`` (the flat16 D2H), with fold/rescue and payload GC
+``serving.readback`` (the flat16 D2H), with settle/rescue and payload GC
 as their own host stages. Each feeds a ``serving.*`` histogram on
 ``/metrics.prom`` (docs/observability.md).
 """
@@ -37,24 +44,32 @@ from . import ticket_kernel as tk
 
 def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
                        lww_states, lww_cols, fused=False, merge_runs=None,
-                       noop_skip=False, stats=False, paged_scalars=False):
-    """The traced body shared by ``serve_window`` (one jitted window),
-    ``serve_window_keep`` (the non-donating recovery variant), and
-    ``serve_burst``'s scan step (K windows in one program).
+                       stats=False):
+    """One fast window: the traced scan body of ``serve_megakernel``.
 
-    ``noop_skip`` is the burst-padding escape hatch: stacked burst
-    windows pad to the union of staged buckets, so a bucket a window
-    never staged carries an all-NOOP op plane — with the flag set, each
-    bucket's apply is lax.cond-guarded on "any real op", skipping the
-    whole T-step apply for padding (kernel.apply_if_any; a NOOP stream
-    is an exact identity on the lane state either way, so results are
-    bit-identical — the guard only saves the padded window's compute).
-    Single-window callers keep it off: the cond costs a predicate per
-    bucket and a real window always has work.
+    The merge states are gathered page-group views whose post scalars
+    the host must adopt (paged scalars are host-authoritative between
+    flushes), so each merge apply also produces the narrow tuple
+    (overflow int16, count, min_seq, seq) — extracted IN-KERNEL by the
+    fused pallas program on its last op step, or derived identically by
+    the scan fallback — and flat16 carries a per-group int32-halves
+    scalar tail (the ``paged_tail`` packing below). ``fused ==
+    "interpret"`` runs the SAME pallas program through the pallas
+    interpreter so CPU tier-1 exercises the identical kernel; any other
+    truthy ``fused`` means Mosaic-lowered.
 
-    ticket_cols: [4, B, T] int32 (kind, client, cseq, refseq) — ONE H2D.
-    merge_cols:  per bucket [12, lanes, Tm] (10 PackedOps columns +
-                 doc_idx + t_idx) — ONE H2D each.
+    Ring windows pad to the union of staged groups/buckets, so a group a
+    window never staged carries an all-NOOP op plane: each apply is
+    lax.cond-guarded on "any real op", skipping the whole T-step apply
+    for padding (a NOOP stream is an exact identity on the lane state
+    either way, so results are bit-identical — the guard only saves the
+    padded window's compute).
+
+    ticket_cols: [4, B, T] int32 (kind, client, cseq, refseq).
+    merge_cols:  per group [12, lanes, Tm] (10 PackedOps columns +
+                 doc_idx + t_idx).
+    merge_runs:  per group [4, lanes, Tm, RUN_K] INSERT_RUN members, or
+                 None.
     lww_cols:    per bucket [6, lanes, Tm] (kind, key, val, delta,
                  doc_idx, t_idx).
     Returns (tstate', merge_states', lww_states', flat16, msn32) where
@@ -62,34 +77,21 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
     [seq_delta B*T | msn_delta B*T | flags B*T | next_seq as (lo B, hi B)
     | msn_base as (lo B, hi B) | msn_ok bit | overflow-any bits |
     per-lane overflow planes (merge then LWW, lanes each) | per-lane
-    occupancy planes (same order) | (stats=True only) the device
-    telemetry plane: device_stats.N_SERVE int32 slots as (lo, hi)
-    int16 halves], decoded by tpu_sequencer._finish_window; msn32 is
-    the exact int32 msn plane, fetched ONLY when the window's msn span
-    overflows the delta (msn_ok == 0; one global bit for the whole
-    window).
+    occupancy planes (same order) | per-group post (count, min_seq,
+    seq) as (lo, hi) halves | (stats=True only) the device telemetry
+    plane: device_stats.N_SERVE int32 slots as (lo, hi) int16 halves],
+    decoded by tpu_sequencer._finish_window; msn32 is the exact int32
+    msn plane, fetched ONLY when the window's msn span overflows the
+    delta (msn_ok == 0; one global bit for the whole window).
 
     ``stats`` (static) appends the device-resident telemetry plane
     (telemetry/device_stats.py SERVE_SLOTS): admitted ops by kind,
     ticket admissions/nacks, overflow-lane and noop-skip counts, and
     post-window lane fill — counted INSIDE the program from the same
-    masks the applies use, so a K-window burst reports exact per-window
+    masks the applies use, so a K-window ring reports exact per-window
     facts with zero extra dispatches and zero extra host round-trips
     (the plane rides this same flat16). Pure output: the op phases
-    never read it, so results are bit-identical with it on or off.
-
-    ``paged_scalars`` (static) is the MEGAKERNEL mode
-    (docs/serving_pipeline.md R10): the merge "buckets" are gathered
-    page-group views whose post scalars the host must adopt (paged
-    scalars are host-authoritative between flushes), so each merge
-    apply also produces the narrow tuple (overflow int16, count,
-    min_seq, seq) — extracted IN-KERNEL by the fused pallas program on
-    its last op step, or derived identically by the scan fallback —
-    and flat16 grows a per-group int32-halves scalar tail (see the
-    ``paged_tail`` packing below). ``fused == "interpret"`` runs the
-    SAME pallas program through the pallas interpreter so CPU tier-1
-    exercises the identical kernel; any other truthy ``fused`` means
-    Mosaic-lowered."""
+    never read it, so results are bit-identical with it on or off."""
     raw = tk.RawOps(client=ticket_cols[1], client_seq=ticket_cols[2],
                     ref_seq=ticket_cols[3], kind=ticket_cols[0])
     tstate, ticketed = tk._scan_tickets(tstate, raw, batched=True,
@@ -106,10 +108,19 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
     st_lww = zero
     st_skips = zero
     new_merge = []
-    merge_narrow = []  # paged_scalars only: per-group narrow tuples
+    merge_narrow = []  # per-group narrow tuples
+
+    def _narrow(s):
+        # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit wire packing
+        # (the overflow plane rides flat16).
+        return (s.overflow.astype(jnp.int16), s.count, s.min_seq, s.seq)
+
+    from ..mergetree.pallas_apply import (FUSED_MAX_CAPACITY,
+                                         apply_ops_fused_pallas)
+    interp = fused == "interpret"
     # fluidlint: disable=RETRACE_HAZARD — deliberate bounded unroll: one
-    # iteration per capacity bucket (≤3 in production; docstring), fused
-    # so the whole window stays a single device program.
+    # iteration per page group of the ring, fused so the whole ring stays
+    # a single device program.
     for mstate, mc, mr in zip(merge_states, merge_cols, merge_runs):
         packed = PackedOps(kind=mc[0], seq=mc[1], ref_seq=mc[2],
                            client=mc[3], pos1=mc[4], pos2=mc[5],
@@ -150,82 +161,35 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
                                      OpKind.INSERT_RUN)):
                 st_kind[ki] = st_kind[ki] + jnp.sum(
                     (ops2.kind == kv).astype(jnp.int32))
-        from ..mergetree.pallas_apply import (FUSED_MAX_CAPACITY,
-                                             apply_ops_fused_pallas)
-        interp = fused == "interpret"
-        use_fused = bool(fused) and mstate.capacity <= FUSED_MAX_CAPACITY
-        if paged_scalars:
-            # Megakernel mode: every merge apply also yields the narrow
-            # scalar tuple the host adopts. The fused kernel extracts it
-            # on its last op step (one pallas invocation per group per
-            # window: gather view in, ops applied, narrow planes out);
-            # the scan fallback derives the bit-identical tuple.
-            def _narrow(s):
-                # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit
-                # wire packing (the overflow plane rides flat16).
-                return (s.overflow.astype(jnp.int16), s.count,
-                        s.min_seq, s.seq)
-            if use_fused:
-                def apply_m(s, o=ops2, r=runs):
-                    return apply_ops_fused_pallas(s, o, interpret=interp,
-                                                  runs=r, extract=True)
-            else:
-                def apply_m(s, o=ops2, r=runs):
-                    out = kernel._scan_ops(s, o, batched=True, runs=r)
-                    return out, _narrow(out)
-            if noop_skip:
-                active = jnp.any(ops2.kind != OpKind.NOOP)
-                if stats:
-                    st_skips = st_skips + (~active).astype(jnp.int32)
-                # kernel.apply_if_any carries state only; the megakernel
-                # body also threads the narrow tuple, so the pad-skip
-                # cond is inlined with a derived-narrow identity arm.
-                out, nr = jax.lax.cond(
-                    active, apply_m, lambda s: (s, _narrow(s)), mstate)
-            else:
-                out, nr = apply_m(mstate)
-            if over_extra is not None:
-                # A nacked INSERT_RUN member voids the slot host-side:
-                # the flag must reach BOTH the carried state and the
-                # narrow plane the host actually reads.
-                out = out._replace(overflow=out.overflow | over_extra)
-                nr = (nr[0] | over_extra.astype(jnp.int16),) + nr[1:]
-            new_merge.append(out)
-            merge_narrow.append(nr)
-            continue
-        if runs is not None:
-            # Run-bearing buckets: the fused kernel's INSERT_RUN variant
-            # when Mosaic lowers it (fused == "both probes passed", see
-            # tpu_sequencer), else the scan kernel — whose per-step cost
-            # the packing itself collapses.
-            if use_fused:
-                def apply_m(s, o=ops2, r=runs):
-                    return apply_ops_fused_pallas(s, o, interpret=interp,
-                                                  runs=r)
-            else:
-                def apply_m(s, o=ops2, r=runs):
-                    return kernel._scan_ops(s, o, batched=True, runs=r)
-        elif use_fused:
-            # VMEM-resident fused apply: the bucket's lane block stays
-            # on-core across the whole op stream — the T-step HBM
-            # re-read/re-write of the scan kernel (the serving apply's
-            # dominant cost) collapses to one read + one write.
-            # Bit-identical to the scan kernel (tests/test_pallas_apply).
-            def apply_m(s, o=ops2):
-                return apply_ops_fused_pallas(s, o, interpret=interp)
+        if fused and mstate.capacity <= FUSED_MAX_CAPACITY:
+            # VMEM-resident fused apply: the group's view stays on-core
+            # across the whole op stream (one read + one write instead of
+            # the scan kernel's T-step HBM re-read/re-write), extracting
+            # the narrow tuple on its last op step. Bit-identical to the
+            # scan kernel (tests/test_pallas_apply).
+            def apply_m(s, o=ops2, r=runs):
+                return apply_ops_fused_pallas(s, o, interpret=interp,
+                                              runs=r, extract=True)
         else:
-            def apply_m(s, o=ops2):
-                return kernel._scan_ops(s, o, batched=True)
-        if noop_skip:
-            active = jnp.any(ops2.kind != OpKind.NOOP)
-            if stats:
-                st_skips = st_skips + (~active).astype(jnp.int32)
-            out = kernel.apply_if_any(apply_m, mstate, active)
-        else:
-            out = apply_m(mstate)
+            def apply_m(s, o=ops2, r=runs):
+                out = kernel._scan_ops(s, o, batched=True, runs=r)
+                return out, _narrow(out)
+        active = jnp.any(ops2.kind != OpKind.NOOP)
+        if stats:
+            st_skips = st_skips + (~active).astype(jnp.int32)
+        # kernel.apply_if_any carries state only; this body also threads
+        # the narrow tuple, so the pad-skip cond is inlined with a
+        # derived-narrow identity arm.
+        out, nr = jax.lax.cond(
+            active, apply_m, lambda s: (s, _narrow(s)), mstate)
         if over_extra is not None:
+            # A nacked INSERT_RUN member voids the slot host-side: the
+            # flag must reach BOTH the carried state and the narrow
+            # plane the host actually reads.
             out = out._replace(overflow=out.overflow | over_extra)
+            nr = (nr[0] | over_extra.astype(jnp.int16),) + nr[1:]
         new_merge.append(out)
+        merge_narrow.append(nr)
 
     new_lww = []
     # fluidlint: disable=RETRACE_HAZARD — deliberate bounded unroll, one
@@ -242,13 +206,10 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
 
         def apply_l(s, o=ops):
             return lk._scan(s, o, batched=True)
-        if noop_skip:
-            active_l = jnp.any(ops.kind != lk.LwwKind.NOOP)
-            if stats:
-                st_skips = st_skips + (~active_l).astype(jnp.int32)
-            new_lww.append(kernel.apply_if_any(apply_l, lstate, active_l))
-        else:
-            new_lww.append(apply_l(lstate))
+        active_l = jnp.any(ops.kind != lk.LwwKind.NOOP)
+        if stats:
+            st_skips = st_skips + (~active_l).astype(jnp.int32)
+        new_lww.append(kernel.apply_if_any(apply_l, lstate, active_l))
 
     flags = ticketed.nacked.astype(jnp.int32) | \
         (ticketed.not_joined.astype(jnp.int32) << 1)
@@ -256,37 +217,25 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
     bits += [s.overflow.any()[None].astype(jnp.int32) for s in new_merge]
     bits += [s.overflow.any()[None].astype(jnp.int32) for s in new_lww]
     # Per-lane overflow planes ride the SAME narrow result (one int16 per
-    # staged bucket lane): overflow recovery learns WHICH lanes flagged
-    # without touching the post states at all — required once the lane
-    # states are donated (the in-ring rollback cannot read a buffer the
-    # next window's dispatch reused), and it also deletes the separate
-    # per-bucket `overflow` D2H the rare recovery path used to pay.
+    # staged lane): overflow recovery learns WHICH lanes flagged without
+    # touching the post states at all — required once the lane states
+    # are donated. The merge planes come from the narrow tuples
+    # (in-kernel extracted under fused; bit-identical derivation under
+    # the scan fallback — over_extra already OR'd in).
+    planes = [nr[0] for nr in merge_narrow]
     # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit wire packing:
     # the planes ride flat16, the narrow result plane (docstring).
-    if paged_scalars:
-        # Megakernel: the overflow planes come from the narrow tuples
-        # (in-kernel extracted under fused; bit-identical derivation
-        # under the scan fallback — over_extra already OR'd in).
-        planes = [nr[0] for nr in merge_narrow]
-    else:
-        planes = [s.overflow.astype(jnp.int16) for s in new_merge]
-    # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit wire packing
-    # (same flat16 plane as the merge overflow planes above).
     planes += [s.overflow.astype(jnp.int16) for s in new_lww]
     # Post-window occupancy planes (row count per merge lane, occupied
-    # key slots per LWW lane; capacities are <= 16k so int16 is exact):
-    # the host's donation/deferral gate keeps its occupancy hints EXACT
-    # from every window's own result instead of decaying pessimistic
-    # until a compact-tick refresh — no extra device round-trip.
+    # key slots per LWW lane): the host's LWW fit proof keeps its
+    # occupancy hints EXACT from every window's own result instead of
+    # decaying pessimistic until a compact-tick refresh — no extra device
+    # round-trip. The int16 view of the group counts keeps the flat16
+    # layout uniform; a large page group can wrap it, so the host adopts
+    # merge counts from the exact int32 paged_tail below instead.
     # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit wire packing
     # (rides the same flat16 narrow result plane).
-    if paged_scalars:
-        # int16 view of the group counts keeps the flat16 layout uniform
-        # with the bucketed wire; a large page group can wrap it, so the
-        # host adopts from the exact int32 paged_tail below instead.
-        planes += [nr[1].astype(jnp.int16) for nr in merge_narrow]
-    else:
-        planes += [s.count.astype(jnp.int16) for s in new_merge]
+    planes += [nr[1].astype(jnp.int16) for nr in merge_narrow]
     # fluidlint: disable=DTYPE_DRIFT — deliberate 16-bit wire packing
     # (rides the same flat16 narrow result plane).
     planes += [(s.key >= 0).sum(-1).astype(jnp.int16) for s in new_lww]
@@ -314,16 +263,13 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
         return [(x32 & 0xFFFF).astype(jnp.int16),
                 (x32 >> 16).astype(jnp.int16)]
 
+    # Scalar-adoption plane: each page group's post count/min_seq/seq as
+    # EXACT int32 (lo, hi) halves — the host's page scalars are
+    # authoritative between flushes, so the ring's last window adopts
+    # these (the post-ring truth). Rides the same one flat16 readback.
     paged_tail = []
-    if paged_scalars:
-        # Megakernel scalar-adoption plane: each page group's post
-        # count/min_seq/seq as EXACT int32 (lo, hi) halves — the host's
-        # paged scalars are authoritative between flushes, and the int16
-        # occupancy planes above can wrap for a large group, so every
-        # window's finish adopts these (the last window's adoption is
-        # the post-burst truth). Rides the same one flat16 readback.
-        for nr in merge_narrow:
-            paged_tail += halves(nr[1]) + halves(nr[2]) + halves(nr[3])
+    for nr in merge_narrow:
+        paged_tail += halves(nr[1]) + halves(nr[2]) + halves(nr[3])
 
     stats_tail = []
     if stats:
@@ -361,90 +307,6 @@ def _serve_window_impl(tstate, ticket_cols, merge_states, merge_cols,
     return tstate, new_merge, new_lww, flat16, msn_bt
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 2, 4),
-                   static_argnums=(6, 8))
-def serve_window(tstate, ticket_cols, merge_states, merge_cols,
-                 lww_states, lww_cols, fused=False, merge_runs=None,
-                 stats=False):
-    """One fast window, donating: the jitted single-window entry point
-    over ``_serve_window_impl`` (docstring there carries the full
-    contract and the flat16 layout)."""
-    return _serve_window_impl(tstate, ticket_cols, merge_states,
-                              merge_cols, lww_states, lww_cols, fused,
-                              merge_runs, stats=stats)
-
-
-# The non-donating recovery-replay variant: identical traced body, but the
-# merge/LWW lane states survive the call. The sequencer dispatches through
-# THIS variant whenever its host-side occupancy hints cannot prove the
-# window overflow-free — the retained pre-window states are what the
-# fold/rescue rollback scatters back before the batched re-run
-# (tpu_sequencer._recover_fast_merge). The common provably-clean window
-# takes the donating `serve_window` above and never allocates a second
-# copy of the lane planes.
-serve_window_keep = functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnums=(6, 8))(
-        serve_window.__wrapped__)
-
-
-def _serve_burst(tstate, merge_states, lww_states, ticket_xs, merge_xs,
-                 lww_xs, runs_xs, fused=False, stats=False):
-    """K serving windows in ONE scanned device program (the fused
-    serving burst, docs/serving_pipeline.md R8).
-
-    The ring overlaps the per-window host→device dispatch and narrow
-    readback but every window still PAYS them, a fixed host cost
-    each. The burst
-    collapses K ready windows into one ``lax.scan`` whose carry is the
-    donated lane-bucket state (ticket state + every staged merge/LWW
-    bucket, updated in place across all K windows) and whose xs are the
-    per-window packed op planes, pre-staged host-side into single
-    stacked buffers:
-
-      ticket_xs: [K, 4, B, T]  — per-window ticket staging
-      merge_xs:  per union bucket [K, 12, lanes, Tm] (NOOP-padded where
-                 a window staged nothing for the bucket; the scan body
-                 cond-skips those applies — kernel.apply_if_any)
-      lww_xs:    per union bucket [K, 6, lanes, Tm]
-      runs_xs:   per union bucket [K, 4, lanes, Tm, RUN_K] or None
-
-    ys are each window's narrow int16 result [K, flat] plus the exact
-    msn planes [K, B, T] (fetched per window only on the rare msn-delta
-    overflow) — the whole burst is ONE dispatch and ONE readback. The
-    body is ``_serve_window_impl`` itself, so results are bit-identical
-    to dispatching the K windows through ``serve_window`` back to back;
-    the host-side finish path (seq distribution, nacks, overflow
-    quarantine) runs per window off the stacked result exactly as it
-    does off ring entries.
-
-    Burst admission is the sequencer's job: only windows whose
-    occupancy-hint fit proofs pass (non-risky AND donate-eligible)
-    enter a burst, so overflow here is the same rare unpredicted class
-    the donated per-window path handles (degrade + quarantine fixup).
-    Mesh note: the body shards exactly as serve_window does under
-    GSPMD, but bursts require donation, which dp meshes gate off (the
-    jax 0.4.37 warm-cache corruption, docs/serving_pipeline.md R6) —
-    so meshes stay on the per-window ring until that clears."""
-    def body(carry, xs):
-        ts, ms, ls = carry
-        tc, mc, lc, rc = xs
-        ts2, nm, nl, flat16, msn32 = _serve_window_impl(
-            ts, tc, list(ms), list(mc), list(ls), list(lc), fused,
-            list(rc), noop_skip=True, stats=stats)
-        return (ts2, tuple(nm), tuple(nl)), (flat16, msn32)
-
-    carry, ys = jax.lax.scan(
-        body, (tstate, tuple(merge_states), tuple(lww_states)),
-        (ticket_xs, tuple(merge_xs), tuple(lww_xs), tuple(runs_xs)))
-    ts, ms, ls = carry
-    return ts, list(ms), list(ls), ys[0], ys[1]
-
-
-serve_burst = functools.partial(
-    jax.jit, donate_argnums=(0, 1, 2),
-    static_argnums=(7, 8))(_serve_burst)
-
-
 def _serve_paged_burst(pool, page_ids, counts, min_seqs, seqs, ops_xs,
                        stats=False):
     """K op windows over PAGED documents in ONE scanned device program
@@ -455,16 +317,15 @@ def _serve_paged_burst(pool, page_ids, counts, min_seqs, seqs, ops_xs,
     leg). The page pool and page tables are the DONATED operands — the
     pool updates in place across the whole burst and page_ids alias
     straight through to the returned plane, so a bulk catch-up stream
-    costs one dispatch regardless of its chunk count, with no
-    bucket-padded planes anywhere: view capacity is the GROUP's page
-    bucket, not the fleet-wide storm doc's.
+    costs one dispatch regardless of its chunk count: view capacity is
+    the GROUP's page bucket, not the fleet-wide storm doc's.
 
     Returns (pool', page_ids, count, min_seq, seq, overflow, over_k,
     pre_view): over_k is the per-chunk overflow plane [K, B] (any bit
     -> the host rolls the flagged docs back from pre_view and runs the
-    host rescue with the FULL stream, mirroring the bucketed recovery
-    contract); pre_view is the gathered pre-burst group view that makes
-    that rollback possible under donation. ``stats`` (static) appends
+    host rescue with the FULL stream); pre_view is the gathered
+    pre-burst group view that makes that rollback possible under
+    donation. ``stats`` (static) appends
     the per-chunk device telemetry plane [K, N_PAGED]
     (kernel.paged_stats_vec riding the scan ys — per-chunk facts from
     the one dispatch the burst already is)."""
@@ -505,14 +366,13 @@ serve_paged_burst_keep = functools.partial(
 def _serve_megakernel(tstate, pool, lww_states, ticket_xs, page_ids,
                       counts, min_seqs, seqs, merge_xs, lww_xs, runs_xs,
                       fused=False, stats=False):
-    """K fast serving windows over PAGED merge lanes in ONE device
+    """K fast serving windows over the paged merge lanes in ONE device
     program — the serving megakernel (docs/serving_pipeline.md R10).
 
-    This is the paged twin of ``_serve_burst``: the native pump's fast
-    flush stages its merge rows as PAGE-GROUP jobs (one group per pow2
-    page-count class, tpu_sequencer.MergeLaneStore paged mode) instead
-    of capacity buckets, and the whole pre-staged ring drains as one
-    dispatch. The program:
+    The native pump's fast flush stages its merge rows as PAGE-GROUP
+    jobs (one group per pow2 page-count class,
+    tpu_sequencer.MergeLaneStore.flush_lane_for), and the whole
+    pre-staged ring drains as one dispatch. The program:
 
       1. gathers each group's documents ONCE by page id
          (kernel.gather_pages — view capacity is the GROUP's page
@@ -541,19 +401,18 @@ def _serve_megakernel(tstate, pool, lww_states, ticket_xs, page_ids,
       runs_xs:   per group [K, 4, n_pad, Tm, RUN_K] or None
 
     Returns (tstate', pool', lww_states', flat16_k [K, flat], msn_k
-    [K, B, T], pre_views): flat16 here carries the R10 paged scalar
-    tail (``paged_scalars`` in ``_serve_window_impl``) so the host
-    adopts exact post int32 scalars per window with no extra readback;
-    pre_views are the gathered pre-ring group views that make the
-    overflow rollback possible under donation (the paged analog of the
-    bucketed ``pre`` job states — rollback_pages + host rescue, same
-    recovery contract as ``_serve_paged_burst``).
+    [K, B, T], pre_views): flat16 carries the paged scalar tail
+    (``_serve_window_impl``) so the host adopts exact post int32
+    scalars per window with no extra readback; pre_views are the
+    gathered pre-ring group views that make the overflow rollback
+    possible under donation (rollback_pages + host rescue, same recovery
+    contract as ``_serve_paged_burst``).
 
-    One ring = one dispatch = one readback: dispatches/burst amortizes
-    toward 0 as the ring deepens, and the jit signature depends only on
-    (K, group shapes, B, T) — scan length does not fragment the grid
-    beyond the K axis, which the sequencer quantizes exactly like burst
-    k (``_burst_k_grid``)."""
+    One ring = one dispatch = one readback: dispatches per window
+    amortize toward 0 as the ring deepens, and the jit signature depends
+    only on (K, group shapes, B, T) — scan length does not fragment the
+    grid beyond the K axis, which the sequencer quantizes on its fixed
+    grid (``_burst_k_grid``)."""
     pre = tuple(kernel.gather_pages(pool, p, c, m, s)
                 for p, c, m, s in zip(page_ids, counts, min_seqs, seqs))
 
@@ -562,7 +421,7 @@ def _serve_megakernel(tstate, pool, lww_states, ticket_xs, page_ids,
         tc, mc, lc, rc = xs
         ts2, nm, nl, flat16, msn32 = _serve_window_impl(
             ts, tc, list(ms), list(mc), list(ls), list(lc), fused,
-            list(rc), noop_skip=True, stats=stats, paged_scalars=True)
+            list(rc), stats=stats)
         return (ts2, tuple(nm), tuple(nl)), (flat16, msn32)
 
     carry, ys = jax.lax.scan(

@@ -15,12 +15,14 @@ client-id interning, emission to the downstream topics (scriptorium/
 broadcaster/scribe consume SequencedDocumentMessages unchanged), nacks,
 and checkpointing.
 
-Capacity discipline (SURVEY.md §7 hard parts 1/3): merge lanes live in
-capacity buckets (one compiled program per bucket size). A lane that
-overflows its bucket during apply is first zamboni-compacted and re-run;
-if it still overflows it promotes to the next bucket — correct-by-recovery,
-never correct-by-luck. The ticket client table grows the same way (K
-doubles pre-flush when a window's join count could exceed it).
+Capacity discipline (SURVEY.md §7 hard parts 1/3): merge lanes live in a
+refcounted page pool with a page table per document (docs/paged_memory.md).
+Growth is pre-proven — each op adds at most 2 rows, so a document's pages
+are appended BEFORE the apply and row overflow cannot happen; the one
+overflow left (annotate-ring/overlap-slot exhaustion) rolls back and
+host-rescues — correct-by-recovery, never correct-by-luck. The ticket
+client table grows pre-flush the same way (K doubles when a window's join
+count could exceed it).
 """
 
 from __future__ import annotations
@@ -44,7 +46,7 @@ from ..mergetree.constants import DEFAULT_T_BUCKETS, PAGE_ROWS
 from ..mergetree.host import OpBuilder, PayloadTable, extract_text
 from ..mergetree.oppack import HostOp, OpKind, PackedOps, pack_ops
 from ..mergetree.paging import PagedMergeStore, pages_for, pow2_pages
-from ..mergetree.state import DocState, make_state
+from ..mergetree.state import DocState
 from ..protocol.messages import (
     Boxcar,
     DocumentMessage,
@@ -74,203 +76,15 @@ def _bucket(n: int, buckets: Tuple[int, ...]) -> int:
 
 
 # ---------------------------------------------------------------------------
-# merge lanes: device-resident per-channel segment tables, capacity-bucketed
+# merge lanes: device-resident per-channel segment tables in a page pool
 # ---------------------------------------------------------------------------
-
-class _MergeBucket:
-    """A batch of merge lanes sharing one segment capacity (one compiled
-    apply program per (capacity, T-bucket) pair)."""
-
-    def __init__(self, capacity: int, lanes: int):
-        self.capacity = capacity
-        self.lanes = lanes
-        self.state: DocState = make_state(capacity, batch=lanes)
-        self.used: List[Optional[tuple]] = [None] * lanes  # lane key or None
-        self._blank_row: Optional[DocState] = None  # built lazily, reused
-        self._free: List[int] = []  # explicitly freed lanes (zeroed)
-        self._next = 0              # frontier: lanes >= _next never used
-        self.placer = None          # optional dp-mesh placement callable
-        # Host-side UPPER BOUND of each lane's live row count: the donation
-        # gate (tpu_sequencer._assess_windows) proves a window cannot
-        # overflow from these hints alone — no device sync on the hot path.
-        # count_hint is the CONFIRMED base (refreshed exactly from every
-        # drained window's occupancy plane, from recovery put_rows, and at
-        # compact ticks); hint_pending is the in-flight windows' staged-op
-        # bound (added at dispatch, removed at that window's drain). The
-        # live bound is their sum.
-        self.count_hint = np.zeros(lanes, np.int64)
-        self.hint_pending = np.zeros(lanes, np.int64)
-
-    def grow(self) -> None:
-        old = self.lanes
-        grown = make_state(self.capacity, batch=old * 2)
-        self.state = jax.tree_util.tree_map(
-            lambda g, s: g.at[:old].set(s) if g.ndim else s,
-            grown, self.state)
-        self.used.extend([None] * old)
-        self.lanes = old * 2
-        self.count_hint = np.concatenate(
-            [self.count_hint, np.zeros(old, np.int64)])
-        self.hint_pending = np.concatenate(
-            [self.hint_pending, np.zeros(old, np.int64)])
-        if self.placer is not None:
-            self.state = self.placer(self.state)
-
-    def alloc(self, key: tuple) -> int:
-        # Free-list + frontier: O(1) per alloc (a linear first-None scan
-        # is O(lanes^2) across a flush that admits thousands of channels).
-        if self._free:
-            i = self._free.pop()
-        else:
-            if self._next >= self.lanes:
-                self.grow()
-            i = self._next
-            self._next += 1
-        self.used[i] = key
-        self.count_hint[i] = 0  # freed/frontier lanes are blank rows
-        return i
-
-    def free(self, lane: int) -> None:
-        self.free_many([lane])
-
-    def free_many(self, lanes: List[int]) -> None:
-        # Zero the rows too: alloc() hands freed lanes to NEW channels, and
-        # a dirty lane's stale segments would leak into the next channel's
-        # materialization (summaries, catch-up seeds, LWW empty-base seed).
-        # Batched: a recovery burst frees thousands of lanes and per-lane
-        # scatters cost one device dispatch each.
-        if not lanes:
-            return
-        for i in lanes:
-            self.used[i] = None
-        self._free.extend(lanes)
-        self.count_hint[np.asarray(lanes, np.int64)] = 0
-        self.hint_pending[np.asarray(lanes, np.int64)] = 0
-        if self._blank_row is None:
-            self._blank_row = make_state(
-                self.capacity, anno_slots=self.state.anno_slots,
-                overlap_slots=self.state.rem_clients.shape[-1])
-        idx = jnp.asarray(np.asarray(lanes, np.int32))
-        k = len(lanes)
-        self.state = jax.tree_util.tree_map(
-            lambda col, blank: col.at[idx].set(
-                jnp.broadcast_to(blank, (k,) + blank.shape)),
-            self.state, self._blank_row)
-
-    def alloc_many(self, keys: List[tuple]) -> List[int]:
-        return [self.alloc(key) for key in keys]
-
-    def row(self, lane: int) -> DocState:
-        """Extract one lane as a single-doc DocState (host-side gather)."""
-        return jax.tree_util.tree_map(lambda x: x[lane], self.state)
-
-    def put_row(self, lane: int, row: DocState,
-                count_hint: Optional[int] = None) -> None:
-        self.state = jax.tree_util.tree_map(
-            lambda b, r: b.at[lane].set(r), self.state, row)
-        self.count_hint[lane] = self.capacity if count_hint is None \
-            else count_hint
-
-    def put_rows(self, lanes: List[int], rows: DocState,
-                 count_hints=None) -> None:
-        """Scatter a [k, ...] sub-batch into k lanes in ONE pass.
-        `count_hints` (aligned to `lanes`) keeps the donation gate's
-        occupancy bound tight; omitted = pessimistic until the next
-        compact-tick refresh."""
-        idx = jnp.asarray(np.asarray(lanes, np.int32))
-        self.state = jax.tree_util.tree_map(
-            lambda col, r: col.at[idx].set(r), self.state, rows)
-        self.count_hint[np.asarray(lanes, np.int64)] = \
-            self.capacity if count_hints is None \
-            else np.asarray(count_hints, np.int64)
-
-
-def _stack_seed_rows(items: List[tuple], capacity: int, anno_slots: int,
-                     overlap_slots: int) -> DocState:
-    """[(key, seed_host_cols dict, min_seq, seq)] -> one [k, ...] DocState
-    built entirely in host numpy, shipped as ONE transfer per column
-    (the batched half of catchup.seed_host_cols)."""
-    from ..mergetree.constants import DEV_NO_REMOVE, DEV_UNASSIGNED
-    k = len(items)
-
-    def full(val, *dims):
-        return np.full((k, *dims), val, np.int32)
-
-    out = {
-        "length": full(0, capacity),
-        "ins_seq": full(DEV_UNASSIGNED, capacity),
-        "ins_client": full(-1, capacity),
-        "local_seq": full(0, capacity),
-        "rem_seq": full(DEV_NO_REMOVE, capacity),
-        "rem_local_seq": full(0, capacity),
-        "origin_op": full(-1, capacity),
-        "origin_off": full(0, capacity),
-    }
-    rem_clients = full(-1, capacity, overlap_slots)
-    anno = full(-1, capacity, anno_slots)
-    count = np.zeros(k, np.int32)
-    mins = np.zeros(k, np.int32)
-    seqs = np.zeros(k, np.int32)
-    for j, (_, cols, mseq, cseq) in enumerate(items):
-        n = len(cols["length"])
-        for name, arr in out.items():
-            arr[j, :n] = cols[name]
-        rem_clients[j, :n, 0] = cols["rem_client"]
-        if "rem_overlap" in cols:
-            ov = cols["rem_overlap"]
-            w = min(ov.shape[1], overlap_slots - 1)
-            rem_clients[j, :n, 1:1 + w] = ov[:, :w]
-        if "anno" in cols:
-            anno[j, :n] = cols["anno"]
-        count[j], mins[j], seqs[j] = n, mseq, cseq
-    return DocState(
-        length=jnp.asarray(out["length"]),
-        ins_seq=jnp.asarray(out["ins_seq"]),
-        ins_client=jnp.asarray(out["ins_client"]),
-        local_seq=jnp.asarray(out["local_seq"]),
-        rem_seq=jnp.asarray(out["rem_seq"]),
-        rem_local_seq=jnp.asarray(out["rem_local_seq"]),
-        rem_clients=jnp.asarray(rem_clients),
-        origin_op=jnp.asarray(out["origin_op"]),
-        origin_off=jnp.asarray(out["origin_off"]),
-        anno=jnp.asarray(anno),
-        count=jnp.asarray(count),
-        min_seq=jnp.asarray(mins),
-        seq=jnp.asarray(seqs),
-        overflow=jnp.zeros(k, jnp.bool_),
-    )
-
-
-def _repad_batch(rows: DocState, capacity: int) -> DocState:
-    """Re-pad a [n, ...] sub-batch to a larger capacity (group promotion)."""
-    n = rows.length.shape[0]
-    base = make_state(capacity, anno_slots=rows.anno_slots,
-                      overlap_slots=rows.rem_clients.shape[-1], batch=n)
-    c = rows.capacity
-
-    def widen(dst, src):
-        if src.ndim <= 1:
-            return src
-        return dst.at[:, :c].set(src)
-
-    return jax.tree_util.tree_map(widen, base, rows)
-
-
-# Non-donating applies (kernel.apply_ops*_keep): the serving path keeps the
-# pre-flush state alive until overflow recovery has cleared, so nothing is
-# rebuilt on the recovery path (jax arrays are immutable; retaining the
-# input is free). Wrapped in the retrace probe: serving windows bucket to
-# a fixed (capacity, T) grid, so compile-cache growth after warmup means
-# an unplanned signature leaked in — counted as kernel.retrace_count and
-# exported via the monitor's /healthz (the runtime cross-check for
-# fluidlint's static RETRACE_HAZARD rule).
-_apply_keep_batched = JitRetraceProbe(kernel.apply_ops_batched_keep,
-                                      name="kernel.merge_apply_batched")
 
 # The paged apply (kernel.apply_ops_paged): gather-by-page-id -> the same
 # batched op phases -> scatter-by-page-id, pool + page tables donated.
-# Shapes bucket to (pow2 docs, pow2 pages, T-grid) so cache growth after
-# warmup is a leaked signature, same contract as the bucketed probe.
+# Shapes bucket to (pow2 docs, pow2 pages, T-grid) so compile-cache growth
+# after warmup means an unplanned signature leaked in — counted as
+# kernel.retrace_count and exported via the monitor's /healthz (the runtime
+# cross-check for fluidlint's static RETRACE_HAZARD rule).
 _apply_paged_probe = JitRetraceProbe(kernel.apply_ops_paged,
                                      name="kernel.paged_apply")
 # The non-donating twin for MESH-placed pools (serving_pipeline.md R6:
@@ -311,38 +125,23 @@ class _PagedFlushGroup:
 
 
 class MergeLaneStore:
-    """All merge lanes across capacity buckets + the shared payload table."""
+    """All merge lanes, in one refcounted page pool, + the shared payload
+    table (docs/paged_memory.md): segment rows live in fixed-size pages
+    with a page table per document, so a document grows by appending a
+    page and applies gather each document's pages by id."""
 
-    def __init__(self, capacities: Tuple[int, ...] = (64, 256, 1024),
-                 lanes_per_bucket: int = 8,
-                 t_buckets: Tuple[int, ...] = DEFAULT_T_BUCKETS,
-                 paged: bool = False,
+    def __init__(self, t_buckets: Tuple[int, ...] = DEFAULT_T_BUCKETS,
                  page_rows: Optional[int] = None,
                  mesh=None):
-        self.capacities = tuple(capacities)
         self.t_buckets = tuple(t_buckets)
-        # dp-mesh placement for the paged pool rides the partition-rule
-        # table (mergetree/partition_rules.py); bucketed lane grids are
-        # placed by the sequencer's bucket.placer instead.
+        # dp-mesh placement for the pool rides the partition-rule table
+        # (mergetree/partition_rules.py).
         self.mesh = mesh
-        # Paged lane memory (docs/paged_memory.md): segment rows live in
-        # a refcounted page pool with per-doc page tables instead of the
-        # capacity-bucket grid — growth is "append a page", so the whole
-        # promote/fold/rescue ceremony (and its padding of every lane to
-        # the storm doc's bucket) disappears from the apply path. The
-        # bucket list stays empty in paged mode; every storage touchpoint
-        # below branches on self.paged.
-        self.paged = bool(paged)
-        self.pages: Optional[PagedMergeStore] = None
-        if self.paged:
-            self.pages = PagedMergeStore(page_rows=page_rows or PAGE_ROWS,
-                                         mesh=mesh)
-        self.buckets = [] if self.paged else [
-            _MergeBucket(c, lanes_per_bucket) for c in self.capacities]
-        # Paged-mode telemetry: host rescues (the only fold/rescue-class
-        # event left — annotate-ring/overlap-slot exhaustion) and
-        # budgeted defrag passes. The paged smoke compares these against
-        # the bucketed path's fold/rescue dispatch count.
+        self.pages = PagedMergeStore(page_rows=page_rows or PAGE_ROWS,
+                                     mesh=mesh)
+        # Telemetry: host rescues (the only fold/rescue-class event —
+        # annotate-ring/overlap-slot exhaustion) and budgeted defrag
+        # passes.
         self.paged_rescues = 0
         self.page_compactions = 0
         self.fold_rescue_dispatches = 0  # device recovery dispatches
@@ -352,17 +151,12 @@ class MergeLaneStore:
         self._flush_group_of: Dict[int, int] = {}
         self.payloads = PayloadTable()
         self.builder = OpBuilder(self.payloads)
-        self.where: Dict[tuple, Tuple[int, int]] = {}  # key -> (bucket, lane)
+        # key -> (-1, -1): placement lives in the page table (lane_for).
+        self.where: Dict[tuple, Tuple[int, int]] = {}
         self.opaque: set = set()  # lanes dropped (unparseable op seen)
-        self.overflow_drops = 0  # lanes degraded after exhausting buckets
+        self.overflow_drops = 0  # lanes degraded after a failed rescue
         self.flushes_since_compact = 0
         self.compact_every = 8
-        self.folds = 0            # lanes host-folded (zamboni pack)
-        self.fold_rows_reclaimed = 0
-        # Overflow below this capacity promotes; at/above it folds.
-        self.fold_min_capacity = min(
-            (c for c in self.capacities if c >= 256),
-            default=self.capacities[-1])
         # op_ids created by the lane's latest fold/rescue generation:
         # freed (PayloadTable free-list) when the next generation
         # supersedes them — otherwise a long-lived document retains
@@ -385,19 +179,15 @@ class MergeLaneStore:
         # reseeds their rows, and blocks older than block_age_ticks
         # compact ticks materialize their remaining (tiny) payloads so
         # the buffers can go.
-        self._lane_blocks: Dict[tuple, set] = {}   # key -> blocks
+        # key -> its blocks, as an insertion-ordered dict: releases free
+        # ids in a replayable order, so recycled ids are deterministic.
+        self._lane_blocks: Dict[tuple, dict] = {}
         self._blocks: List[list] = []              # [ticks, block]
         self.block_age_ticks = 8                   # x compact_every flushes
         self.blocks_aged = 0
-        # Demotion-fold memo: live-row count at the last fold attempt
-        # that could not demote — retry only when the count changes (the
-        # extract+coalesce probe costs ~ms/lane and a contended lane can
-        # stay crowded-but-undemotable across many ticks).
-        self._fold_skip: Dict[tuple, int] = {}
-        # Tick-fold work cap: bounds the host fold per compact tick so
-        # proactive folding smooths latency instead of creating its own
-        # stop-the-world wave.
-        self.fold_budget_per_tick = 64
+        # Defrag work cap: documents compacted per tick, so the tick
+        # smooths latency instead of creating its own stop-the-world wave.
+        self.compact_budget_per_tick = 64
         # Major payload-id collection cadence: every N compact ticks IF
         # the table grew past double its post-collection size (the
         # heap-doubling heuristic — dead slots cannot be counted via
@@ -445,19 +235,13 @@ class MergeLaneStore:
     # -- lane admission ----------------------------------------------------
     def lane_for(self, key: tuple) -> Tuple[int, int]:
         if key not in self.where:
-            if self.paged:
-                # Admission = one blank page + a page-table entry; the
-                # placement tuple keeps the (bucket, lane) arity with a
-                # fixed (-1, -1) sentinel — paged placement lives in
-                # the page table, so there is no unique lane index here
-                # and pretending otherwise (e.g. an insertion ordinal)
-                # would collide after drops.
-                self.pages.ensure(key)
-                self.where[key] = (-1, -1)
-            else:
-                bucket = 0
-                lane = self.buckets[bucket].alloc(key)
-                self.where[key] = (bucket, lane)
+            # Admission = one blank page + a page-table entry; the
+            # placement tuple keeps the (bucket, lane) arity with a fixed
+            # (-1, -1) sentinel — placement lives in the page table, so
+            # there is no unique lane index here and pretending otherwise
+            # (e.g. an insertion ordinal) would collide after drops.
+            self.pages.ensure(key)
+            self.where[key] = (-1, -1)
         return self.where[key]
 
     # -- paged fast-flush group directory (R10) ----------------------------
@@ -517,11 +301,8 @@ class MergeLaneStore:
         """Mark a channel opaque: an op arrived the server cannot model
         (chunked/unknown payload); its device lane is abandoned."""
         if key in self.where:
-            b, lane = self.where.pop(key)
-            if self.paged:
-                self.pages.free_all(key)
-            else:
-                self.buckets[b].free(lane)
+            self.where.pop(key)
+            self.pages.free_all(key)
         self._forget_lane_payloads(key)
         self.opaque.add(key)
 
@@ -536,7 +317,6 @@ class MergeLaneStore:
         self.free_payloads(self._fold_payloads.pop(key, ()))
         for block in self._lane_blocks.pop(key, ()):
             self._release_block_ref(block, key)
-        self._fold_skip.pop(key, None)
         self.catchup_unsafe.discard(key)
         with self._guard_lock:
             self._snap_cache.pop(key, None)
@@ -571,35 +351,23 @@ class MergeLaneStore:
             self._extract_guards -= 1
 
     def _swap_fold_payloads(self, key: tuple, new_ids: set,
-                            keep_ops=()) -> None:
-        """Adopt a fold/rescue generation's payload ids for `key`, freeing
-        the superseded generation (every row got a fresh id, so the old
-        ones are unreferenced once the new rows are adopted). Block refs
-        release too: after a reseed the lane's rows reference only the
-        new generation — plus, for an overflow fold that re-ran the
-        current window on device, that window's block ids (keep_ops)."""
+                            keep_block=None) -> None:
+        """Adopt a seed/rescue generation's payload ids for `key`,
+        freeing the superseded generation (every row got a fresh id, so
+        the old ones are unreferenced once the new rows are adopted).
+        Block refs release too: after a reseed the lane's rows reference
+        only the new generation — except `keep_block`, the current fast
+        flush's arena block, whose ids later windows of the same flush
+        may still stage for this lane (it ages out like any block)."""
         self.free_payloads([op_id
                             for op_id in self._fold_payloads.pop(key, ())
                             if op_id not in new_ids])
         self._fold_payloads[key] = sorted(new_ids)
-        refs = self._lane_blocks.get(key)
-        if refs:
-            keep_ids = {op.op_id for op in keep_ops}
-            kept = set()
-            for block in list(refs):
-                # Membership against the block's RECORDED ids for this
-                # lane, not its id range: freed range ids recycle to
-                # unrelated builder ops, and a range test would let such
-                # an op spuriously pin an old block's buffers.
-                if keep_ids and not keep_ids.isdisjoint(
-                        block.lane_ids.get(key, ())):
-                    kept.add(block)
-                else:
-                    self._release_block_ref(block, key)
-            if kept:
-                self._lane_blocks[key] = kept
+        for block in self._lane_blocks.pop(key, ()):
+            if block is keep_block:
+                self._lane_blocks[key] = {block: None}
             else:
-                self._lane_blocks.pop(key, None)
+                self._release_block_ref(block, key)
 
     def note_block(self, block, lane_ids: Dict[tuple, list]) -> None:
         """Register a fast-flush arena block for aging. lane_ids maps each
@@ -607,7 +375,7 @@ class MergeLaneStore:
         block.lane_ids = lane_ids
         self._blocks.append([0, block])
         for key in lane_ids:
-            self._lane_blocks.setdefault(key, set()).add(block)
+            self._lane_blocks.setdefault(key, {})[block] = None
 
     def _release_block_ref(self, block, key: tuple) -> None:
         """A lane's rows no longer reference this block: free its ids (the
@@ -622,8 +390,8 @@ class MergeLaneStore:
         grows one slot per ingested op (blocks append contiguously;
         holes recycle but the list never shrinks), so a long-lived
         server would hold an ever-growing slab of dead slots. Collects
-        referenced ids from the origin_op + anno planes (run right
-        after compact_batched, so rows past count are blanked),
+        referenced ids from the pool's origin_op + anno planes (rows
+        past each document's count and freed pages are blanked),
         materializes block-backed payloads, renumbers the planes with a
         vectorized searchsorted remap, and drops every block. Skipped
         (retried next tick) while an async summary worker resolves the
@@ -632,27 +400,14 @@ class MergeLaneStore:
             if self._extract_guards:
                 return False
             self._deferred_frees = []  # table is rebuilt wholesale
-        per_bucket: List[Optional[tuple]] = []
         referenced: set = set()
-        pool_planes: Optional[tuple] = None
-        if self.paged:
-            # One whole-pool pass: free pages are zeroed (-1 planes) and
-            # padding rows stay blank, so the pool's unique ids ARE the
-            # live reference set — no per-bucket walk.
-            op_np = np.asarray(self.pages.pool.origin_op)
-            an_np = np.asarray(self.pages.pool.anno)
-            pool_planes = (op_np, an_np)
-            referenced.update(int(v) for v in np.unique(op_np) if v >= 0)
-            referenced.update(int(v) for v in np.unique(an_np) if v >= 0)
-        for bucket in self.buckets:
-            if not any(k is not None for k in bucket.used):
-                per_bucket.append(None)
-                continue
-            op_np = np.asarray(bucket.state.origin_op)
-            an_np = np.asarray(bucket.state.anno)
-            per_bucket.append((op_np, an_np))
-            referenced.update(int(v) for v in np.unique(op_np) if v >= 0)
-            referenced.update(int(v) for v in np.unique(an_np) if v >= 0)
+        # One whole-pool pass: free pages are zeroed (-1 planes) and
+        # padding rows stay blank, so the pool's unique ids ARE the live
+        # reference set.
+        op_np = np.asarray(self.pages.pool.origin_op)
+        an_np = np.asarray(self.pages.pool.anno)
+        referenced.update(int(v) for v in np.unique(op_np) if v >= 0)
+        referenced.update(int(v) for v in np.unique(an_np) if v >= 0)
         order = sorted(referenced)
         sorted_old = np.asarray(order, np.int64)
         new_entries = [self.payloads.get(old) for old in order]
@@ -662,23 +417,9 @@ class MergeLaneStore:
             idx = np.searchsorted(sorted_old, plane)
             return np.where(live, idx, -1).astype(np.int32)
 
-        if pool_planes is not None:
-            op_np, an_np = pool_planes
-            self.pages.adopt_pool(self.pages.pool._replace(
-                origin_op=jnp.asarray(renumber(op_np)),
-                anno=jnp.asarray(renumber(an_np))))
-        for bucket, host in zip(self.buckets, per_bucket):
-            if host is None:
-                continue
-            op_np, an_np = host
-            bucket.state = bucket.state._replace(
-                origin_op=jnp.asarray(renumber(op_np)),
-                anno=jnp.asarray(renumber(an_np)))
-            if bucket.placer is not None:
-                # jnp.asarray built host-resident replicated columns,
-                # dropping the dp-mesh placement: re-place so major
-                # collection preserves sharding (grow() does the same).
-                bucket.state = bucket.placer(bucket.state)
+        self.pages.adopt_pool(self.pages.pool._replace(
+            origin_op=jnp.asarray(renumber(op_np)),
+            anno=jnp.asarray(renumber(an_np))))
         remap = {old: new for new, old in enumerate(order)}
         self._fold_payloads = {
             key: sorted(remap[i] for i in ids if i in remap)
@@ -719,7 +460,7 @@ class MergeLaneStore:
                 self._fold_payloads.setdefault(key, []).extend(ids)
                 refs = self._lane_blocks.get(key)
                 if refs is not None:
-                    refs.discard(block)
+                    refs.pop(block, None)
                     if not refs:
                         self._lane_blocks.pop(key, None)
             self.blocks_aged += 1
@@ -737,67 +478,13 @@ class MergeLaneStore:
         """Bootstrap a lane from snapshot segments (a document whose
         content shipped via the attach/client summary rather than ops —
         without this, the first op addressing snapshot content finds an
-        empty lane and overflows every bucket). Picks the smallest bucket
-        with 2x headroom; unmodelable or oversized snapshots degrade the
-        channel to opaque."""
+        empty lane). Any snapshot size fits: it just allocates more pages
+        (the page pool grows by doubling like every other table). Only
+        unmodelable payload shapes degrade the channel to opaque."""
         from ..mergetree.catchup import Unmodelable, seed_host_cols
         from ..mergetree.state import state_from_numpy
         if key in self.where or key in self.opaque:
             return key in self.where
-        if self.paged:
-            return self._seed_paged(key, entries, min_seq, current_seq)
-        allow_runs = matrix_base_key(key) is not None
-        # Plain snapshot seed: no window to re-apply, so the widest
-        # bucket may fill completely (last_slack=0) before degrading.
-        b = self._seed_bucket_for(len(entries), last_slack=0)
-        if b is None:
-            self.opaque.add(key)
-            return False
-        bucket = self.buckets[b]
-        try:
-            cols = seed_host_cols(entries, self.payloads,
-                                  anno_slots=bucket.state.anno_slots,
-                                  allow_runs=allow_runs,
-                                  allow_items=not allow_runs)
-        except (Unmodelable, ValueError):
-            self.opaque.add(key)
-            return False
-        row = state_from_numpy(
-            cols, bucket.capacity,
-            anno_slots=bucket.state.anno_slots)._replace(
-            min_seq=jnp.asarray(min_seq, jnp.int32),
-            seq=jnp.asarray(current_seq, jnp.int32))
-        lane = bucket.alloc(key)
-        bucket.put_row(lane, row, count_hint=len(cols["length"]))
-        self.where[key] = (b, lane)
-        self.mark_dirty(key)
-        self._mark_catchup_safety(key, entries)
-        # Track the seed generation like a fold's: the first fold (or a
-        # drop) frees it instead of stranding the attach-time document
-        # text in the shared table forever.
-        self._swap_fold_payloads(key, self._seed_ids(cols))
-        return True
-
-    def _mark_catchup_safety(self, key: tuple, entries) -> None:
-        """Seed-time gate for the read-path artifact publisher: summary
-        entries still carrying contended client metadata seed quorum-join
-        ordinals into a lane whose ops intern 0,1,2,… — the two spaces
-        alias, so client-field translation back to wire ids is ambiguous
-        for this lane (class docstring at catchup_unsafe)."""
-        if any(e.get("client") is not None
-               or e.get("removedClient") is not None
-               or e.get("removedOverlapClients")
-               for e in entries):
-            self.catchup_unsafe.add(key)
-
-    def _seed_paged(self, key: tuple, entries, min_seq: int,
-                    current_seq: int) -> bool:
-        """Paged snapshot seed: no bucket-fit degradation — any snapshot
-        size fits, it just allocates more pages (the page pool grows by
-        doubling like every other table). Only unmodelable payload
-        shapes still degrade the channel to opaque."""
-        from ..mergetree.catchup import Unmodelable, seed_host_cols
-        from ..mergetree.state import state_from_numpy
         pg = self.pages
         allow_runs = matrix_base_key(key) is not None
         try:
@@ -818,110 +505,39 @@ class MergeLaneStore:
         pg.put_row(key, row, count=n)
         self.mark_dirty(key)
         self._mark_catchup_safety(key, entries)
+        # Track the seed generation like a rescue's: the first rescue (or
+        # a drop) frees it instead of stranding the attach-time document
+        # text in the shared table forever.
         self._swap_fold_payloads(key, self._seed_ids(cols))
         return True
 
+    def _mark_catchup_safety(self, key: tuple, entries) -> None:
+        """Seed-time gate for the read-path artifact publisher: summary
+        entries still carrying contended client metadata seed quorum-join
+        ordinals into a lane whose ops intern 0,1,2,… — the two spaces
+        alias, so client-field translation back to wire ids is ambiguous
+        for this lane (class docstring at catchup_unsafe)."""
+        if any(e.get("client") is not None
+               or e.get("removedClient") is not None
+               or e.get("removedOverlapClients")
+               for e in entries):
+            self.catchup_unsafe.add(key)
+
     # -- batched apply with overflow recovery ------------------------------
     def apply(self, streams: Dict[tuple, List[HostOp]]) -> None:
-        """Apply per-lane op streams; windows longer than the largest
-        T-bucket chunk into successive device passes (bulk catch-up)."""
+        """Apply per-lane op streams; a stream longer than the largest
+        T-bucket rides one scanned program (bulk catch-up), then the
+        compaction cadence ticks."""
         self._in_apply = True
         try:
-            self._apply(streams)
-        finally:
-            self._in_apply = False
-
-    def _apply(self, streams: Dict[tuple, List[HostOp]]) -> None:
-        if self.paged:
             self._apply_paged(streams)
             with tracing.span("serving.gc", hist="serving.gc"):
                 self.flushes_since_compact += 1
                 if self.flushes_since_compact >= self.compact_every:
                     self.compact_all()
-            return
-        max_t = self.t_buckets[-1]
-        while streams:
-            window: Dict[tuple, List[HostOp]] = {}
-            rest: Dict[tuple, List[HostOp]] = {}
-            for key, ops in streams.items():
-                if not ops:
-                    continue
-                window[key] = ops[:max_t]
-                if len(ops) > max_t:
-                    rest[key] = ops[max_t:]
-            if not window:
-                break
-            self._apply_window(window)
-            streams = rest
+        finally:
+            self._in_apply = False
 
-    def _apply_window(self, streams: Dict[tuple, List[HostOp]]) -> None:
-        self._apply_streams(streams)
-        with tracing.span("serving.gc", hist="serving.gc"):
-            self.flushes_since_compact += 1
-            if self.flushes_since_compact >= self.compact_every:
-                self.compact_all()
-
-    def _apply_streams(self, streams: Dict[tuple, List[HostOp]]) -> None:
-        """One batched device pass per bucket; recover overflowing lanes by
-        compact -> re-run -> promote. No GC tick: the in-ring fixup path
-        (TpuSequencerLambda._finish_window) re-applies quarantined lanes
-        through here while later windows are still in flight, and a
-        compaction there would move lanes those windows already staged
-        against."""
-        per_bucket: Dict[int, Dict[int, List[HostOp]]] = {}
-        for key, ops in streams.items():
-            if key in self.opaque or not ops:
-                continue
-            b, lane = self.lane_for(key)
-            self.mark_dirty(key)
-            per_bucket.setdefault(b, {})[lane] = ops
-
-        for b, lane_ops in sorted(per_bucket.items()):
-            bucket = self.buckets[b]
-            with tracing.span("serving.pack", hist="serving.pack",
-                              stage="merge-oppack", bucket=b):
-                t = _bucket(max(len(v) for v in lane_ops.values()),
-                            self.t_buckets)
-                streams_list = [lane_ops.get(i, [])
-                                for i in range(bucket.lanes)]
-                packed = pack_ops(streams_list, steps=t)
-            pre = bucket.state
-            with tracing.span("serving.dispatch", hist="serving.dispatch",
-                              stage="merge-apply", bucket=b):
-                new_state = _apply_keep_batched(pre, packed)
-            with tracing.span("serving.readback", hist="serving.readback",
-                              stage="merge-overflow", bucket=b):
-                over = np.asarray(new_state.overflow)
-            flagged = [i for i in range(bucket.lanes)
-                       if over[i] and i in lane_ops]
-            # Unconditional fold/rescue span: a clean window records the
-            # stage at ~0 so flush captures always attribute it.
-            with tracing.span("serving.fold_rescue",
-                              hist="serving.fold_rescue", bucket=b):
-                if flagged:
-                    # Adopt the clean lanes; roll flagged lanes back to
-                    # their pre-flush rows (one batched scatter), then
-                    # recover them.
-                    idx = jnp.asarray(np.asarray(flagged, np.int32))
-                    new_state = jax.tree_util.tree_map(
-                        lambda bcol, p: bcol.at[idx].set(p[idx]),
-                        new_state, pre)
-                bucket.state = new_state
-                # Occupancy hints: each applied op adds at most 2 rows
-                # (insert + split); recovery's put_rows re-hints flagged
-                # lanes below.
-                for i, ops in lane_ops.items():
-                    bucket.count_hint[i] += 2 * len(ops)
-                if flagged:
-                    # One BATCHED compact->rerun->promote per level —
-                    # per-lane device round-trips over a thin host link
-                    # turn a 1k-lane overflow burst into minutes. Lane
-                    # counts pad to powers of two so the compiled shapes
-                    # stay bounded.
-                    self._recover_batch(b, {i: lane_ops[i]
-                                            for i in flagged})
-
-    # -- the paged apply path (docs/paged_memory.md) -----------------------
     def _apply_paged(self, streams: Dict[tuple, List[HostOp]]) -> None:
         """Apply per-lane op streams against the page pool. Growth is
         pre-proven: each op adds at most 2 rows, so `ensure_rows(count +
@@ -1110,10 +726,13 @@ class MergeLaneStore:
                 _sp.set(dropped=dropped)
 
     def _rescue_paged(self, key: tuple, row: DocState, ops) -> bool:
-        """_rescue_lane's contract, page-backed: fold the pre-window row
-        on the host (annotate rings resolve into props, acked runs
-        coalesce), re-apply this stream's ops host-side, reseed into
-        exactly the pages the folded content needs."""
+        """Last resort before opaque: fold the pre-window row on the host
+        (annotate rings resolve into props, acked runs coalesce),
+        re-apply this stream's ops host-side, and reseed into exactly the
+        pages the folded content needs. More pages cannot fix ring
+        exhaustion; the fold empties every ring, so only >anno_slots
+        annotates on one segment within a single stream can still defeat
+        it."""
         from ..mergetree.catchup import (Unmodelable, apply_host_ops,
                                          coalesce_entries, extract_entries)
         pg = self.pages
@@ -1147,7 +766,11 @@ class MergeLaneStore:
         pg.put_row(key, row2, count=n)
         self.mark_dirty(key)
         self.fold_rescue_dispatches += 1  # the per-lane put_row dispatch
-        self._swap_fold_payloads(key, self._seed_ids(cols))
+        # The newest arena block is the current fast flush's: windows of
+        # it not yet applied may still stage this lane's ops by its ids.
+        self._swap_fold_payloads(
+            key, self._seed_ids(cols),
+            keep_block=self._blocks[-1][1] if self._blocks else None)
         pg.ops_since_compact.pop(key, None)
         return True
 
@@ -1155,8 +778,8 @@ class MergeLaneStore:
         """Page-granular zamboni tick: fully-dead trailing pages already
         released at every apply, so this pass only defrags FRAGMENTED
         documents — ranked by applied-op volume since their last pass
-        (the host-visible upper bound on new tombstones) — under the
-        same per-tick budget the bucketed fold uses, releasing whatever
+        (the host-visible upper bound on new tombstones) — under a
+        per-tick budget (compact_budget_per_tick), releasing whatever
         pages the left-pack empties."""
         pg = self.pages
         cands = [key for key, v in pg.ops_since_compact.items()
@@ -1164,7 +787,7 @@ class MergeLaneStore:
         if not cands:
             return
         cands.sort(key=lambda k: -pg.ops_since_compact[k])
-        cands = cands[:self.fold_budget_per_tick]
+        cands = cands[:self.compact_budget_per_tick]
         groups: Dict[int, List[tuple]] = {}
         for key in cands:
             groups.setdefault(
@@ -1209,276 +832,12 @@ class MergeLaneStore:
             "pool_grows": pg.pool_grows,
         }
 
-    @staticmethod
-    def _pad_pow2(sub: DocState, packed: PackedOps, n: int,
-                  capacity: int):
-        """Pad a recovery sub-batch to a power-of-two lane count with
-        empty rows + NOOP streams: the compiled (lanes, capacity, t)
-        shapes stay bounded at log2 variants instead of one per distinct
-        overflow-burst size."""
-        tm = jax.tree_util.tree_map
-        n_pad = 1 << max(n - 1, 0).bit_length()
-        if n_pad == n:
-            return sub, packed
-        base = make_state(capacity, anno_slots=sub.anno_slots,
-                          overlap_slots=sub.rem_clients.shape[-1],
-                          batch=n_pad)
-        sub = tm(lambda full, s: full.at[:n].set(s)
-                 if getattr(full, "ndim", 0) else s, base, sub)
-        packed = tm(lambda x: jnp.concatenate(
-            [x, jnp.zeros((n_pad - n,) + x.shape[1:], x.dtype)], 0), packed)
-        return sub, packed
-
-    def _recover_batch(self, b: int,
-                       lane_ops: Dict[int, List[HostOp]]) -> None:
-        """Batched overflow recovery (the only recovery path — one lane is
-        a batch of one): stack the flagged lanes' pre-flush rows into a
-        sub-batch, compact + re-run them together, then group-promote the
-        still-overflowing remainder upward; opaque at exhaustion."""
-        tm = jax.tree_util.tree_map
-        lanes = sorted(lane_ops)
-        n = len(lanes)
-        bucket = self.buckets[b]
-        take = np.asarray(lanes)
-        sub = tm(lambda x: x[take] if getattr(x, "ndim", 0) else x,
-                 bucket.state)
-        t = _bucket(max(len(v) for v in lane_ops.values()), self.t_buckets)
-        packed = pack_ops([lane_ops[i] for i in lanes], steps=t)
-        sub, packed = self._pad_pow2(sub, packed, n, bucket.capacity)
-        # Attempt 1: compact in place and re-run at this capacity.
-        self.fold_rescue_dispatches += 1
-        compacted = kernel.compact_batched(sub)
-        redone = _apply_keep_batched(compacted, packed)
-        over = np.asarray(redone.overflow)
-        ok_j = [j for j in range(len(lanes)) if not over[j]]
-        bad_j = [j for j in range(len(lanes)) if over[j]]
-        if ok_j:
-            sel = np.asarray(ok_j)
-            # Exact counts ride the same sync the overflow read already
-            # paid: recovered lanes re-qualify for deferral/donation
-            # immediately instead of staying pessimistic until the next
-            # compact tick.
-            cnts = np.asarray(redone.count)
-            bucket.put_rows([lanes[j] for j in ok_j],
-                            tm(lambda x: x[sel], redone),
-                            count_hints=cnts[sel])
-        # Attempt 2: host-fold acked runs and re-run at the SAME
-        # capacity. Sustained typing overflows with mostly-acked rows
-        # (device compaction cannot merge them — payload bytes live
-        # host-side), and promotion would climb to capacities whose
-        # apply cost scales with C (measured steady-state ingest on the
-        # CPU host: 139k -> 75k -> 17k ops/s at C=64/256/1024). The fold
-        # caps that climb; only lanes whose live in-window rows genuinely
-        # exceed the fold capacity still promote past it. Buckets BELOW
-        # fold_min_capacity promote instead (warm shapes, one batched
-        # pass): folding there would fire every ~(C - window)/window
-        # flushes and the per-lane host fold cost would dominate — the
-        # fold amortizes ~13x wider at 256 for keystroke windows.
-        if bad_j and bucket.capacity >= self.fold_min_capacity:
-            bad_j = self._fold_rerun_batch(bucket, lanes, bad_j,
-                                           compacted, packed, lane_ops)
-        carried = [bucket.used[lanes[j]] for j in bad_j]  # keys carrying up
-        # Pre-apply row index + this window's ops per carried key: the
-        # host-fold rescue (rare: only lanes that exhaust every capacity
-        # promotion) slices `compacted` lazily — no per-lane gathers on
-        # the batched path.
-        rescue_src = {bucket.used[lanes[j]]: (j, lane_ops[lanes[j]])
-                      for j in bad_j}
-        keep = bad_j                 # their row indices into src/packed
-        bucket.free_many([lanes[j] for j in bad_j])
-        src = compacted
-        for nb in range(b + 1, len(self.buckets)):
-            if not carried:
-                return
-            n = len(keep)
-            sel = np.asarray(keep)
-            src = tm(lambda x: x[sel] if getattr(x, "ndim", 0) else x, src)
-            packed = tm(lambda x: x[sel], packed)
-            target = self.buckets[nb]
-            wide = _repad_batch(src, target.capacity)
-            wide, packed = self._pad_pow2(wide, packed, n, target.capacity)
-            self.fold_rescue_dispatches += 1
-            redone = _apply_keep_batched(wide, packed)
-            over = np.asarray(redone.overflow)
-            ok_k = [k for k in range(len(carried)) if not over[k]]
-            if ok_k:
-                new_lanes = target.alloc_many([carried[k] for k in ok_k])
-                sel_ok = np.asarray(ok_k)
-                target.put_rows(new_lanes, tm(lambda x: x[sel_ok], redone),
-                                count_hints=np.asarray(
-                                    redone.count)[sel_ok])
-                for k, nl in zip(ok_k, new_lanes):
-                    self.where[carried[k]] = (nb, nl)
-            keep = [k for k in range(len(carried)) if over[k]]
-            carried = [carried[k] for k in keep]
-            src = wide
-        for key in carried:
-            j, ops = rescue_src[key]
-            row = tm(lambda x: x[j] if getattr(x, "ndim", 0) else x,
-                     compacted)
-            if self._rescue_lane(key, row, ops):
-                continue
-            self.where.pop(key, None)
-            self._forget_lane_payloads(key)
-            self.opaque.add(key)
-            self.overflow_drops += 1
-
-    def _fold_rerun_batch(self, bucket, lanes: List[int], bad_j: List[int],
-                          compacted: DocState, packed,
-                          lane_ops: Dict[int, List[HostOp]]) -> List[int]:
-        """Overflow attempt 2: fold the flagged lanes' acked runs on the
-        host (coalesce_entries — the zamboni pack step the device cannot
-        do) and re-run this window at the SAME capacity, batched. Returns
-        the lane indices that still overflow (those carry into the
-        promotion cascade). One D2H slice in, one batched apply + one
-        put_rows out."""
-        from ..mergetree.catchup import (Unmodelable, coalesce_entries,
-                                         extract_entries, seed_host_cols)
-        tm = jax.tree_util.tree_map
-        sel = np.asarray(bad_j)
-        host_rows = jax.device_get(tm(
-            lambda x: x[sel] if getattr(x, "ndim", 0) else x, compacted))
-        folded: List[tuple] = []  # (j, key, cols, mseq, cseq)
-        for k, j in enumerate(bad_j):
-            key = bucket.used[lanes[j]]
-            row = tm(lambda x: x[k] if getattr(x, "ndim", 0) else x,
-                     host_rows)
-            mseq = int(row.min_seq)
-            cseq = int(row.seq)
-            allow_runs = matrix_base_key(key) is not None
-            try:
-                entries = coalesce_entries(
-                    extract_entries(row, self.payloads, mseq, fold=True))
-                # Re-run headroom: each window op costs at most 2 rows
-                # (insert + split). Not enough -> promotion is correct.
-                need = len(entries) + 2 * len(lane_ops[lanes[j]]) + 8
-                if need > bucket.capacity:
-                    continue
-                cols = seed_host_cols(
-                    entries, self.payloads,
-                    anno_slots=int(row.anno.shape[-1]),
-                    allow_runs=allow_runs, allow_items=not allow_runs)
-            except (Unmodelable, ValueError):
-                continue  # ring depth, odd payloads: promotion handles it
-            folded.append((j, key, cols, mseq, cseq))
-        if not folded:
-            return bad_j
-        rows = _stack_seed_rows(
-            [(key, cols, ms, cs) for _, key, cols, ms, cs in folded],
-            bucket.capacity, bucket.state.anno_slots,
-            bucket.state.rem_clients.shape[-1])
-        psel = np.asarray([j for j, *_ in folded])
-        sub_packed = tm(lambda x: x[psel], packed)
-        rows, sub_packed = self._pad_pow2(rows, sub_packed, len(folded),
-                                          bucket.capacity)
-        self.fold_rescue_dispatches += 1
-        redone = _apply_keep_batched(rows, sub_packed)
-        over = np.asarray(redone.overflow)
-        adopted = [k for k in range(len(folded)) if not over[k]]
-        if adopted:
-            idx = np.asarray(adopted)
-            bucket.put_rows([lanes[folded[k][0]] for k in adopted],
-                            tm(lambda x: x[idx], redone),
-                            count_hints=[
-                                len(folded[k][2]["length"])
-                                + 2 * len(lane_ops[lanes[folded[k][0]]])
-                                for k in adopted])
-            self.folds += len(adopted)
-            for k in adopted:
-                # The fold reseeded the rows (coalesced segmentation, new
-                # payload ids): any cached summary blob is stale even
-                # though the window's mark_dirty already fired — keep the
-                # epoch honest for callers that summarize mid-recovery.
-                self.mark_dirty(folded[k][1])
-        counts = np.asarray(host_rows.count)
-        bad_pos = {j: k for k, j in enumerate(bad_j)}
-        for k, (j, key, cols, _, _) in enumerate(folded):
-            if over[k]:
-                # Rerun still overflowed: this generation's fresh seed
-                # payloads were never adopted — free them now.
-                self.free_payloads(self._seed_ids(cols))
-            else:
-                self._swap_fold_payloads(key, self._seed_ids(cols),
-                                         keep_ops=lane_ops[lanes[j]])
-                self.fold_rows_reclaimed += (
-                    int(counts[bad_pos[j]]) - len(cols["length"]))
-        done = {folded[k][0] for k in adopted}
-        return [j for j in bad_j if j not in done]
-
-    def _rescue_lane(self, key: tuple, row: DocState, ops) -> bool:
-        """Last resort before opaque: fold the lane on the HOST — annotate
-        rings resolve into props, acked runs coalesce — re-apply this
-        window's ops with the chunked escalating applier, and reseed into
-        the smallest fitting bucket. Capacity promotion alone cannot fix
-        ring-ACCUMULATION overflow (ring depth is fixed per bucket); the
-        fold empties every ring, so only >anno_slots annotates on one
-        segment within a single window can still defeat it."""
-        from ..mergetree.catchup import (Unmodelable, apply_host_ops,
-                                         coalesce_entries, extract_entries)
-        try:
-            mseq = int(np.asarray(row.min_seq))
-            cseq = int(np.asarray(row.seq))
-            entries = coalesce_entries(
-                extract_entries(row, self.payloads, mseq, fold=True))
-            new_entries = coalesce_entries(
-                apply_host_ops(entries, ops, self.payloads, mseq, cseq))
-        except (Unmodelable, ValueError):
-            return False
-        from ..mergetree.constants import DEV_UNASSIGNED, UNASSIGNED_SEQ
-        mseq2 = max([mseq] + [op.msn for op in ops])
-        cseq2 = max([cseq] + [op.seq for op in ops
-                              if op.seq not in (DEV_UNASSIGNED,
-                                                UNASSIGNED_SEQ)])
-        # _seed_bucket_for: smallest with 2x headroom (a +8 fit would
-        # re-overflow on the very next busy window and thrash the whole
-        # recovery cascade per flush); the widest bucket accepts an
-        # n + 8 fit as the final fallback.
-        nb = self._seed_bucket_for(len(new_entries))
-        if nb is None:
-            return False
-        bucket = self.buckets[nb]
-        from ..mergetree.catchup import seed_host_cols
-        from ..mergetree.state import state_from_numpy
-        try:
-            cols = seed_host_cols(new_entries, self.payloads,
-                                  anno_slots=bucket.state.anno_slots)
-        except (Unmodelable, ValueError):
-            return False
-        row2 = state_from_numpy(
-            cols, bucket.capacity,
-            anno_slots=bucket.state.anno_slots)._replace(
-            min_seq=jnp.asarray(mseq2, jnp.int32),
-            seq=jnp.asarray(cseq2, jnp.int32))
-        lane = bucket.alloc(key)
-        bucket.put_row(lane, row2, count_hint=len(new_entries))
-        self.where[key] = (nb, lane)
-        self.mark_dirty(key)
-        self.fold_rescue_dispatches += 1
-        self._swap_fold_payloads(key, self._seed_ids(cols))
-        return True
-
     def compact_all(self) -> None:
-        """Zamboni every bucket (reference mergeTree.ts:1422, run between
-        batches so the gather cost amortizes, kernel.py design note),
-        then pack crowded lanes host-side. Paged mode replaces both
-        halves with the page-granular tick: no whole-fleet compaction
-        pass, no host folds."""
-        if self.paged:
-            self._compact_tick_paged()
-            self._age_blocks()
-            self._ticks_since_payload_compact += 1
-            self.maybe_compact_payload_ids()
-            self.flushes_since_compact = 0
-            return
-        for bucket in self.buckets:
-            if any(k is not None for k in bucket.used):
-                bucket.state = kernel.compact_batched(bucket.state)
-                # Exact occupancy refresh at the safe boundary: lanes that
-                # went pessimistic (recovery put_rows) re-qualify for the
-                # donating dispatch. One small D2H per bucket per tick.
-                bucket.count_hint = np.asarray(
-                    bucket.state.count).astype(np.int64).copy()
-        self._fold_crowded()
+        """The compaction tick (reference mergeTree.ts:1422 zamboni, run
+        between batches so the gather cost amortizes): the page-granular
+        defrag, arena-block aging, and the payload-id collection cadence
+        — no whole-fleet compaction pass, no host folds."""
+        self._compact_tick_paged()
         self._age_blocks()
         self._ticks_since_payload_compact += 1
         self.maybe_compact_payload_ids()
@@ -1503,247 +862,28 @@ class MergeLaneStore:
         else:
             self._ticks_since_payload_compact = 0
 
-    # Fold when live rows pass 3/4 of capacity; the per-lane cadence is
-    # therefore ~capacity/4 ops, so the host cost amortizes wider as
-    # documents grow.
-    FOLD_NUM, FOLD_DEN = 3, 4
-
-    def _seed_bucket_for(self, n: int, last_slack: int = 8) -> \
-            Optional[int]:
-        """Smallest bucket with 2x headroom (a tight fit would
-        re-overflow next window and thrash); the widest bucket accepts a
-        fit with `last_slack` spare rows as the final fallback —
-        rescue/fold need room to re-apply a window (slack 8), a plain
-        snapshot seed does not (slack 0)."""
-        last = len(self.buckets) - 1
-        for nb, bucket in enumerate(self.buckets):
-            if n * 2 <= bucket.capacity or \
-                    (nb == last and n + last_slack <= bucket.capacity):
-                return nb
-        return None
-
-    def _fold_crowded(self) -> None:
-        """Host-side pack — the serving half of the reference's zamboni
-        scour/pack (mergeTree.ts:1289): device compaction frees removed
-        rows but cannot merge ACKED adjacent rows (payload bytes live
-        host-side as origin slices), so sustained typing grows one row
-        per op and climbs capacity buckets whose apply cost scales with
-        capacity (measured steady-state ingest on the CPU host: 139k ->
-        75k -> 17k ops/s at C=64/256/1024, with multi-second promotion
-        stalls at each boundary). Folding acked runs through
-        coalesce_entries and reseeding into the smallest fitting bucket
-        keeps long-lived documents in the fast small buckets. Candidate
-        rows leave the device in ONE slice per bucket and folded lanes
-        return in ONE batched put per destination bucket (every host
-        round-trip pays a fixed dispatch cost)."""
-        from ..mergetree.catchup import (Unmodelable, coalesce_entries,
-                                         extract_entries, seed_host_cols)
-        tm = jax.tree_util.tree_map
-        dest: Dict[int, List[tuple]] = {}  # nb -> [(key, cols, mseq, cseq)]
-        budget = self.fold_budget_per_tick
-        for b, bucket in enumerate(self.buckets):
-            if not any(k is not None for k in bucket.used):
-                continue
-            counts = np.asarray(bucket.state.count)
-            mseqs = np.asarray(bucket.state.min_seq)
-            # Near-overflow lanes in fold-eligible buckets fold ahead of
-            # time (same-bucket reseed allowed, budget-capped): spreading
-            # the host fold across ticks instead of letting a cohort of
-            # lockstep lanes all hit the synchronized overflow fold in
-            # one flush (a p99 latency cliff).
-            near_ok = bucket.capacity >= self.fold_min_capacity
-            if b == 0 and not near_ok:
-                # Neither demotion (no smaller bucket) nor refold
-                # (below fold_min_capacity) is possible here: probing
-                # would burn budget + extract time on guaranteed no-ops,
-                # starving the buckets the budget exists to smooth.
-                continue
-            # The memo keys on (count, min_seq): an msn advance can turn
-            # a previously-undemotable lane foldable without its row
-            # count changing.
-            cands = [i for i, key in enumerate(bucket.used)
-                     if key is not None
-                     and int(counts[i]) * self.FOLD_DEN
-                     >= bucket.capacity * self.FOLD_NUM
-                     and self._fold_skip.get(key)
-                     != (int(counts[i]), int(mseqs[i]))]
-            if len(cands) > budget:
-                cands = sorted(cands, key=lambda i: -int(counts[i]))
-                cands = cands[:budget]
-            budget -= len(cands)
-            if not cands:
-                continue
-            take = jnp.asarray(np.asarray(cands, np.int32))
-            # One DEVICE dispatch per candidate slice (the unit
-            # fold_rescue_dispatches counts everywhere — the paged
-            # smoke's ceremony-cut gate compares it across engines, so
-            # per-key counting here would inflate the bucketed side).
-            self.fold_rescue_dispatches += 1
-            sub = jax.device_get(tm(
-                lambda x: x[take] if getattr(x, "ndim", 0) else x,
-                bucket.state))
-            freed: List[int] = []
-            for j, lane in enumerate(cands):
-                key = bucket.used[lane]
-                row = tm(lambda x: x[j] if getattr(x, "ndim", 0) else x,
-                         sub)
-                mseq = int(row.min_seq)
-                cseq = int(row.seq)
-                allow_runs = matrix_base_key(key) is not None
-                try:
-                    entries = coalesce_entries(
-                        extract_entries(row, self.payloads, mseq,
-                                        fold=True))
-                    nb = self._seed_bucket_for(len(entries))
-                    # Accept a demotion (content shrank: cheaper
-                    # capacity) or, for a fold-eligible bucket, a
-                    # near-overflow fold in place that actually reclaims
-                    # rows (>= half) — same-bucket rebuilds that reclaim
-                    # little would be pure churn; the overflow-time fold
-                    # still owns contended lanes.
-                    near = (near_ok
-                            and int(counts[lane]) * 8
-                            >= bucket.capacity * 7
-                            and len(entries) * 2 <= int(counts[lane]))
-                    demote = nb is not None and nb < b
-                    refold = nb == b and near
-                    if not (demote or refold):
-                        self._fold_skip[key] = (int(counts[lane]),
-                                                int(mseqs[lane]))
-                        continue
-                    cols = seed_host_cols(
-                        entries, self.payloads,
-                        anno_slots=int(row.anno.shape[-1]),
-                        allow_runs=allow_runs,
-                        allow_items=not allow_runs)
-                except (Unmodelable, ValueError):
-                    self._fold_skip[key] = (int(counts[lane]),
-                                            int(mseqs[lane]))
-                    continue  # leave the lane untouched; fold is optional
-                dest.setdefault(nb, []).append((key, cols, mseq, cseq))
-                freed.append(lane)
-                self._fold_skip.pop(key, None)
-                # Reseeded rows = new segmentation + payload ids: a
-                # cached summary blob assembled before the fold no longer
-                # describes the lane — advance the change generation so
-                # dirty-epoch extraction re-assembles it.
-                self.mark_dirty(key)
-                self.folds += 1
-                self.fold_rows_reclaimed += int(counts[lane]) \
-                    - len(entries)
-            if freed:
-                bucket.free_many(freed)
-        for nb, items in dest.items():
-            target = self.buckets[nb]
-            self.fold_rescue_dispatches += 1  # one batched put per dest
-            lanes = target.alloc_many([key for key, *_ in items])
-            target.put_rows(lanes, _stack_seed_rows(
-                items, target.capacity, target.state.anno_slots,
-                target.state.rem_clients.shape[-1]),
-                count_hints=[len(cols["length"])
-                             for _, cols, *_ in items])
-            for (key, cols, *_), lane in zip(items, lanes):
-                self.where[key] = (nb, lane)
-                self._swap_fold_payloads(key, self._seed_ids(cols))
-
     # -- batched summary extraction ----------------------------------------
     def extract_dispatch(self, only: Optional[set] = None,
                          chunk_chars: int = 10000) -> tuple:
-        """Phase 1 (device, async): launch ONE fused zamboni+extraction
-        pass per bucket (kernel.compact_extract_batched — compaction and
-        snapshot packing share a single keep-mask/prefix-sum/gather, and
-        the bucket adopts the compacted state). The returned jobs hold
-        in-flight device arrays — jax dispatch is asynchronous, so the
-        caller can keep sequencing the next window while these execute
-        (the reference's pipeline-stage overlap,
-        kafka-service/README.md:58-60).
+        """Phase 1 (device, async): dirty lanes group by their pow2 page
+        bucket and each group runs ONE fused zamboni+extract over
+        gathered page views (kernel.compact_extract_paged — compaction
+        and snapshot packing share a single keep-mask/prefix-sum/gather,
+        pool adopted in place). The returned jobs hold in-flight device
+        arrays — jax dispatch is asynchronous, so the caller can keep
+        sequencing the next window while these execute (the reference's
+        pipeline-stage overlap, kafka-service/README.md:58-60).
 
         Dirty-epoch extraction: lanes whose change generation still
         matches their cached assembly (the summarize epoch) skip device
         extraction entirely and return their previous blobs via the
-        second element. Remaining dirty lanes gather into a pow2-padded
-        sub-batch (kernel.gather_rows_pow2, bounded compile shapes), so
-        extraction compute AND the D2H transfer scale with the dirty
-        count, not the fleet size. `only` further restricts the keys
-        considered. Returns (jobs, cached_snapshots)."""
-        if self.paged:
-            return self._extract_dispatch_paged(only, chunk_chars)
-        jobs = []
-        cached: Dict[tuple, dict] = {}
-        # One lock round for the whole scan: the blob cache is written
-        # from the async-summary worker under the guard, so the dispatch
-        # reads a coherent epoch snapshot instead of the live dict.
-        with self._guard_lock:
-            snap_view = dict(self._snap_cache)
-        for bucket in self.buckets:
-            lanes = []
-            live = 0
-            for i, key in enumerate(bucket.used):
-                if key is None:
-                    continue
-                live += 1
-                if only is not None and key not in only:
-                    continue
-                hit = snap_view.get(key)
-                if hit is not None and hit[0] == self.change_gen.get(key, 0) \
-                        and hit[1] == chunk_chars:
-                    cached[key] = hit[2]
-                    continue
-                lanes.append((i, key))
-            if not lanes:
-                continue
-            # Generations captured AT DISPATCH: ops applied while an async
-            # assembly is in flight advance change_gen past these, so the
-            # cache entry written later correctly reads as stale.
-            gens = {key: self.change_gen.get(key, 0) for _, key in lanes}
-            # Device telemetry (static at dispatch): the fused zamboni+
-            # extract also returns its PRE-compaction per-doc row counts
-            # so the host can report zamboni reclamation without a
-            # separate fetch of the device-resident pre state (the
-            # counts ride the assemble join's existing transfers).
-            stats_on = device_stats.enabled()
-            if len(lanes) == live:
-                # Every live lane extracts: fuse over the whole bucket
-                # state and adopt the compacted result (the summarize
-                # pass IS this tick's zamboni for these lanes).
-                with compile_ledger.track("kernel.compact_extract",
-                                          kernel.compact_extract_batched):
-                    res = kernel.compact_extract_batched(
-                        bucket.state, stats=stats_on)
-                new_state, packed = res[0], res[1]
-                pre_counts = res[2] if stats_on else None
-                bucket.state = new_state
-                jobs.append((packed, lanes, new_state.seq,
-                             new_state.min_seq, gens, pre_counts))
-            else:
-                sub, _n = kernel.gather_rows_pow2(
-                    bucket.state, [i for i, _ in lanes])
-                with compile_ledger.track("kernel.compact_extract",
-                                          kernel.compact_extract_batched):
-                    res = kernel.compact_extract_batched(
-                        sub, stats=stats_on)
-                packed = res[1]
-                pre_counts = res[2] if stats_on else None
-                # Lane indices become sub-batch rows.
-                jobs.append((packed,
-                             [(j, key) for j, (_, key)
-                              in enumerate(lanes)],
-                             sub.seq, sub.min_seq, gens, pre_counts))
-        if cached:
-            increment("summarize.blob_cache.hits", len(cached))
-        return jobs, cached
-
-    def _extract_dispatch_paged(self, only: Optional[set],
-                                chunk_chars: int) -> tuple:
-        """Paged phase-1 extraction: dirty lanes group by their pow2
-        page bucket and each group runs ONE fused zamboni+extract over
-        gathered page views (kernel.compact_extract_paged, pool adopted
-        in place). The packed rows keep the extract_visible_batched
-        layout, so phase 2 (extract_assemble / assemble_snapshot) runs
-        unchanged. Counts adopt synchronously — the host scalar mirrors
-        are authoritative and the next apply's page pre-growth proof
-        reads them — then trailing pages release: a summarize pass IS
-        these lanes' zamboni, exactly like the bucketed fuse."""
+        second element, so extraction compute AND the D2H transfer scale
+        with the dirty count, not the fleet size. `only` further
+        restricts the keys considered. Counts adopt synchronously — the
+        host scalar mirrors are authoritative and the next apply's page
+        pre-growth proof reads them — then trailing pages release: a
+        summarize pass IS these lanes' zamboni. Returns (jobs,
+        cached_snapshots)."""
         pg = self.pages
         jobs = []
         cached: Dict[tuple, dict] = {}
@@ -1793,8 +933,7 @@ class MergeLaneStore:
             pg.release_trailing_many(keys)
             for key in keys:
                 pg.ops_since_compact.pop(key, None)
-            jobs.append((packed, list(enumerate(keys)), seqs, mins, gens,
-                         None))
+            jobs.append((packed, list(enumerate(keys)), seqs, mins, gens))
         if cached:
             increment("summarize.blob_cache.hits", len(cached))
         return jobs, cached
@@ -1823,25 +962,13 @@ class MergeLaneStore:
         # protected by the _extract_guards deferred-free protocol
         table = self.payloads
         out: Dict[tuple, dict] = dict(cached or {})
-        for packed, lanes, seq_dev, min_seq_dev, gens, *tail in jobs:
-            pre_counts = tail[0] if tail else None
+        for packed, lanes, seq_dev, min_seq_dev, gens in jobs:
             t0 = time.perf_counter()
             packed = kernel.fetch_extracted(packed)
             increment("summarize.extract_ms",
                            (time.perf_counter() - t0) * 1000.0)
             seqs = np.asarray(seq_dev)
             min_seqs = np.asarray(min_seq_dev)
-            if pre_counts is not None:
-                # Zamboni reclamation from the device telemetry plane
-                # (pre-compaction counts) vs the fetched post counts —
-                # restricted to the job's REAL lanes (pow2 padding rows
-                # duplicate row 0 and must not multi-count its reclaim).
-                pre_np = np.asarray(pre_counts).astype(np.int64)
-                post_np = np.asarray(packed[-1]).astype(np.int64)
-                rows = [lane for lane, _ in lanes]
-                reclaimed = int((pre_np[rows] - post_np[rows]).sum())
-                device_stats.fold_extract(
-                    [len(lanes), int(post_np[rows].sum()), reclaimed])
             for lane, key in lanes:
                 snap = assemble_snapshot(
                     packed, table, lane,
@@ -1890,9 +1017,7 @@ class MergeLaneStore:
             return None
         from ..mergetree.host import NonTextPayload
 
-        b, lane = self.where[key]
-        row = self.pages.row(key) if self.paged \
-            else self.buckets[b].row(lane)
+        row = self.pages.row(key)
         try:
             return extract_text(row, self.payloads)
         except NonTextPayload:  # items/run lane: not a text channel
@@ -1906,9 +1031,7 @@ class MergeLaneStore:
 
         if key not in self.where:
             return None
-        b, lane = self.where[key]
-        row = self.pages.row(key) if self.paged \
-            else self.buckets[b].row(lane)
+        row = self.pages.row(key)
         return extract_entries(row, self.payloads,
                                int(np.asarray(row.min_seq)))
 
@@ -2144,11 +1267,10 @@ def lww_lanes_for(capacity: int) -> int:
 
 
 class _LwwBucket:
-    """A batch of LWW lanes sharing one key-slot capacity (mirrors
-    _MergeBucket: per-capacity buckets instead of one global table, so one
-    hot channel cannot inflate device memory for every lane). The device
-    table is built on first use: the big rungs cost nothing until a lane
-    needs them."""
+    """A batch of LWW lanes sharing one key-slot capacity (per-capacity
+    buckets instead of one global table, so one hot channel cannot
+    inflate device memory for every lane). The device table is built on
+    first use: the big rungs cost nothing until a lane needs them."""
 
     def __init__(self, lk, capacity: int, lanes: int = 8):
         self.lk = lk
@@ -2160,9 +1282,13 @@ class _LwwBucket:
         self._blank_row = None  # built lazily, reused across frees
         self._free: List[int] = []
         self._next = 0
-        # Upper bound of each lane's occupied key slots (the donation
-        # gate's host-side fit proof; see _MergeBucket.count_hint for
-        # the confirmed-base / in-flight-pending split).
+        # Host-side UPPER BOUND of each lane's occupied key slots: the
+        # fast flush's fit proof (_assess_windows) reads these hints
+        # alone — no device sync on the hot path. count_hint is the
+        # CONFIRMED base (refreshed exactly from every drained window's
+        # occupancy plane and at value compaction); hint_pending is the
+        # in-flight windows' staged-op bound (added at staging, removed
+        # at that window's drain). The live bound is their sum.
         self.count_hint = np.zeros(lanes, np.int64)
         self.hint_pending = np.zeros(lanes, np.int64)
 
@@ -2195,7 +1321,8 @@ class _LwwBucket:
             [self.hint_pending, np.zeros(old, np.int64)])
 
     def alloc(self, key: tuple) -> int:
-        # Free-list + frontier (see _MergeBucket.alloc).
+        # Free-list + frontier: O(1) per alloc (a linear first-None scan
+        # is O(lanes^2) across a flush that admits thousands of channels).
         if self._free:
             i = self._free.pop()
         else:
@@ -2209,7 +1336,7 @@ class _LwwBucket:
 
     def free(self, lane: int) -> None:
         # Zero on free: reused lanes must not expose the previous
-        # channel's keys/values (see _MergeBucket.free).
+        # channel's keys/values.
         self.used[lane] = None
         self._free.append(lane)
         self.hint_pending[lane] = 0
@@ -2488,7 +1615,7 @@ class LwwLaneStore:
         self.values = [self.value(int(old)) for old in referenced]
         for bucket, vals in zip(used, planes):
             # Exact key-slot occupancy refresh while the plane is on the
-            # host anyway (donation-gate hints; see _MergeBucket).
+            # host anyway (the fit proof's hints; see _LwwBucket).
             bucket.count_hint = np.count_nonzero(
                 np.asarray(bucket.state.key) >= 0, axis=-1).astype(np.int64)
             # Every id >= 0 in a used bucket is in `referenced`, so its
@@ -2920,8 +2047,7 @@ class TpuSequencerLambda(IPartitionLambda):
                  merge_store: Optional[MergeLaneStore] = None,
                  t_buckets: Tuple[int, ...] = DEFAULT_T_BUCKETS,
                  storage=None, client_timeout_s: float = 300.0,
-                 send_system=None, config=None, mesh=None,
-                 paged_lanes: bool = False):
+                 send_system=None, config=None, mesh=None):
         """storage: optional callable doc_id -> SummaryTree | None (the
         historian's latest summary). Enables snapshot seeding: merge lanes
         for channels whose base content shipped in a summary bootstrap
@@ -2984,33 +2110,29 @@ class TpuSequencerLambda(IPartitionLambda):
         self.pending: Dict[str, List[_Pending]] = {}
         self.materialize = materialize
         self.merge = merge_store if merge_store is not None else \
-            MergeLaneStore(t_buckets=t_buckets, paged=paged_lanes,
-                           mesh=mesh)
+            MergeLaneStore(t_buckets=t_buckets, mesh=mesh)
         self.lww = LwwLaneStore(t_buckets=t_buckets)
-        if getattr(self.merge, "paged", False) and mesh is not None \
-                and getattr(self.merge.pages, "mesh", None) is None:
+        if mesh is not None and self.merge.pages.mesh is None:
             # An externally provided paged store must already carry the
             # mesh placement: the pool's dispatch selection (donate vs
             # keep — R6) is fixed at ITS construction, and silently
             # serving a single-chip pool under a mesh would re-donate a
             # sharded plane exactly where MESH_DONATION_GATE forbids it.
             raise ValueError(
-                "paged merge_store was constructed without the mesh: "
+                "merge_store was constructed without the mesh: "
                 "pass mesh= to MergeLaneStore/PagedMergeStore so the "
                 "pool places via partition_rules.POOL_PARTITION_RULES "
                 "and dispatches through the non-donating variants "
                 "(docs/serving_pipeline.md R6).")
         if mesh is not None:
             dp = int(mesh.shape.get("dp", 1))
-            for bucket in self.merge.buckets + self.lww.buckets:
+            for bucket in self.lww.buckets:
                 # Grow to a dp multiple BEFORE placing (a 16-chip mesh
-                # cannot shard the default 8 lanes).
+                # cannot shard the default 8 lanes); each table is
+                # placed when it is first built.
                 while bucket.lanes % dp != 0 or bucket.lanes < dp:
                     bucket.grow()
                 bucket.placer = self._place
-                if isinstance(bucket, _LwwBucket):
-                    continue  # placed when its table is first built
-                bucket.state = self._place(bucket.state)
         self._pending_offset: Optional[int] = None
         # Fast-path (raw wire bytes) ingest state: the native pump + its
         # ordinal mirrors. emit_window, when set, receives ONE
@@ -3019,68 +2141,39 @@ class TpuSequencerLambda(IPartitionLambda):
         self._raw_backlog: List[Tuple[int, str, bytes]] = []
         self.poison_frames = 0  # undecodable raw frames dropped (logged)
         self._raw_offsets: Dict[str, int] = {}
-        # Pipelined mode (opt-in): clean fast windows defer their result
-        # fetch/emit into a bounded FIFO ring of dispatched-but-unread
-        # windows, so window k+1's host pack/staging overlaps window k's
-        # device execution and window k-1's narrow readback. The ring
-        # drains in dispatch order; anything lane-state-dependent (slow
-        # windows, fold/rescue, payload GC, summarize extract) forces a
-        # full drain first (docs/serving_pipeline.md).
+        # Pipelined mode (opt-in): a clean fast flush stages all its
+        # windows and leaves as ONE serving megakernel whose result
+        # fetch/emit the NEXT flush's top-of-flush drain joins, so that
+        # flush's native parse overlaps this one's device execution and
+        # readback (one in flight, docs/serving_pipeline.md R10). Risky
+        # windows and unpipelined flushes dispatch and drain at once;
+        # anything lane-state-dependent (slow windows, rescue, payload
+        # GC, summarize extract) forces a full drain first.
         self.pipelined = False
-        self.ring_depth = 4            # max dispatched-but-unread entries
-        self.adaptive_window = True    # per-flush T/depth from latencies
+        # Reported to the admission controller (occupancy_hints) and
+        # bounding the adaptive T choice; the megakernel ring itself is
+        # one flush deep.
+        self.ring_depth = 4
+        self.adaptive_window = True    # per-flush T from the backlog
         self._ring: "deque" = deque()
-        # Fused serving bursts (docs/serving_pipeline.md R8): windows
-        # whose occupancy-hint fit proofs pass stay STAGED (packed but
-        # undispatched) and flush as ONE lax.scan program per
-        # burst_depth windows (serve_step.serve_burst) — the last
-        # per-window host round-trip (dispatch RPC + narrow readback)
-        # amortizes over the whole burst. Requires lane-state donation
-        # (the scan carry is donated), so dp meshes stay on the
-        # per-window ring. Scan lengths draw from the fixed grid so the
-        # burst program's compile cache stays bounded; a remainder of
-        # one window dispatches through plain serve_window.
-        self.fused_bursts = True
-        self.burst_depth = 8           # staged windows per scan cap
+        # Megakernel scan lengths draw from this fixed grid, so the
+        # program's compile cache stays bounded.
         self._burst_k_grid = (2, 4, 8, 16, 32)
         self._staged: List[dict] = []  # packed-not-yet-dispatched windows
-        # Whether this backend's jit call BLOCKS on execution (CPU) or
-        # dispatches asynchronously (TPU) — picks the _device_busy
-        # signal that decides when staged windows stop accumulating.
         import jax as _jax
         on_tpu = _jax.default_backend() == "tpu"
-        self._dispatch_blocking = not on_tpu
-        # Overflow quarantine (mid-ring fold/rescue): channel ordinals
-        # whose lanes were rolled back + host-recovered while later
-        # windows were already in flight. Those windows' rows for these
-        # channels re-apply host-side at their own drain; the sets clear
-        # when the ring fully drains.
-        self._ring_fixup: set = set()
+        # LWW overflow quarantine: channel ordinals whose lanes were
+        # rolled back + host-recovered while later windows of the same
+        # megakernel ring were already computed. Those windows' rows for
+        # these channels re-apply host-side at their own finish; the set
+        # clears when the ring fully drains.
         self._ring_fixup_lww: set = set()
         # Deferred GC cadence: compactions that came due while windows
-        # were in flight (they move lanes, so they only run ring-empty).
+        # were in flight (they move pages, so they only run ring-empty).
         self._gc_due = False
-        # Donation: provably-overflow-free windows dispatch through the
-        # donating serve_window (lane states updated in place); windows
-        # the occupancy hints cannot clear keep their pre states via
-        # serve_window_keep for the fold/rescue rollback. Mesh placements
-        # keep every window on serve_window_keep (ticket-state-only
-        # donation): on jax 0.4.37 a donated dp-sharded lane-state list
-        # reloaded from the persistent compilation cache returns corrupt
-        # lane planes — cold compiles and non-donating reloads are both
-        # correct, only the cache-hit donating executable miscompiles
-        # (repro: tests/test_mesh_serving.py warm vs cold after
-        # clearing the compile cache). Revisit on a jax upgrade.
-        self.donate_lane_states = mesh is None
-        # Bumped by every fast-path fold/rescue/fixup: a flush's staged
-        # lane placement is stale once this moves (re-resolve).
+        # Bumped by every fast-path rescue/fixup: a flush's staged lane
+        # placement is stale once this moves (re-resolve).
         self._recovery_gen = 0
-        # Test/chaos hook: defer even hint-risky windows into the ring,
-        # forcing the mid-ring quarantine fixup path that production
-        # traffic only hits on unpredicted (overlap/anno-ring) overflow.
-        # Donation still follows the gate — risky windows keep their pre
-        # states, which the forced recovery then needs.
-        self.defer_risky_windows = False
         # Fault-injection hook (testing/faultinject.py stall): called at
         # the top of every flush to model a slow device; None in
         # production.
@@ -3090,12 +2183,12 @@ class TpuSequencerLambda(IPartitionLambda):
         # member admission (rare: dup/stale nack inside a run) flags the
         # lane and takes the standard overflow rollback + scalar re-run.
         self.pack_runs = True
-        # Fused VMEM-resident merge apply inside the fast window: the
+        # Fused VMEM-resident merge apply inside the megakernel: the
         # Pallas kernel on the TPU (no probe — a kernel the compiler
-        # refuses takes the counted degrade ladder below), the scan
-        # kernel on the CPU and wherever a bucket exceeds the fused VMEM
-        # budget. Mesh sharding keeps the scan path — the fused kernel
-        # is single-chip.
+        # refuses takes the counted degrade in _dispatch_megakernel_chunk),
+        # the scan kernel on the CPU and wherever a page group exceeds
+        # the fused VMEM budget. Mesh sharding keeps the scan path — the
+        # fused kernel is single-chip.
         self._fused_serve = on_tpu and mesh is None
         self._pump = None
         self._pump_ord: Dict[str, int] = {}     # doc id -> pump ordinal
@@ -3114,11 +2207,8 @@ class TpuSequencerLambda(IPartitionLambda):
         # Directory lanes: lane key -> set of existing subdirectory paths
         # (host structure; rebuilt by replay, seeded from summaries).
         self._dir_paths: Dict[tuple, set] = {}
-        # R10: the native pump runs paged too — paged fast flushes stage
-        # page-group jobs and dispatch the serving megakernel
-        # (serve_step.serve_megakernel), so there is no bucket-grid
-        # dependency left in the hot path and no reason to gate the
-        # toolchain on the storage layout.
+        # The native pump's fast flushes stage page-group jobs and
+        # dispatch the serving megakernel (serve_step.serve_megakernel).
         try:
             from . import pump as _pump_mod
             if _pump_mod.available():
@@ -3129,7 +2219,7 @@ class TpuSequencerLambda(IPartitionLambda):
             # regression on /healthz instead of just running slow.
             record_swallow("sequencer.pump_unavailable")
             self._pump = None
-        # Megakernel fused-phase mode for paged rings on CPU backends:
+        # Megakernel fused-phase mode on CPU backends:
         # False dispatches the scan op-phase INSIDE serve_megakernel
         # (still one device program per ring); True ("interpret") runs
         # the pallas megakernel body under the pallas interpreter so
@@ -3318,9 +2408,12 @@ class TpuSequencerLambda(IPartitionLambda):
         return shard_docs(self.mesh, tree)
 
     def _place_cols(self, arr: np.ndarray, lane_axis: int = 1):
-        """H2D a staging array with its lane axis sharded over 'dp'."""
+        """H2D a staging array with its lane axis sharded over 'dp' (a
+        page group narrower than the mesh rides replicated: its width is
+        a pow2 member count, not a dp multiple)."""
         x = jnp.asarray(arr)
-        if self.mesh is not None:
+        if self.mesh is not None and \
+                x.shape[lane_axis] % int(self.mesh.shape.get("dp", 1)) == 0:
             from jax.sharding import NamedSharding, PartitionSpec
             spec = [None] * x.ndim
             spec[lane_axis] = "dp"
@@ -3481,12 +2574,12 @@ class TpuSequencerLambda(IPartitionLambda):
         slow-path pending queues) and the in-flight WINDOW fill. Host-
         state reads only — never blocks on the device.
 
-        Window-counted, not entry-counted: a K-window fused burst is one
+        Window-counted, not entry-counted: a K-window megakernel is one
         ring entry but K windows of committed in-flight work — reporting
         it as fill 1 would let the controller's latency term read a long
         scan step as calm (ring "mostly empty" zeroes the term). Staged
-        (packed, not yet dispatched) burst windows count too; the
-        controller clamps the resulting fill fraction at 1.0 so bursting
+        (packed, not yet dispatched) windows count too; the controller
+        clamps the resulting fill fraction at 1.0 so a deep megakernel
         by design never throttles on its own."""
         return {
             "staged_ops": len(self._raw_backlog)
@@ -3496,9 +2589,9 @@ class TpuSequencerLambda(IPartitionLambda):
         }
 
     def _in_flight_windows(self) -> int:
-        """Dispatched-but-unread windows (burst entries count their K)
+        """Dispatched-but-unread windows (each ring entry counts its K)
         plus staged-but-undispatched windows."""
-        return sum(e.get("n_windows", 1) for e in self._ring) \
+        return sum(e["n_windows"] for e in self._ring) \
             + len(self._staged)
 
     def _flush_parent(self):
@@ -3538,7 +2631,7 @@ class TpuSequencerLambda(IPartitionLambda):
         # boundary — every window above has fully applied. In-flight ring
         # windows are the same hazard class: their recovery replays
         # op_ids and pre-window rows numbered against the CURRENT table,
-        # so no renumbering while any are in flight — and STAGED burst
+        # so no renumbering while any are in flight — and STAGED
         # windows more so (their packed cols embed op ids and lane
         # placements that a renumber/compaction would invalidate before
         # they even dispatch).
@@ -3565,14 +2658,14 @@ class TpuSequencerLambda(IPartitionLambda):
 
     def _run_fast_gc(self) -> None:
         """The fast path's due lane compactions, at a ring-empty boundary
-        (compact_all/_fold_crowded move lanes; in-flight windows staged
-        against the old placement would corrupt their successors)."""
+        (the defrag tick moves rows and releases pages; in-flight windows
+        staged against the old placement would corrupt their
+        successors)."""
         assert not self._ring and not self._staged
         self._gc_due = False
-        # compact_all's _fold_crowded reseeds channels at new (bucket,
-        # lane) placements: any flush staging resolved before this point
-        # is stale — bump the gen so the window loop re-resolves, exactly
-        # as it does after a fold/rescue.
+        # The defrag tick changes page tables: any flush staging resolved
+        # before this point is stale — bump the gen so the window loop
+        # re-resolves, exactly as it does after a rescue.
         self._recovery_gen += 1
         with tracing.span("serving.gc", hist="serving.gc"):
             if self.merge.flushes_since_compact >= self.merge.compact_every:
@@ -3592,18 +2685,16 @@ class TpuSequencerLambda(IPartitionLambda):
         from . import pump as P
         from .wire import boxcar_from_wire
 
-        if self.merge.paged:
-            # R10 one-in-flight: the previous flush's megakernel ring
-            # drains before ANY of this flush's work — the slow-path
-            # fallback routing below and the staging's flush_lane_for
-            # both read the host page scalars that the drain adopts.
-            # Lane GC that came due mid-ring runs at this now-empty
-            # boundary (the _flush_traced boundary only fires when the
-            # ring is ALREADY empty, which a one-in-flight tail ride
-            # would otherwise starve).
-            self.drain()
-            if self._gc_due:
-                self._run_fast_gc()
+        # R10 one-in-flight: the previous flush's megakernel ring drains
+        # before ANY of this flush's work — the slow-path fallback
+        # routing below and the staging's flush_lane_for both read the
+        # host page scalars that the drain adopts. Lane GC that came due
+        # mid-ring runs at this now-empty boundary (the _flush_traced
+        # boundary only fires when the ring is ALREADY empty, which a
+        # one-in-flight tail ride would otherwise starve).
+        self.drain()
+        if self._gc_due:
+            self._run_fast_gc()
 
         backlog = self._raw_backlog
         self._raw_backlog = []
@@ -3709,20 +2800,17 @@ class TpuSequencerLambda(IPartitionLambda):
         lanes_r = self._pump_lane[doc_col[rows]]
         pos = _cumcount(lanes_r)
         max_per_doc = int(pos.max()) + 1
-        # Pipelining: clean fast windows defer their result fetch + emit
-        # into the in-flight ring (the NEXT flush's native parse then
-        # overlaps their transfers). Flushes with slow-routed docs or
-        # pending object-path messages stay synchronous — their later
-        # work touches the same lane state a deferred recovery might
-        # roll back — and drain the ring BEFORE resolving lanes, so a
-        # drain-time fold/promotion cannot stale this flush's staging.
+        # Pipelining: a clean flush's megakernel defers its result fetch
+        # + emit to the NEXT flush's top-of-flush drain (whose native
+        # parse then overlaps the transfer). Flushes with slow-routed
+        # docs or pending object-path messages stay synchronous — their
+        # later work touches the same lane state a deferred recovery
+        # might roll back.
         defer_ok = (self.pipelined and not slow_ids
                     and not any(self.pending.values()))
-        if not defer_ok:
-            self.drain()
         per_doc = np.bincount(lanes_r)
-        T, depth = self._adaptive_shape(max_per_doc,
-                                        per_doc[per_doc > 0])
+        T, _depth = self._adaptive_shape(max_per_doc,
+                                         per_doc[per_doc > 0])
         win = (pos // T).astype(np.int64)
         slot = (pos % T).astype(np.int64)
         n_windows = int(win.max()) + 1
@@ -3740,180 +2828,80 @@ class TpuSequencerLambda(IPartitionLambda):
 
         row_seq = np.zeros(rows.size, np.int32)
         row_msn = np.zeros(rows.size, np.int32)
-        # Per-window risk (host occupancy hints): a window whose staged
-        # lanes might overflow dispatches SYNCHRONOUSLY behind a full
-        # drain — its fold/rescue then runs with nothing in flight (the
-        # cheap sync recovery), and the expensive mid-ring quarantine
-        # fixup stays reserved for genuinely unpredicted overflow
-        # (overlap-slot / anno-ring exhaustion).
         win_m = win[np.searchsorted(rows, merge_all)] \
             if merge_all.size else np.zeros(0, np.int64)
         win_l = win[np.searchsorted(rows, lww_all)] \
             if lww_all.size else np.zeros(0, np.int64)
-        risky, donate_ok = self._assess_windows(
-            parsed, n_windows, merge_all, win_m, chan_ok, chan_b, chan_l,
-            win_l, lchan_ok, lchan_b, lchan_l)
+        risky, lww_risky = self._assess_windows(
+            parsed, n_windows, merge_all, win_m, chan_ok, win_l, lchan_ok,
+            lchan_b, lchan_l)
         gen_seen = self._recovery_gen
-        if self.merge.paged:
-            # --- paged fast flush (R10) -----------------------------------
-            # Every window stages a page-group job set into the
-            # megakernel ring; the whole flush leaves as ONE
-            # serve_megakernel dispatch below (the next flush's
-            # top-of-flush drain joins it — depth-1 pipelining). Risky
-            # windows (non-insert merge traffic whose overlap/anno rings
-            # may exhaust, or LWW fit risk) flush the staged ring and
-            # drain immediately so their likely rescue runs with nothing
-            # behind it; a rescue moves pages, so the flush re-resolves
-            # its group directory before staging more windows (invariant
-            # R3, paged form).
-            w = 0
-            while w < n_windows:
-                sel = win == w
-                wd = self._stage_fast_window(
-                    parsed, rows[sel], lanes_r[sel], slot[sel], T,
-                    mbase, chan_ok, chan_b, chan_l,
-                    vbase, lchan_ok, lchan_b, lchan_l,
-                    row_seq, sel, row_msn,
-                    donate=self.merge.pages.donate)
-                self._staged.append(wd)
-                increment("serving.ring_windows_deferred")
-                wd["counted_deferred"] = True
-                if risky[w] or not defer_ok:
-                    self._dispatch_staged_megakernel()
-                    self.drain()
-                    if self._recovery_gen != gen_seen:
-                        gen_seen = self._recovery_gen
-                        chan_ok, chan_b, chan_l = \
-                            self._resolve_merge_lanes(
-                                cols[P.CHAN, merge_all])
-                        lchan_ok, lchan_b, lchan_l = \
-                            self._resolve_lww_lanes(cols[P.CHAN, lww_all])
-                        risky, donate_ok = self._assess_windows(
-                            parsed, n_windows, merge_all, win_m, chan_ok,
-                            chan_b, chan_l, win_l, lchan_ok, lchan_b,
-                            lchan_l, start_w=w + 1)
-                w += 1
-            n_windows = 0  # staging done; the bucketed loop must not run
-        burst_on = (defer_ok and self.fused_bursts
-                    and self.donate_lane_states)
-        w = 0
-        while w < n_windows:
-            burst_w = (burst_on and not risky[w]
-                       and bool(donate_ok[w]))
-            defer_w = defer_ok and (not risky[w]
-                                    or self.defer_risky_windows)
-            if burst_w or defer_w:
-                if not burst_w and self._staged:
-                    # A non-burstable window interrupts accumulation:
-                    # dispatch the staged run first (FIFO — emits and
-                    # lane mutations must land in stage order).
-                    increment("serving.burst_breaks")
-                    self._dispatch_staged_burst()
-                # Bounded ring admission: retire the oldest entry once
-                # the ring is full (for burst windows _drain_one first
-                # flushes the staged run as a scan, keeping FIFO).
-                while len(self._ring) >= depth:
-                    self._drain_one()
-                if self._ring_fixup or self._ring_fixup_lww:
-                    self.drain()
-            elif self._staged or self._ring:
-                # Sync dispatch (risky or unpipelined): settle every
-                # in-flight window first — _finish_window's quarantine
-                # direction assumes ring entries are LATER windows.
-                # drain() dispatches any staged burst before joining.
-                if self._staged:
-                    increment("serving.burst_breaks")
-                self.drain()
-            if self._recovery_gen != gen_seen:
-                # A fold/rescue (drained window's, or the previous sync
-                # window's own) may have moved channels: re-resolve this
-                # flush's lane placement and re-assess the remaining
-                # windows (docs/serving_pipeline.md invariant R3).
-                gen_seen = self._recovery_gen
-                chan_ok, chan_b, chan_l = self._resolve_merge_lanes(
-                    cols[P.CHAN, merge_all])
-                lchan_ok, lchan_b, lchan_l = self._resolve_lww_lanes(
-                    cols[P.CHAN, lww_all])
-                risky, donate_ok = self._assess_windows(
-                    parsed, n_windows, merge_all, win_m, chan_ok, chan_b,
-                    chan_l, win_l, lchan_ok, lchan_b, lchan_l, start_w=w)
-                # Re-derive this window's routing against the fresh
-                # placement before staging anything.
-                continue
+
+        def settle(next_w: int) -> None:
+            """Dispatch + drain everything staged; a rescue or recovery
+            that ran moves pages and lanes, so the flush re-resolves its
+            group directory and re-assesses the windows from `next_w`
+            before staging more (invariant R3)."""
+            nonlocal gen_seen, chan_ok, chan_b, chan_l, lchan_ok, \
+                lchan_b, lchan_l, risky, lww_risky
+            self._dispatch_staged()
+            self.drain()
+            if self._recovery_gen == gen_seen:
+                return
+            gen_seen = self._recovery_gen
+            chan_ok, chan_b, chan_l = self._resolve_merge_lanes(
+                cols[P.CHAN, merge_all])
+            lchan_ok, lchan_b, lchan_l = self._resolve_lww_lanes(
+                cols[P.CHAN, lww_all])
+            risky, lww_risky = self._assess_windows(
+                parsed, n_windows, merge_all, win_m, chan_ok, win_l,
+                lchan_ok, lchan_b, lchan_l, start_w=next_w)
+
+        # Every window stages a page-group job set into the megakernel
+        # ring; the whole flush leaves as ONE serve_megakernel dispatch
+        # below (the next flush's top-of-flush drain joins it — depth-1
+        # pipelining). Risky windows (non-insert merge traffic whose
+        # overlap/anno rings may exhaust, or LWW fit risk) flush the
+        # staged ring and drain immediately so their likely rescue runs
+        # with nothing behind it. An LWW-risky window rides a ring of its
+        # own, which keeps its pre-window LWW states for the
+        # rollback + promotion (_dispatch_megakernel_chunk).
+        for w in range(n_windows):
+            if lww_risky[w] and self._staged:
+                settle(w)
             sel = win == w
             wd = self._stage_fast_window(
                 parsed, rows[sel], lanes_r[sel], slot[sel], T,
                 mbase, chan_ok, chan_b, chan_l,
                 vbase, lchan_ok, lchan_b, lchan_l,
-                row_seq, sel, row_msn,
-                donate=self.donate_lane_states and bool(donate_ok[w]))
-            if burst_w:
-                self._staged.append(wd)
-                increment("serving.ring_windows_deferred")
-                # Deferral counted at stage; the dispatch paths below
-                # (burst chunk or solo remainder) must not re-count it.
-                wd["counted_deferred"] = True
-                if len(self._staged) >= self.burst_depth:
-                    self._dispatch_staged_burst()
-            else:
-                self._dispatch_staged_window(wd, defer=defer_w)
-            w += 1
+                row_seq, sel, row_msn)
+            wd["lww_risky"] = bool(lww_risky[w])
+            self._staged.append(wd)
+            increment("serving.ring_windows_deferred")
+            if risky[w] or not defer_ok:
+                settle(w + 1)
 
         emit_args = (bufs,
                      [self._pump_docs[int(o)] for o in doc_col[rows]],
                      rows, cols, row_seq, row_msn)
-        if defer_ok and (self._staged or self._ring):
+        if defer_ok and self._staged:
             # Attached to the flush's LAST window: its drain (after every
             # earlier window filled its row_seq/row_msn slice) emits and
-            # checkpoints for the whole flush. Staged windows are always
-            # newer than every ring entry (dispatch preserves FIFO).
-            if self._staged:
-                self._staged[-1]["emit_args"] = emit_args
-            else:
-                tail = self._ring[-1]
-                if "burst" in tail:
-                    # The flush's last window already dispatched inside
-                    # a burst entry: emits ride that WINDOW's retire so
-                    # ordering stays per-window uniform.
-                    tail["burst"][-1]["emit_args"] = emit_args
-                else:
-                    tail["emit_args"] = emit_args
+            # checkpoints for the whole flush. (A window that dispatched
+            # early — risky — drained with it, so the ring is empty
+            # whenever nothing is staged.)
+            self._staged[-1]["emit_args"] = emit_args
         else:
             self._emit_fast_window(emit_args)
-        # Load-adaptive burst sizing: dispatch whatever accumulated the
-        # moment the DEVICE goes idle — a single staged window rides
-        # plain serve_window (the burst degrades to exactly the
-        # per-window ring under light load, keeping the ring's
-        # pack/execute overlap), while a device running behind lets
-        # staged windows pile up and leave as ONE scan (dispatch count
-        # per window shrinks precisely when dispatch pressure is the
-        # bottleneck). The burst_depth cap above bounds staging memory
-        # and emit latency either way.
-        if self._staged and self.merge.paged:
-            # R10: the flush's staged windows leave as ONE megakernel
+        if self._staged:
+            # The flush's staged windows leave as ONE megakernel
             # dispatch; the NEXT flush's top-of-flush drain joins it.
-            self._dispatch_staged_megakernel()
-        elif self._staged and not self._device_busy():
-            self._dispatch_staged_burst()
+            self._dispatch_staged()
         occ = self._in_flight_windows()
         gauge("serving.ring_occupancy", float(occ))
         peak = max(occ, int(counter_get("serving.ring_peak_occupancy")))
         gauge("serving.ring_peak_occupancy", float(peak))
         return sorted(doc_active.keys() - slow_ids)
-
-    def _device_busy(self) -> bool:
-        """Is dispatched work still in flight? On async backends (the
-        TPU) fetch threads exit the moment their D2H lands, so
-        a live thread means the device or transfer is still working
-        through the ring — staged windows should accumulate into a
-        bigger scan rather than queue behind it. On blocking-dispatch
-        backends (CPU: the jit call runs the program inline, so threads
-        die instantly) undrained ring entries are the only in-flight
-        signal: results nobody has joined yet mean nobody is waiting,
-        so batching costs nothing — dispatches serialize either way."""
-        if any(e["thread"].is_alive() for e in self._ring):
-            return True
-        return self._dispatch_blocking and bool(self._ring)
 
     def _emit_fast_window(self, emit_args) -> None:
         bufs, doc_ids_r, rows, cols, row_seq, row_msn = emit_args
@@ -3946,29 +2934,21 @@ class TpuSequencerLambda(IPartitionLambda):
 
     def drain(self) -> None:
         """Finish EVERY deferred fast window, oldest first: dispatch any
-        staged burst, then join each result transfer, then nacks,
+        staged windows, then join each result transfer, then nacks,
         overflow recovery, the flush's batched emit, and its checkpoint
         — always on the caller's thread, so lane stores are never
-        touched concurrently. A completed full drain clears the overflow
-        quarantine: every window that could carry a quarantined
+        touched concurrently. A completed full drain clears the LWW
+        overflow quarantine: every window that could carry a quarantined
         channel's ops has re-applied them."""
         if self._staged:
             self._dispatch_staged()
         while self._ring:
             self._drain_one()
-        if self._ring_fixup or self._ring_fixup_lww:
-            self._ring_fixup.clear()
-            self._ring_fixup_lww.clear()
+        self._ring_fixup_lww.clear()
 
     def _drain_one(self) -> None:
         """Retire the OLDEST in-flight ring entry (FIFO: emits and lane
-        mutations must land in dispatch order). Staged windows dispatch
-        FIRST: retiring an entry can run a recovery that moves lanes,
-        and staged windows' packed placements must reach the device
-        before any move (their results then ride the same quarantine
-        fixup every later in-flight window does)."""
-        if self._staged:
-            self._dispatch_staged()
+        mutations must land in dispatch order)."""
         ctx = self._ring.popleft()
         increment("serving.ring_drains")
         _t0 = time.perf_counter()
@@ -3981,17 +2961,12 @@ class TpuSequencerLambda(IPartitionLambda):
                             hist="serving.readback", deferred=True)
         if "error" in ctx:
             raise ctx["error"]
-        wins = ctx.get("burst")
-        if wins is None:
-            self._finish_window(ctx)
-            self._retire_window(ctx)
-            return
-        # A burst entry: ONE stacked readback finishes its K windows in
-        # stage order; windows with burst siblings still behind them
-        # quarantine any recovery exactly as if the siblings were ring
-        # entries. Dispatches-per-burst (1 scan + any recovery re-runs
-        # its windows' finish triggers) feeds the burst histogram — the
-        # figure the fused-smoke grades at <= 2.
+        # ONE stacked readback finishes the entry's K windows in stage
+        # order; windows with siblings still behind them quarantine any
+        # LWW recovery. Dispatches-per-burst (1 megakernel + any recovery
+        # re-runs its windows' finish triggers) feeds the burst
+        # histogram.
+        wins = ctx["burst"]
         rec0 = counter_get("serving.recovery_dispatches")
         for k, wd in enumerate(wins):
             wd["flat"] = ctx["flat"][k]
@@ -4063,9 +3038,9 @@ class TpuSequencerLambda(IPartitionLambda):
 
     def _merge_block_and_lanes(self, parsed, merge_rows: np.ndarray):
         """Register the flush's merge payload block and resolve each
-        channel's (bucket, lane), seeding new channels from stored
+        channel's (group, lane), seeding new channels from stored
         summaries exactly as the slow path does. Returns (op-id base,
-        per-row ok mask, bucket array, lane array) aligned to
+        per-row ok mask, group array, lane array) aligned to
         merge_rows."""
         from ..mergetree.host import MergeArenaBlock
         from . import pump as P
@@ -4131,23 +3106,19 @@ class TpuSequencerLambda(IPartitionLambda):
         return mbase, ok_rows, b_rows, l_rows
 
     def _resolve_merge_lanes(self, chans: np.ndarray):
-        """Resolve each merge row's channel to its CURRENT (bucket, lane),
-        seeding new channels from stored summaries exactly as the slow
-        path does. Idempotent — re-run after a mid-ring recovery moved
-        channels (promotion/fold/page rescue) to refresh a flush's
-        staging. Paged stores resolve to per-flush (group, lane)
-        coordinates in a directory rebuilt here (R10): the group is the
-        channel's pow2 page-count class after pre-growing its pages for
-        this flush's op count, so the megakernel's gathered views fit
-        by construction."""
+        """Resolve each merge row's channel to its per-flush (group,
+        lane) coordinate, seeding new channels from stored summaries
+        exactly as the slow path does. The directory is rebuilt here
+        (R10): the group is the channel's pow2 page-count class after
+        pre-growing its pages for this flush's op count, so the
+        megakernel's gathered views fit by construction. Idempotent —
+        re-run after a page rescue to refresh a flush's staging."""
         uniq, inv = np.unique(chans, return_inverse=True)
         ok_u = np.zeros(uniq.size, bool)
         b_u = np.zeros(uniq.size, np.int32)
         l_u = np.zeros(uniq.size, np.int32)
-        paged = self.merge.paged
-        if paged:
-            self.merge.begin_flush_groups()
-            n_by_u = np.bincount(inv, minlength=uniq.size)
+        self.merge.begin_flush_groups()
+        n_by_u = np.bincount(inv, minlength=uniq.size)
         for j, ch in enumerate(uniq.tolist()):
             key = self._pump_chan[ch]
             if key in self.merge.opaque:
@@ -4160,10 +3131,7 @@ class TpuSequencerLambda(IPartitionLambda):
                         self.merge.seed(key, *payload)
                         if key in self.merge.opaque:
                             continue
-            if paged:
-                bb, ll = self.merge.flush_lane_for(key, int(n_by_u[j]))
-            else:
-                bb, ll = self.merge.lane_for(key)
+            bb, ll = self.merge.flush_lane_for(key, int(n_by_u[j]))
             self.merge.mark_dirty(key)
             ok_u[j] = True
             b_u[j] = bb
@@ -4219,7 +3187,7 @@ class TpuSequencerLambda(IPartitionLambda):
 
         The op-depth always comes from the FIXED t_buckets grid — the
         adaptive policy changes which bucket is chosen, never the shape
-        vocabulary, so serve_window's compile cache stays bounded
+        vocabulary, so serve_megakernel's compile cache stays bounded
         (JitRetraceProbe-checked in tests/test_pipelined_serving.py).
 
         Policy: T follows the p95 per-doc depth, not the max. A RAGGED
@@ -4273,14 +3241,13 @@ class TpuSequencerLambda(IPartitionLambda):
     def _stage_fast_window(self, parsed, rows, lanes, slot, T,
                            mbase, chan_ok, chan_b, chan_l,
                            vbase, lchan_ok, lchan_b, lchan_l,
-                           row_seq, flush_sel, row_msn,
-                           donate: bool = False) -> dict:
+                           row_seq, flush_sel, row_msn) -> dict:
         """Pack one fast window into host staging arrays + job records —
         everything the dispatch needs EXCEPT the device call, so a
-        window can sit in the staged-burst queue across flushes. The
-        in-flight occupancy bound (hint_pending) is charged HERE: later
-        flushes' fit proofs must see staged windows' worst-case rows
-        whether or not they have dispatched yet."""
+        window can sit in the staged queue until its flush's megakernel
+        leaves. The in-flight LWW occupancy bound (hint_pending) is
+        charged HERE: later windows' fit proofs must see staged windows'
+        worst-case key slots whether or not they have dispatched yet."""
         from . import pump as P
         cols = parsed.cols
         B = self.lanes
@@ -4299,15 +3266,11 @@ class TpuSequencerLambda(IPartitionLambda):
             lww_jobs = self._build_lww(parsed, rows, lanes, slot,
                                        vbase, lchan_ok, lchan_b, lchan_l)
 
-        # In-flight occupancy bound: each staged merge op adds at most 2
-        # rows, each LWW op at most one key slot; confirmed exactly (and
-        # removed from pending) when this window's occupancy plane comes
-        # back at its drain. Paged merge needs no charge — flush_lane_for
-        # pre-grew every member's pages for the whole flush's op count.
-        if not self.merge.paged:
-            for j in merge_jobs:
-                np.add.at(self.merge.buckets[j["bucket"]].hint_pending,
-                          j["lanes"], 2)
+        # In-flight occupancy bound: each LWW op adds at most one key
+        # slot; confirmed exactly (and removed from pending) when this
+        # window's occupancy plane comes back at its drain. Merge needs
+        # no charge — flush_lane_for pre-grew every member's pages for
+        # the whole flush's op count.
         for j in lww_jobs:
             np.add.at(self.lww.buckets[j["bucket"]].hint_pending,
                       j["lanes"], 1)
@@ -4319,10 +3282,9 @@ class TpuSequencerLambda(IPartitionLambda):
                 "merge_jobs": merge_jobs, "lww_jobs": lww_jobs,
                 "mbase": mbase, "block": self._flush_merge_block,
                 "row_seq": row_seq, "row_msn": row_msn,
-                "donated": donate,
                 # Staged placements go stale the moment a recovery moves
                 # lanes; the GC/drain discipline guarantees gen cannot
-                # move while a window sits staged (staged bursts always
+                # move while a window sits staged (staged windows always
                 # dispatch before any join/recovery), so stage-time gen
                 # IS dispatch-time gen.
                 "gen": self._recovery_gen,
@@ -4333,21 +3295,14 @@ class TpuSequencerLambda(IPartitionLambda):
                 # The flush's trace position, so the deferred readback
                 # (joined by a LATER flush's drain) attributes to the
                 # window that dispatched it, not the one that drained it.
-                "trace_ctx": tracing.current(),
-                # Degrade-path restage context (the fused INSERT_RUN
-                # variant failing at a production shape re-builds the
-                # merge jobs without packing): the originating flush's
-                # row universe, valid cross-flush because the arrays are
-                # immutable snapshots.
-                "rebuild": (rows, lanes, slot, mbase, chan_ok, chan_b,
-                            chan_l, self._flush_merge_rows)}
+                "trace_ctx": tracing.current()}
 
     def _pad_staged_window(self, wd: dict) -> None:
         """Re-shape a staged window's cols to CURRENT table widths: doc
-        lanes and bucket lanes may have grown (new docs/channels in a
-        later flush) between staging and dispatch. Growth only appends
-        lanes, so zero-padding the lane axis (NOOP rows) is exact; T/Tm
-        never change after staging."""
+        lanes, flush groups and LWW bucket lanes may have grown (later
+        windows of the flush admitted more members) between staging and
+        dispatch. Growth only appends lanes, so zero-padding the lane
+        axis (NOOP rows) is exact; T/Tm never change after staging."""
         B = self.lanes
         tc = wd["ticket_cols"]
         if tc.shape[1] < B:
@@ -4357,13 +3312,8 @@ class TpuSequencerLambda(IPartitionLambda):
             wd["ticket_cols"] = grown
         wd["B"] = B
         for j in wd["merge_jobs"]:
-            if self.merge.paged:
-                # R10: pad to the flush group's CURRENT pow2 width —
-                # later windows of the same flush may have admitted more
-                # members into the group.
-                width = self.merge.flush_groups[j["bucket"]].lanes
-            else:
-                width = self.merge.buckets[j["bucket"]].lanes
+            # Pad to the flush group's CURRENT pow2 width.
+            width = self.merge.flush_groups[j["bucket"]].lanes
             c = j["cols"]
             if c is not None and c.shape[1] < width:
                 grown = np.zeros((12, width, c.shape[2]), np.int32)
@@ -4387,325 +3337,6 @@ class TpuSequencerLambda(IPartitionLambda):
                 j["cols"] = grown
             j["lanes_n"] = bucket.lanes
 
-    def _dispatch_staged_window(self, wd: dict, defer: bool) -> None:
-        """Dispatch ONE staged window: the fused device program, then
-        either an immediate result fetch (_finish_window) or — pipelined
-        — a background transfer joined by the next drain()."""
-        from . import serve_step
-        self._pad_staged_window(wd)
-        donate = wd["donated"]
-        merge_jobs, lww_jobs = wd["merge_jobs"], wd["lww_jobs"]
-        ticket_cols = wd["ticket_cols"]
-
-        # Buffer donation (decided by _assess_windows' occupancy-hint fit
-        # proof): donated windows update lane states in place — no fresh
-        # HBM allocation per window; kept windows retain the pre states
-        # the fold/rescue rollback scatters back.
-        increment("serving.ring_donated_windows" if donate
-                  else "serving.ring_kept_windows")
-        increment("serving.window_dispatches")
-
-        # Device telemetry plane (telemetry/device_stats.py): static at
-        # dispatch, stamped on the window so _finish_window decodes the
-        # flat16 tail only when this window actually carried it.
-        stats_on = device_stats.enabled()
-        wd["stats"] = stats_on
-
-        # ONE fused device program for the whole window (every extra
-        # dispatch pays a fixed host cost), then ONE host sync of the
-        # narrow int16 result (msn32_dev is fetched only on the rare
-        # msn-span overflow).
-        def dispatch(fused):
-            step = serve_step.serve_window if donate \
-                else serve_step.serve_window_keep
-            ledger_name = "serve.window" if donate else "serve.window_keep"
-            with compile_ledger.track(ledger_name, step):
-                return step(
-                    self.tstate, self._place_cols(ticket_cols),
-                    [self.merge.buckets[j["bucket"]].state
-                     for j in merge_jobs],
-                    [self._place_cols(j["cols"]) for j in merge_jobs],
-                    [self.lww.buckets[j["bucket"]].state
-                     for j in lww_jobs],
-                    [self._place_cols(j["cols"]) for j in lww_jobs],
-                    fused,
-                    [None if j["runs"] is None
-                     else self._place_cols(j["runs"])
-                     for j in merge_jobs],
-                    stats_on)
-
-        with tracing.span("serving.dispatch", hist="serving.dispatch"):
-            try:
-                (self.tstate, new_merge, new_lww, flat_dev,
-                 msn32_dev) = dispatch(self._fused_serve)
-            except Exception as err:  # noqa: BLE001 — degrade, never crash
-                if not self._fused_serve:
-                    raise
-                # The fused path, chosen at construction on a TPU, failed
-                # to lower at THIS production shape (e.g. the runs
-                # variant's 24 extra op columns blew the VMEM budget at a
-                # large (capacity, T)). Failures happen at lowering,
-                # before execution, so the donated buffers are intact.
-                # If this window carries runs, drop PACKING (keep the
-                # fused kernel for plain buckets) and re-stage; else
-                # forfeit fused. Either way, log loudly — a silent
-                # degrade would hide both a Mosaic regression and the
-                # perf cliff.
-                import logging
-                increment("sequencer.fused_degrades")
-                had_runs = any(j["runs"] is not None for j in merge_jobs)
-                if had_runs and self.pack_runs:
-                    self.pack_runs = False
-                    logging.getLogger(__name__).warning(
-                        "fused INSERT_RUN variant failed at a production "
-                        "shape; disabling run packing (%r)", err)
-                    merge_jobs = self._restage_merge_jobs(wd)
-                    wd["merge_jobs"] = merge_jobs
-                    try:
-                        (self.tstate, new_merge, new_lww, flat_dev,
-                         msn32_dev) = dispatch(self._fused_serve)
-                    except Exception as err2:  # noqa: BLE001
-                        increment("sequencer.fused_degrades")
-                        self._fused_serve = False
-                        logging.getLogger(__name__).warning(
-                            "fused serving failed without runs too; scan "
-                            "path from now on (%r)", err2)
-                        (self.tstate, new_merge, new_lww, flat_dev,
-                         msn32_dev) = dispatch(False)
-                else:
-                    self._fused_serve = False
-                    logging.getLogger(__name__).warning(
-                        "fused serving apply failed; scan path from now "
-                        "on (%r)", err)
-                    (self.tstate, new_merge, new_lww, flat_dev,
-                     msn32_dev) = dispatch(False)
-        for j, post in zip(merge_jobs, new_merge):
-            self.merge.buckets[j["bucket"]].state = post
-            if donate:
-                # The donated pre-state buffers were consumed in place;
-                # drop the stale reference so a recovery bug trips the
-                # explicit pre-is-None degrade, not a deleted-buffer read.
-                j["pre"] = None
-        for j, post in zip(lww_jobs, new_lww):
-            self.lww.buckets[j["bucket"]].state = post
-            if donate:
-                j["pre"] = None
-
-        wd["msn32_dev"] = msn32_dev
-        if defer:
-            import threading
-
-            def fetch():
-                try:
-                    wd["flat"] = np.asarray(flat_dev)
-                except Exception as err:  # noqa: BLE001 — surface at join
-                    wd["error"] = err
-
-            wd["thread"] = threading.Thread(target=fetch, daemon=True)
-            wd["thread"].start()
-            self._ring.append(wd)
-            if not wd.pop("counted_deferred", False):
-                increment("serving.ring_windows_deferred")
-        else:
-            with tracing.span("serving.readback",
-                              hist="serving.readback"):
-                wd["flat"] = np.asarray(flat_dev)  # the window's ONE sync
-            self._finish_window(wd)
-
-    def _restage_merge_jobs(self, wd: dict) -> List[dict]:
-        """Rebuild a staged window's merge jobs (degrade path: packing
-        just turned off) against its ORIGINATING flush's row universe,
-        preserving the hint_pending charge (same rows, same lanes, same
-        +2-per-op bound — no re-add)."""
-        (rows, lanes, slot, mbase, chan_ok, chan_b, chan_l,
-         flush_rows) = wd["rebuild"]
-        return self._build_merge(wd["parsed"], rows, lanes, slot, mbase,
-                                 chan_ok, chan_b, chan_l,
-                                 flush_rows=flush_rows)
-
-    def _dispatch_staged_burst(self) -> None:
-        """Dispatch EVERY staged window, oldest first, as fused scan
-        bursts: consecutive staged windows sharing a ticket depth T
-        chunk into scan lengths from the fixed grid (compile-cache
-        bound); a remainder of one dispatches through plain
-        serve_window. Always empties the staged queue — callers rely on
-        'staged dispatched before any join' to keep recovery
-        quarantine's window-ordering invariant."""
-        staged, self._staged = self._staged, []
-        i = 0
-        while i < len(staged):
-            # Longest same-T run from i (T is baked into the stacked
-            # ticket planes and the flat16 layout).
-            run = i + 1
-            while (run < len(staged)
-                   and staged[run]["T"] == staged[i]["T"]):
-                run += 1
-            while i < run:
-                left = run - i
-                k = 1
-                for cand in self._burst_k_grid:
-                    if cand <= left:
-                        k = cand
-                if k >= 2:
-                    if not self._dispatch_burst_chunk(staged[i:i + k]):
-                        # Lowering failed (counted/logged there): fall
-                        # back to per-window dispatch for this chunk.
-                        for wd in staged[i:i + k]:
-                            self._dispatch_staged_window(wd, defer=True)
-                else:
-                    self._dispatch_staged_window(staged[i], defer=True)
-                i += k
-
-    def _dispatch_burst_chunk(self, wins: List[dict]) -> bool:
-        """ONE scanned device program for K staged windows: stack every
-        window's packed op planes (NOOP-padded to the union of staged
-        buckets), dispatch serve_burst with the donated lane-bucket
-        carry, and enter the ring as a single entry whose drain finishes
-        all K windows off the stacked narrow result. Returns False if
-        the burst program failed to lower (donated buffers intact — the
-        caller falls back to per-window dispatch)."""
-        from . import serve_step
-        K = len(wins)
-        for wd in wins:
-            self._pad_staged_window(wd)
-        B, T = self.lanes, wins[0]["T"]
-
-        with tracing.span("serving.pack", hist="serving.pack",
-                          stage="burst-stack"):
-            # Every member passed _pad_staged_window, so each
-            # ticket_cols is exactly [4, B, T] and fills its full slice.
-            tx = np.empty((K, 4, B, T), np.int32)
-            for k, wd in enumerate(wins):
-                tx[k] = wd["ticket_cols"]
-
-            def stack_jobs(job_lists, buckets, ncols, fills):
-                """Union-bucket stacking: per bucket one [K, ncols,
-                lanes, Tm] plane (+ runs for merge); windows without the
-                bucket ride all-NOOP padding, and every window's job
-                list is rewritten union-aligned so _finish_window parses
-                the shared flat16 layout."""
-                ids = sorted({j["bucket"] for jl in job_lists for j in jl})
-                xs, rxs, states = [], [], []
-                aligned: List[List[dict]] = [[] for _ in wins]
-                for b in ids:
-                    bucket = buckets[b]
-                    jobs = [next((j for j in jl if j["bucket"] == b),
-                                 None) for jl in job_lists]
-                    tm = max(j["cols"].shape[2] for j in jobs
-                             if j is not None)
-                    arr = np.zeros((K, ncols, bucket.lanes, tm), np.int32)
-                    for plane, fill in fills:
-                        arr[:, plane] = fill
-                    has_runs = any(j is not None and j.get("runs")
-                                   is not None for j in jobs)
-                    rarr = None
-                    if has_runs:
-                        from ..mergetree.oppack import RUN_K
-                        rarr = np.zeros((K, 4, bucket.lanes, tm, RUN_K),
-                                        np.int32)
-                    for k, j in enumerate(jobs):
-                        if j is None:
-                            aligned[k].append(self._empty_job(
-                                b, bucket.lanes))
-                            continue
-                        c = j["cols"]
-                        arr[k, :, :c.shape[1], :c.shape[2]] = c
-                        if rarr is not None and j.get("runs") is not None:
-                            r = j["runs"]
-                            rarr[k, :, :r.shape[1], :r.shape[2], :] = r
-                        aligned[k].append(j)
-                    xs.append(self._place_cols(arr, lane_axis=2))
-                    rxs.append(None if rarr is None else
-                               self._place_cols(rarr, lane_axis=2))
-                    states.append(bucket.state)
-                return ids, xs, rxs, states, aligned
-
-            m_ids, merge_xs, runs_xs, merge_states, m_aligned = stack_jobs(
-                [wd["merge_jobs"] for wd in wins], self.merge.buckets,
-                12, ())
-            l_ids, lww_xs, _, lww_states, l_aligned = stack_jobs(
-                [wd["lww_jobs"] for wd in wins], self.lww.buckets,
-                6, ((1, -1), (2, -1)))
-
-        stats_on = device_stats.enabled()
-        with tracing.span("serving.dispatch", hist="serving.dispatch"):
-            try:
-                with compile_ledger.track("serve.burst",
-                                          serve_step.serve_burst):
-                    (self.tstate, new_merge, new_lww, flats_dev,
-                     msns_dev) = serve_step.serve_burst(
-                        self.tstate, tuple(merge_states),
-                        tuple(lww_states),
-                        self._place_cols(tx, lane_axis=2),
-                        tuple(merge_xs), tuple(lww_xs), tuple(runs_xs),
-                        self._fused_serve, stats_on)
-            except Exception as err:  # noqa: BLE001 — degrade, never crash
-                # Lowering failures leave the donated buffers intact
-                # (same contract as the per-window degrade ladder); the
-                # per-window fallback then runs its own fused degrade.
-                # Job lists are still the windows' OWN (union alignment
-                # is adopted only below, on success), so serve_window
-                # re-dispatches them unchanged. A POST-lowering failure
-                # (device OOM mid-scan) may have consumed the donated
-                # carry, though — falling back onto deleted/corrupt lane
-                # buffers would materialize garbage, so probe for it and
-                # re-raise: that failure mode has no safe recovery.
-                def _gone(tree):
-                    leaf = jax.tree_util.tree_leaves(tree)
-                    return bool(leaf) and bool(
-                        getattr(leaf[0], "is_deleted", bool)())
-                if (_gone(self.tstate) or any(map(_gone, merge_states))
-                        or any(map(_gone, lww_states))):
-                    raise
-                import logging
-                increment("serving.burst_fallbacks")
-                logging.getLogger(__name__).warning(
-                    "fused burst scan failed at K=%d; dispatching the "
-                    "chunk per-window (%r)", K, err)
-                return False
-        for k, wd in enumerate(wins):
-            # Union-aligned job lists: each window's _finish_window
-            # parses the SHARED flat16 layout (one plane set per union
-            # bucket), so its jobs must cover every union bucket in
-            # order — placeholders for buckets it never staged.
-            wd["merge_jobs"] = m_aligned[k]
-            wd["lww_jobs"] = l_aligned[k]
-            wd["stats"] = stats_on
-            # The scan body runs with noop_skip: the host mirror of the
-            # device skip counter needs to know (solo windows never
-            # count skips).
-            wd["noop_skip"] = True
-        for b, post in zip(m_ids, new_merge):
-            self.merge.buckets[b].state = post
-        for b, post in zip(l_ids, new_lww):
-            self.lww.buckets[b].state = post
-        for k, wd in enumerate(wins):
-            for j in wd["merge_jobs"] + wd["lww_jobs"]:
-                # The scan carry was donated: no per-window pre states
-                # exist (burst admission proved the windows overflow-
-                # free; unpredicted overflow takes the donated degrade
-                # + quarantine path, exactly as per-window donation).
-                j["pre"] = None
-            wd["msn32_dev"] = msns_dev[k]
-        increment("serving.ring_donated_windows", K)
-        increment("serving.bursts")
-        increment("serving.burst_windows", K)
-
-        entry = {"burst": wins, "n_windows": K,
-                 "trace_ctx": wins[-1]["trace_ctx"]}
-        import threading
-
-        def fetch():
-            try:
-                entry["flat"] = np.asarray(flats_dev)  # [K, flat] D2H
-            except Exception as err:  # noqa: BLE001 — surface at join
-                entry["error"] = err
-
-        entry["thread"] = threading.Thread(target=fetch, daemon=True)
-        entry["thread"].start()
-        self._ring.append(entry)
-        return True
-
     @staticmethod
     def _empty_job(bucket: int, lanes_n: int) -> dict:
         """A window's placeholder for a union bucket it never staged:
@@ -4715,13 +3346,6 @@ class TpuSequencerLambda(IPartitionLambda):
         return {"bucket": bucket, "pre": None, "cols": None, "runs": None,
                 "lanes_n": lanes_n, "chan": z, "rows": z, "lanes": z,
                 "op_ids": z, "val_ids": z, "doc_lane": z, "slot": z}
-
-    def _dispatch_staged(self) -> None:
-        """Route the staged queue to its storage layout's dispatcher."""
-        if self.merge.paged:
-            self._dispatch_staged_megakernel()
-        else:
-            self._dispatch_staged_burst()
 
     def _mega_fused_mode(self):
         """Op-phase mode for the megakernel scan body: True runs the
@@ -4735,13 +3359,17 @@ class TpuSequencerLambda(IPartitionLambda):
             return "interpret"
         return False
 
-    def _dispatch_staged_megakernel(self) -> None:
+    def _dispatch_staged(self) -> None:
         """Dispatch EVERY staged window, oldest first, as serving
         megakernels (R10): consecutive windows sharing a ticket depth T
         chunk into scan lengths from the fixed burst grid (the jit
         cache sees only grid-quantized signatures, never the raw
-        backlog length). Always empties the staged queue — same
-        contract as _dispatch_staged_burst."""
+        backlog length). Always empties the staged queue — callers rely
+        on 'staged dispatched before any join' to keep the recovery
+        quarantine's window-ordering invariant. The host page scalars are
+        authoritative only while ONE ring is in flight, so a chunk waits
+        for the one before it to land before it stages its page
+        tables."""
         staged, self._staged = self._staged, []
         i = 0
         while i < len(staged):
@@ -4755,12 +3383,14 @@ class TpuSequencerLambda(IPartitionLambda):
                 for cand in self._burst_k_grid:
                     if cand <= left:
                         k = cand
+                while self._ring:
+                    self._drain_one()
                 self._dispatch_megakernel_chunk(staged[i:i + k])
                 i += k
 
     def _dispatch_megakernel_chunk(self, wins: List[dict]) -> None:
-        """ONE persistent device program for K staged paged windows
-        (R10): gather every flush group's pages into views, scan the K
+        """ONE persistent device program for K staged windows (R10):
+        gather every flush group's pages into views, scan the K
         windows' op planes over them (pallas fused apply+extract, its
         interpreted twin, or the scan kernel — _mega_fused_mode),
         scatter the views back, and enter the ring as a single entry
@@ -4781,10 +3411,12 @@ class TpuSequencerLambda(IPartitionLambda):
                 tx[k] = wd["ticket_cols"]
 
             def stack_group_jobs(job_lists):
-                """Union-group stacking — _dispatch_burst_chunk's
-                stack_jobs with flush groups as the bucket axis and no
-                pre states (the megakernel's readback carries the
-                gathered pre views instead)."""
+                """Union-group stacking: per flush group one [K, 12,
+                width, Tm] plane (+ runs); windows without the group
+                ride all-NOOP padding, and every window's job list is
+                rewritten union-aligned so _finish_window parses the
+                shared flat16 layout. No pre states: the megakernel's
+                readback carries the gathered pre views instead."""
                 ids = sorted({j["bucket"] for jl in job_lists
                               for j in jl})
                 xs, rxs = [], []
@@ -4849,12 +3481,21 @@ class TpuSequencerLambda(IPartitionLambda):
             l_ids, lww_xs, lww_states, l_aligned = stack_lww_jobs(
                 [wd["lww_jobs"] for wd in wins])
             # Page-id tables + pre-ring scalars per union group, staged
-            # at dispatch (host-authoritative under one-in-flight).
+            # at dispatch (host-authoritative under one-in-flight). Each
+            # doc first holds pages for this ring's worst case (2 rows
+            # per op + slack): flush_lane_for pre-grew the whole flush,
+            # but an earlier ring of the flush may have settled since and
+            # released the pages it left unused.
             pg = self.merge.pages
             group_info: Dict[int, dict] = {}
             pids_l, counts_l, mins_l, seqs_l = [], [], [], []
             for gi, g in enumerate(m_ids):
                 grp = self.merge.flush_groups[g]
+                n_ops = np.zeros(len(grp.keys), np.int64)
+                for jobs in m_aligned:
+                    np.add.at(n_ops, jobs[gi]["lanes"], 1)
+                for key, n in zip(grp.keys, n_ops.tolist()):
+                    pg.ensure_rows(key, pg.counts[key] + 2 * n + 8)
                 n_pad, pids, counts, mins, seqs = \
                     self.merge._stage_paged_group(grp.keys)
                 assert n_pad == self.merge.flush_groups[g].lanes
@@ -4871,6 +3512,14 @@ class TpuSequencerLambda(IPartitionLambda):
             else serve_step.serve_megakernel_keep
         name = "serve.megakernel" if donate else "serve.megakernel_keep"
         tx_dev = self._place_cols(tx, lane_axis=2)
+        # An LWW-risky window rides a ring of its own (_flush_raw): its
+        # LWW lane states outlive the dispatch as the pre-window rows the
+        # rollback + promotion reads (_recover_fast_lww). The donating
+        # program consumes its inputs, so it gets copies.
+        lww_pre = None
+        if K == 1 and wins[0].get("lww_risky"):
+            lww_pre = [jax.tree_util.tree_map(jnp.copy, st) if donate
+                       else st for st in lww_states]
 
         def _dispatch(mode):
             with compile_ledger.track(name, fn):
@@ -4886,9 +3535,11 @@ class TpuSequencerLambda(IPartitionLambda):
             except Exception as err:  # noqa: BLE001 — degrade, not crash
                 # The pallas phases failed to lower: fall back to the
                 # scan op-phase INSIDE the same megakernel (still one
-                # dispatch per ring). A post-lowering failure may have
-                # consumed the donated carry — probe and re-raise, as
-                # in _dispatch_burst_chunk.
+                # dispatch per ring). Lowering failures leave the donated
+                # buffers intact; a POST-lowering failure (device OOM
+                # mid-scan) may have consumed the donated carry, and
+                # falling back onto deleted/corrupt lane buffers would
+                # materialize garbage — so probe for it and re-raise.
                 def _gone(tree):
                     leaf = jax.tree_util.tree_leaves(tree)
                     return bool(leaf) and bool(
@@ -4915,17 +3566,17 @@ class TpuSequencerLambda(IPartitionLambda):
             wd["merge_jobs"] = m_aligned[k]
             wd["lww_jobs"] = l_aligned[k]
             wd["stats"] = stats_on
-            wd["noop_skip"] = True
-            wd["paged"] = True
             wd["paged_shared"] = shared
-            for j in wd["merge_jobs"] + wd["lww_jobs"]:
-                # The carry was donated (or the keep twin holds the pre
-                # views in its readback): per-window bucket pre states
-                # never exist on this path.
-                j["pre"] = None
+            # Merge rollback reads the gathered pre views in the
+            # readback; LWW pre states exist only for an LWW-risky
+            # window's own ring.
+            for li, j in enumerate(wd["lww_jobs"]):
+                j["pre"] = None if lww_pre is None else lww_pre[li]
             wd["msn32_dev"] = msns_dev[k]
         increment("serving.megakernel_rings")
         increment("serving.megakernel_windows", K)
+        increment("serving.ring_donated_windows" if donate
+                  else "serving.ring_kept_windows", K)
         increment("serving.bursts")
         increment("serving.burst_windows", K)
 
@@ -4943,74 +3594,36 @@ class TpuSequencerLambda(IPartitionLambda):
         entry["thread"].start()
         self._ring.append(entry)
 
-    def _assess_windows(self, parsed, n_windows: int,
-                        merge_all, win_m, chan_ok, chan_b, chan_l,
-                        win_l, lchan_ok, lchan_b, lchan_l,
+    def _assess_windows(self, parsed, n_windows: int, merge_all, win_m,
+                        chan_ok, win_l, lchan_ok, lchan_b, lchan_l,
                         start_w: int = 0):
-        """Per-window (risky, donate_ok) from the host occupancy hints.
+        """Per-window (risky, lww_risky) from the host occupancy hints:
+        risky windows dispatch and drain at once, so their likely rescue
+        or recovery runs with nothing staged behind it; lww_risky marks
+        the windows whose risk is an LWW key-slot fit.
 
-        risky[w]: some staged lane's ROW fit cannot be proven —
-        `hint + 2*inserts + 8 > capacity` (merge, each op adds at most 2
-        rows) or `hint + ops + 4 > capacity` key slots (LWW). Risky
-        windows dispatch synchronously so their likely fold/rescue runs
-        the cheap empty-ring recovery.
+        Merge: no row-fit risk (flush_lane_for pre-grew pages for the
+        flush's worst case), but non-insert traffic is risky — removes
+        and annotates touch the overlap/anno rings, whose exhaustion
+        needs the pre views for rollback.
 
-        donate_ok[w]: not risky AND insert-only merge traffic — removes
-        touch the overlap ring and annotates the anno ring, neither
-        bounded by the count hint, so those windows keep their pre
-        states (their rare exhaustion overflow needs the rollback). The
-        margins mirror the recovery paths' +8 re-run slack convention.
-
-        The bound ACCUMULATES across this flush's windows (from
-        `start_w`, where earlier windows' charges already live in
-        hint_pending): window w's fit proof counts windows start_w..w-1
-        worst-case rows on the shared lanes, because none of them will
-        have confirmed occupancy before w dispatches — with fused bursts
-        a whole run of windows dispatches in one scan before ANY plane
-        comes back, so a per-window-only bound would under-count deep
-        docs and break the donated-dispatch soundness invariant."""
+        LWW: `hint + ops + 4 > capacity` key slots. The bound
+        ACCUMULATES across this flush's windows (from `start_w`, where
+        earlier windows' charges already live in hint_pending): window
+        w's fit proof counts windows start_w..w-1 worst-case key slots
+        on the shared lanes, because a whole run of windows dispatches
+        in one megakernel before ANY occupancy plane comes back."""
         from . import pump as P
         cols = parsed.cols
         risky = np.zeros(n_windows, bool)
-        donate_ok = np.ones(n_windows, bool)
-        acc_m: Dict[int, np.ndarray] = {}
+        lww_risky = np.zeros(n_windows, bool)
         acc_l: Dict[int, np.ndarray] = {}
         mk = cols[P.MKIND, merge_all] if merge_all.size else None
-        paged = self.merge.paged
         for w in range(start_w, n_windows):
-            if mk is not None and paged:
-                # R10: paged merge has no row-fit risk (flush_lane_for
-                # pre-grew pages for the flush's worst case) but
-                # non-insert traffic still forfeits donation — removes
-                # and annotates touch the overlap/anno rings, whose
-                # exhaustion needs the pre views for rollback. The
-                # megakernel keeps pre views in its own readback, so
-                # this only routes the window to an immediate
-                # dispatch+drain (nothing stacks behind a likely
-                # rescue).
+            if mk is not None:
                 ws = chan_ok & (win_m == w)
                 if ws.any() and np.any(mk[ws] != 1):
                     risky[w] = True
-            elif mk is not None:
-                ws = chan_ok & (win_m == w)
-                if ws.any():
-                    if np.any(mk[ws] != 1):
-                        donate_ok[w] = False
-                    for b in np.unique(chan_b[ws]).tolist():
-                        bucket = self.merge.buckets[b]
-                        bsel = ws & (chan_b == b)
-                        ins = np.bincount(chan_l[bsel & (mk == 1)],
-                                          minlength=bucket.lanes)
-                        touched = np.unique(chan_l[bsel])
-                        acc = acc_m.setdefault(
-                            b, np.zeros(bucket.lanes, np.int64))
-                        bound = bucket.count_hint[touched] \
-                            + bucket.hint_pending[touched] \
-                            + acc[touched]
-                        if np.any(bound + 2 * ins[touched] + 8
-                                  > bucket.capacity):
-                            risky[w] = True
-                        acc += 2 * ins
             if lchan_ok.size:
                 ws = lchan_ok & (win_l == w)
                 if ws.any():
@@ -5027,10 +3640,9 @@ class TpuSequencerLambda(IPartitionLambda):
                             + acc[touched]
                         if np.any(bound + per[touched] + 4
                                   > bucket.capacity):
-                            risky[w] = True
+                            lww_risky[w] = True
                         acc += per
-        donate_ok &= ~risky
-        return risky, donate_ok
+        return risky | lww_risky, lww_risky
 
     def _finish_window(self, ctx) -> None:
         """The post-fetch half of a fast window: seq/msn distribution,
@@ -5049,8 +3661,8 @@ class TpuSequencerLambda(IPartitionLambda):
             return ((hi.astype(np.int64) << 16)
                     | (lo.astype(np.int64) & 0xFFFF)).astype(np.int64)
 
-        # Narrow layout (serve_step.serve_window): int16 deltas + int32
-        # lane scalars as (lo, hi) halves + [msn_ok | overflow bits].
+        # Narrow layout (serve_step._serve_window_impl): int16 deltas +
+        # int32 lane scalars as (lo, hi) halves + [msn_ok | overflow bits].
         seq_d = flat[:bt].reshape(B, T).astype(np.int64)
         msn_d = flat[bt:2 * bt].reshape(B, T).astype(np.int64)
         fl_bt = flat[2 * bt:3 * bt].reshape(B, T)
@@ -5062,53 +3674,50 @@ class TpuSequencerLambda(IPartitionLambda):
         nm, nl = len(merge_jobs), len(lww_jobs)
         msn_ok = tailbits[0]
         bits = tailbits[1:2 + nm + nl]
-        # Per-lane overflow planes (one int16 per staged bucket lane,
-        # serve_step.serve_window layout): merge jobs then LWW jobs, each
-        # lanes_n wide — recovery never touches the (possibly donated)
-        # post states. The occupancy (count) planes follow in the same
-        # order.
+        # Per-lane overflow planes (one int16 per staged lane): merge jobs
+        # then LWW jobs, each lanes_n wide — recovery never touches the
+        # (possibly donated) post states. The occupancy (count) planes
+        # follow in the same order.
         plane_total = sum(j["lanes_n"] for j in merge_jobs) \
             + sum(j["lanes_n"] for j in lww_jobs)
         planes = tailbits[2 + nm + nl:2 + nm + nl + plane_total]
         cnt_planes = tailbits[2 + nm + nl + plane_total:
                               2 + nm + nl + 2 * plane_total]
         tail_base = 2 + nm + nl + 2 * plane_total
-        if ctx.get("paged"):
-            # Megakernel scalar-adoption plane (R10): each page group's
-            # post count/min_seq/seq as exact int32 halves — the int16
-            # occupancy planes above can wrap for a large group, so
-            # scalar adoption and the stats mirror read these.
-            m_tot = sum(j["lanes_n"] for j in merge_jobs)
-            paged16 = tailbits[tail_base:tail_base + 6 * m_tot]
-            tail_base += 6 * m_tot
-            ctx["_paged_scalars"] = []
-            off = 0
-            for job in merge_jobs:
-                n = job["lanes_n"]
-                seg = paged16[off:off + 6 * n]
-                off += 6 * n
-                ctx["_paged_scalars"].append(
-                    (u32(seg[:n], seg[n:2 * n]),
-                     u32(seg[2 * n:3 * n], seg[3 * n:4 * n]),
-                     u32(seg[4 * n:5 * n], seg[5 * n:6 * n])))
+        # Megakernel scalar-adoption plane (R10): each page group's post
+        # count/min_seq/seq as exact int32 halves — the int16 occupancy
+        # planes above can wrap for a large group, so scalar adoption and
+        # the stats mirror read these.
+        m_tot = sum(j["lanes_n"] for j in merge_jobs)
+        paged16 = tailbits[tail_base:tail_base + 6 * m_tot]
+        tail_base += 6 * m_tot
+        ctx["_paged_scalars"] = []
+        off = 0
+        for job in merge_jobs:
+            n = job["lanes_n"]
+            seg = paged16[off:off + 6 * n]
+            off += 6 * n
+            ctx["_paged_scalars"].append(
+                (u32(seg[:n], seg[n:2 * n]),
+                 u32(seg[2 * n:3 * n], seg[3 * n:4 * n]),
+                 u32(seg[4 * n:5 * n], seg[5 * n:6 * n])))
         # The device telemetry plane (present only when this window
         # dispatched with stats): N_SERVE int32 slots as lo/hi halves.
         stats16 = tailbits[tail_base:] if ctx.get("stats") else None
 
-        q_m = np.fromiter(self._ring_fixup, np.int64,
-                          len(self._ring_fixup)) \
-            if self._ring_fixup else None
         q_l = np.fromiter(self._ring_fixup_lww, np.int64,
                           len(self._ring_fixup_lww)) \
             if self._ring_fixup_lww else None
 
-        # Exact occupancy refresh from this window's own result: the
+        # Exact LWW occupancy refresh from this window's own result: the
         # confirmed base adopts the post-window counts for the lanes THIS
-        # window staged (never the whole plane — lanes seeded/alloc'd by
-        # later flushes while this window was in flight have newer hints)
-        # and this window's staged-op bound leaves the pending set — the
-        # donation/deferral gate never decays pessimistic. Runs BEFORE
-        # recovery, whose put_rows re-hint any rolled-back lanes;
+        # window staged (never the whole plane — lanes alloc'd while this
+        # window was in flight have newer hints) and this window's
+        # staged-op bound leaves the pending set — the fit proof never
+        # decays pessimistic. Merge occupancy is the host page scalars,
+        # adopted at the ring's LAST window from the exact paged16 plane
+        # (_finish_paged_group). Runs BEFORE recovery, whose put_row
+        # re-hints any promoted lanes;
         # quarantined lanes keep their recovered base (this window's
         # device counts for them describe discarded state). When a
         # recovery ran since this window dispatched (gen moved), a staged
@@ -5125,32 +3734,7 @@ class TpuSequencerLambda(IPartitionLambda):
                  for l, c in zip(job["lanes"], job["chan"])), bool,
                 job["lanes"].size)
 
-        cnt_off = 0
-        for job in merge_jobs:
-            n = job["lanes_n"]
-            if ctx.get("paged"):
-                # R10: no bucket hints to refresh — paged occupancy is
-                # the host page scalars, adopted at the ring's LAST
-                # window from the exact paged16 plane below.
-                cnt_off += n
-                continue
-            bucket = self.merge.buckets[job["bucket"]]
-            fresh = cnt_planes[cnt_off:cnt_off + n].astype(np.int64)
-            cnt_off += n
-            pend_lanes = job["lanes"]
-            lanes_j = np.unique(pend_lanes)
-            if not gen_same:
-                own = _owned_rows(bucket, job)
-                job["owned"] = own
-                pend_lanes = pend_lanes[own]
-                lanes_j = np.unique(pend_lanes)
-            if q_m is not None:
-                qlanes = np.unique(job["lanes"][np.isin(job["chan"], q_m)])
-                lanes_j = np.setdiff1d(lanes_j, qlanes)
-            bucket.count_hint[lanes_j] = fresh[lanes_j]
-            np.subtract.at(bucket.hint_pending, pend_lanes, 2)
-            np.maximum(bucket.hint_pending, 0,
-                       out=bucket.hint_pending)
+        cnt_off = m_tot
         for job in lww_jobs:
             n = job["lanes_n"]
             bucket = self.lww.buckets[job["bucket"]]
@@ -5179,13 +3763,11 @@ class TpuSequencerLambda(IPartitionLambda):
             # exact int32 plane (rare second RPC).
             msn_bt = np.asarray(ctx["msn32_dev"]).astype(np.int64)
             msn_bt = np.where(admitted, msn_bt, 0)
-        if ctx.get("paged"):
-            # The megakernel ring's LAST window settles every page
-            # group; it rebuilds flagged docs' op streams from ALL K
-            # windows, so each window stashes its decoded seq/msn
-            # planes here.
-            ctx["_seq_bt"] = seq_bt
-            ctx["_msn_bt"] = msn_bt
+        # The megakernel ring's LAST window settles every page group; it
+        # rebuilds flagged docs' op streams from ALL K windows, so each
+        # window stashes its decoded seq/msn planes here.
+        ctx["_seq_bt"] = seq_bt
+        ctx["_msn_bt"] = msn_bt
         if bits[0]:
             raise RuntimeError("ticket client table overflow despite "
                                "pre-flush growth — invariant violation")
@@ -5223,72 +3805,44 @@ class TpuSequencerLambda(IPartitionLambda):
                       Nack(msg, int(next_seq[dl.lane]) - 1,
                            NackContent(NACK_BAD_REF_SEQ, reason)))
 
-        # Overflow recovery (rare): roll flagged lanes back to their
-        # pre-window rows and reuse the batched slow-path recovery. The
-        # span is unconditional — a flush with nothing to rescue records
-        # a ~0 µs stage, so captures always show the stage's cost.
-        # Ring-aware: lanes recovered while LATER windows are in flight
-        # quarantine their channels — those windows' rows for them
-        # re-apply host-side here (fixup), in dispatch order, instead of
-        # trusting device results computed from pre-recovery rows.
+        # Overflow recovery (rare). The span is unconditional — a flush
+        # with nothing to rescue records a ~0 µs stage, so captures always
+        # show the stage's cost.
         with tracing.span("serving.fold_rescue", parent=ctx.get("trace_ctx"),
                           hist="serving.fold_rescue") as _frsp:
-            bit_i = 1  # bits[0] is the ticket-table invariant bit
             recovered = 0
             plane_off = 0
-            # Quarantine direction: anything dispatched AFTER this
-            # window — later ring entries, staged windows, or the rest
-            # of this window's own burst (burst_more) — holds device
-            # results computed from pre-recovery rows.
-            ring_behind = bool(self._ring) or bool(self._staged) \
-                or bool(ctx.get("burst_more"))
-            fixup_merge: Dict[tuple, List[HostOp]] = {}
-            fixup_lww: Dict[tuple, List[tuple]] = {}
             for gi, job in enumerate(merge_jobs):
                 n = job["lanes_n"]
                 over = planes[plane_off:plane_off + n] != 0
                 plane_off += n
-                if ctx.get("paged"):
-                    # R10: overflow is sticky in the megakernel's scan
-                    # carry, so the LAST window's plane is the union of
-                    # every flagged doc and all settlement (scalar
-                    # adoption, trailing-page release, rollback+rescue)
-                    # happens there — with nothing in flight behind it
-                    # (one-in-flight ring), so no quarantine direction
-                    # exists on this path.
-                    bit_i += 1
-                    if not ctx.get("burst_more"):
-                        # Named settle stage: the megakernel ring's
-                        # scalar adoption + page release + rescue is
-                        # the path's fourth serving sub-span (pack /
-                        # dispatch / readback / settle), so ring
-                        # captures attribute settlement cost instead
-                        # of folding it into fold_rescue.
-                        with tracing.span("serving.settle",
-                                          hist="serving.settle") as _ssp:
-                            got = self._finish_paged_group(
-                                ctx, gi, job, over)
-                            if got:
-                                _ssp.set(rescued=True)
-                            recovered += got
-                    continue
-                qsel = np.isin(job["chan"], q_m) if q_m is not None \
-                    else None
-                if bits[bit_i]:
-                    qlanes = set(job["lanes"][qsel].tolist()) \
-                        if qsel is not None else set()
-                    flagged = sorted(
-                        {int(i) for i in job["lanes"].tolist()
-                         if over[i] and i not in qlanes})
-                    if flagged:
-                        self._recover_fast_merge(
-                            parsed, job, seq_bt, msn_bt, flagged,
-                            quarantine=ring_behind)
-                        recovered += 1
-                bit_i += 1
-                if qsel is not None and qsel.any():
-                    self._collect_merge_fixup(fixup_merge, parsed, job,
-                                              seq_bt, msn_bt, qsel)
+                # Overflow is sticky in the megakernel's scan carry, so
+                # the LAST window's plane is the union of every flagged
+                # doc and all settlement (scalar adoption, trailing-page
+                # release, rollback+rescue) happens there — with nothing
+                # in flight behind it (one-in-flight ring), so no merge
+                # quarantine exists.
+                if not ctx.get("burst_more"):
+                    # Named settle stage: the megakernel ring's scalar
+                    # adoption + page release + rescue is the path's
+                    # fourth serving sub-span (pack / dispatch / readback
+                    # / settle), so ring captures attribute settlement
+                    # cost instead of folding it into fold_rescue.
+                    with tracing.span("serving.settle",
+                                      hist="serving.settle") as _ssp:
+                        got = self._finish_paged_group(ctx, gi, job, over)
+                        if got:
+                            _ssp.set(rescued=True)
+                        recovered += got
+            # LWW: lanes recovered while LATER windows of the ring were
+            # already computed quarantine their channels — those windows'
+            # rows for them re-apply host-side here (fixup), in dispatch
+            # order, instead of trusting device results computed from
+            # pre-recovery rows.
+            ring_behind = bool(self._ring) or bool(self._staged) \
+                or bool(ctx.get("burst_more"))
+            fixup_lww: Dict[tuple, List[tuple]] = {}
+            bit_i = 1 + nm  # bits[0] is the ticket-table invariant bit
             for job in lww_jobs:
                 n = job["lanes_n"]
                 over = planes[plane_off:plane_off + n] != 0
@@ -5310,10 +3864,6 @@ class TpuSequencerLambda(IPartitionLambda):
                 if qsel is not None and qsel.any():
                     self._collect_lww_fixup(fixup_lww, parsed, job,
                                             seq_bt, qsel)
-            if fixup_merge:
-                increment("serving.ring_fixups", len(fixup_merge))
-                self._recovery_gen += 1  # the re-apply itself may promote
-                self.merge._apply_streams(fixup_merge)
             if fixup_lww:
                 increment("serving.ring_fixups", len(fixup_lww))
                 self._recovery_gen += 1
@@ -5370,8 +3920,7 @@ class TpuSequencerLambda(IPartitionLambda):
 
     def _collect_paged_ring_ops(self, shared, gi: int, keys):
         """HostOp streams for one page group across its megakernel
-        ring's K windows, in window order — _recover_fast_merge's
-        stream rebuild, widened to the whole ring entry."""
+        ring's K windows, in window order."""
         from . import pump as P
         ops_by: Dict[int, List[HostOp]] = {}
         for wd in shared["wins"]:
@@ -5411,14 +3960,12 @@ class TpuSequencerLambda(IPartitionLambda):
         exact counter diff — the obs-smoke gate. Vectorized numpy over
         the window's staged columns: microseconds against the device
         program it mirrors."""
-        noop_skip = bool(ctx.get("noop_skip"))
         kinds = np.zeros(6, np.int64)  # INSERT..INSERT_RUN
         skips = 0
         for job in merge_jobs:
             c = job.get("cols")
             if c is None:
-                if noop_skip:
-                    skips += 1  # union-bucket padding: all-NOOP plane
+                skips += 1  # union-group padding: all-NOOP plane
                 continue
             kind = c[0]
             ok = (kind != OpKind.NOOP) & (seq_bt[c[10], c[11]] > 0)
@@ -5431,31 +3978,25 @@ class TpuSequencerLambda(IPartitionLambda):
                 ok &= ~mispredict
             kk = kind[ok]
             kinds += np.bincount(kk, minlength=7)[1:7]
-            if noop_skip and kk.size == 0:
+            if kk.size == 0:
                 skips += 1
         lww_n = 0
         for job in lww_jobs:
             c = job.get("cols")
             if c is None:
-                if noop_skip:
-                    skips += 1
+                skips += 1
                 continue
             # LwwKind.NOOP == 0 (server/lww_kernel.py)
             ok = (c[0] != 0) & (seq_bt[c[4], c[5]] > 0)
             n_ok = int(ok.sum())
             lww_n += n_ok
-            if noop_skip and n_ok == 0:
+            if n_ok == 0:
                 skips += 1
         merge_total = sum(j["lanes_n"] for j in merge_jobs)
-        if ctx.get("_paged_scalars") is not None:
-            # R10: the device sums the EXACT int32 group counts; the
-            # int16 count planes can wrap for a large page group, so
-            # the mirror reads the decoded paged16 scalars instead.
-            merge_cnt = sum(int(c.sum())
-                            for c, _m, _s in ctx["_paged_scalars"])
-        else:
-            merge_cnt = int(cnt_planes[:merge_total].astype(np.int64)
-                            .sum())
+        # The device sums the EXACT int32 group counts; the int16 count
+        # planes can wrap for a large page group, so the mirror reads the
+        # decoded paged16 scalars instead.
+        merge_cnt = sum(int(c.sum()) for c, _m, _s in ctx["_paged_scalars"])
         host_vec = np.array(list(kinds) + [
             lww_n,
             int(admitted.sum()),
@@ -5464,25 +4005,22 @@ class TpuSequencerLambda(IPartitionLambda):
             int((planes[:merge_total] != 0).sum()),
             int((planes[merge_total:] != 0).sum()),
             skips,
-            # Lane-fill gauges: the device sums the same count planes
-            # that ride this result, so the mirror is the plane sum.
+            # Lane-fill gauges: the device sums the same counts that
+            # ride this result, so the mirror is their sum.
             merge_cnt,
             int(cnt_planes[merge_total:].astype(np.int64).sum()),
         ], np.int64)
         return host_vec
 
     def _build_merge(self, parsed, rows, lanes, slot,
-                     mbase, chan_ok, chan_b, chan_l, flush_rows=None):
-        """Per-bucket merge window staging ([12, lanes, Tm]: 10 PackedOps
-        columns + doc_idx + t_idx, one array => one H2D); returns job
-        records carrying what the (rare) recovery path needs.
-        `flush_rows` overrides the live flush's merge-row universe (the
-        staged-window degrade restage, which may run after a LATER flush
-        overwrote self._flush_merge_rows)."""
+                     mbase, chan_ok, chan_b, chan_l):
+        """Per-flush-group merge window staging ([12, lanes, Tm]: 10
+        PackedOps columns + doc_idx + t_idx, one array => one H2D);
+        returns job records carrying what the (rare) recovery path
+        needs."""
         from . import pump as P
         cols = parsed.cols
-        if flush_rows is None:
-            flush_rows = self._flush_merge_rows
+        flush_rows = self._flush_merge_rows
         in_window = np.isin(flush_rows, rows)
         sel = in_window & chan_ok
         jobs = []
@@ -5495,20 +4033,13 @@ class TpuSequencerLambda(IPartitionLambda):
         op_ids = mbase + np.flatnonzero(sel)
         # Window-local position of each selected merge row (rows sorted).
         wrow = np.searchsorted(rows, mrows)
-        paged = self.merge.paged
         for b in np.unique(mb).tolist():
             bsel = mb == b
-            if paged:
-                # R10: b is a flush-group id; the plane width is the
-                # group's pow2-padded member count and there is no pre
-                # state to snapshot — the megakernel returns the
-                # gathered pre views in its own readback.
-                group_lanes = self.merge.flush_groups[b].lanes
-                pre_state = None
-            else:
-                bucket = self.merge.buckets[b]
-                group_lanes = bucket.lanes
-                pre_state = bucket.state
+            # b is a flush-group id; the plane width is the group's
+            # pow2-padded member count and there is no pre state to
+            # snapshot — the megakernel returns the gathered pre views in
+            # its own readback.
+            group_lanes = self.merge.flush_groups[b].lanes
             rl = ml[bsel]
             rr = mrows[bsel]
             doc_lane = lanes[wrow[bsel]]
@@ -5534,8 +4065,8 @@ class TpuSequencerLambda(IPartitionLambda):
             Tm = _bucket(int(rp.max()) + 1 if rr.size else 1,
                          self.t_buckets)
             mc = np.zeros((12, group_lanes, Tm), np.int32)
-            # Layout matches serve_step.serve_window: kind seq ref client
-            # pos1 pos2 op_id new_len local_seq msn doc_idx t_idx.
+            # Layout matches serve_step._serve_window_impl: kind seq ref
+            # client pos1 pos2 op_id new_len local_seq msn doc_idx t_idx.
             # Run slots: the stream-FIRST member provides pos1/ref/client
             # (writes below land head-last per slot via masked ordering),
             # the stream-LAST member provides doc_idx/t_idx (seq/msn
@@ -5570,7 +4101,7 @@ class TpuSequencerLambda(IPartitionLambda):
                 rc[2, rl[msel], rp[msel], sub[msel]] = doc_lane[msel]
                 rc[3, rl[msel], rp[msel], sub[msel]] = tslot[msel]
                 runs_rc = rc
-            jobs.append({"bucket": b, "pre": pre_state, "cols": mc,
+            jobs.append({"bucket": b, "cols": mc,
                          "runs": runs_rc, "lanes_n": group_lanes,
                          "chan": cols[P.CHAN, rr],
                          "rows": rr, "lanes": rl, "op_ids": op_ids[bsel],
@@ -5617,125 +4148,23 @@ class TpuSequencerLambda(IPartitionLambda):
             tslot = slot[wrow[bsel]]
             lc[4, rl, rp] = doc_lane
             lc[5, rl, rp] = tslot
-            jobs.append({"bucket": b, "pre": bucket.state, "cols": lc,
+            jobs.append({"bucket": b, "pre": None, "cols": lc,
                          "lanes_n": bucket.lanes, "chan": cols[P.CHAN, rr],
                          "rows": rr, "lanes": rl, "val_ids": val_ids[bsel],
                          "doc_lane": doc_lane, "slot": tslot})
         return jobs
 
-    def _recover_fast_merge(self, parsed, job, seq_bt, msn_bt,
-                            flagged: List[int],
-                            quarantine: bool = False) -> None:
-        """A merge bucket overflowed in a fast window: rebuild HostOp
-        streams for the flagged lanes from the pump columns, roll those
-        lanes back to their pre-window rows, and run the slow path's
-        batched recovery. `quarantine=True` (windows behind this one are
-        still in flight) additionally quarantines the recovered channels:
-        the later windows' device results for these lanes are void, and
-        their rows re-apply host-side at each window's own drain."""
-        from . import pump as P
-        cols = parsed.cols
-        b = job["bucket"]
-        bucket = self.merge.buckets[b]
-        flag_set = set(flagged)
-        own = job.get("owned")  # set by _finish_window when gen moved
-        tm = jax.tree_util.tree_map
-        lane_ops: Dict[int, List[HostOp]] = {}
-        for k, i in enumerate(job["lanes"].tolist()):
-            if i not in flag_set:
-                continue
-            if own is not None and not own[k]:
-                # A recovery freed + reallocated this lane while the
-                # window was in flight: the plane bit describes the OLD
-                # channel's discarded state — never roll back or re-run
-                # over the lane's new owner.
-                continue
-            r = int(job["rows"][k])
-            # seq/msn were assigned by the ticket pass regardless of the
-            # merge overflow; reuse them for the re-run.
-            seq = int(seq_bt[job["doc_lane"][k], job["slot"][k]])
-            msn = int(msn_bt[job["doc_lane"][k], job["slot"][k]])
-            if seq <= 0:
-                continue
-            lane_ops.setdefault(i, []).append(HostOp(
-                kind=int(cols[P.MKIND, r]), seq=seq,
-                ref_seq=int(cols[P.REFSEQ, r]),
-                client=int(cols[P.CLIENT, r]),
-                pos1=int(cols[P.POS1, r]), pos2=int(cols[P.POS2, r]),
-                op_id=int(job["op_ids"][k]),
-                new_len=int(cols[P.CHARLEN, r]),
-                local_seq=0, msn=msn))
-        if not lane_ops:
-            return
-        self._recovery_gen += 1
-        increment("serving.recovery_dispatches")
-        if job["pre"] is None:
-            # Donated window flagged overflow: the gate's fit proof was
-            # wrong (hint bug) or the overflow was structurally
-            # unpredictable (bad insert position, a nacked INSERT_RUN
-            # member) and the pre rows are gone. Degrade the affected
-            # channels to opaque instead of materializing corrupt state
-            # — loudly.
-            self._degrade_donated_merge(b, sorted(lane_ops))
-            return
-        if quarantine:
-            for i in sorted(lane_ops):
-                key = bucket.used[i]
-                ch = self._chan_ord.get(key)
-                if ch is not None:
-                    self._ring_fixup.add(int(ch))
-        idx = jnp.asarray(np.asarray(sorted(lane_ops), np.int32))
-        bucket.state = tm(lambda col, p: col.at[idx].set(p[idx]),
-                          bucket.state, job["pre"])
-        self.merge._recover_batch(b, lane_ops)
-
-    def _degrade_donated_merge(self, b: int, lanes: List[int]) -> None:
-        import logging
-        increment("sequencer.donated_overflow")
-        bucket = self.merge.buckets[b]
-        keys = [bucket.used[i] for i in lanes if bucket.used[i] is not None]
-        logging.getLogger(__name__).error(
-            "merge overflow on a DONATED window (occupancy-hint invariant "
-            "break); degrading %d channel(s) to opaque: %r", len(keys),
-            keys)
-        for key in keys:
-            # Quarantine BEFORE dropping: in-flight windows that staged
-            # this channel must void their device results for it at
-            # their drain (the opaque check then skips the re-apply),
-            # not recover against a freed/reallocated lane.
-            ch = self._chan_ord.get(key)
-            if ch is not None:
-                self._ring_fixup.add(int(ch))
-            self.merge.drop(key)
-            self.merge.overflow_drops += 1
-
-    def _collect_merge_fixup(self, streams: Dict[tuple, List[HostOp]],
-                             parsed, job, seq_bt, msn_bt,
-                             qsel: np.ndarray) -> None:
-        """Rows riding a quarantined channel: their lanes were rolled back
-        and host-recovered by an EARLIER window's drain, so this window's
-        device result for them is void — rebuild the ops as HostOp
-        streams (arrival order) for the sync-faithful re-apply."""
-        from . import pump as P
-        cols = parsed.cols
-        for k in np.flatnonzero(qsel).tolist():
-            r = int(job["rows"][k])
-            seq = int(seq_bt[job["doc_lane"][k], job["slot"][k]])
-            msn = int(msn_bt[job["doc_lane"][k], job["slot"][k]])
-            if seq <= 0:
-                continue
-            key = self._pump_chan[int(job["chan"][k])]
-            streams.setdefault(key, []).append(HostOp(
-                kind=int(cols[P.MKIND, r]), seq=seq,
-                ref_seq=int(cols[P.REFSEQ, r]),
-                client=int(cols[P.CLIENT, r]),
-                pos1=int(cols[P.POS1, r]), pos2=int(cols[P.POS2, r]),
-                op_id=int(job["op_ids"][k]),
-                new_len=int(cols[P.CHARLEN, r]),
-                local_seq=0, msn=msn))
-
     def _recover_fast_lww(self, parsed, job, seq_bt, flagged: List[int],
                           quarantine: bool = False) -> None:
+        """An LWW bucket overflowed in a fast window: rebuild the flagged
+        lanes' op tuples from the pump columns, roll those lanes back to
+        their pre-window rows and promote them. `quarantine=True`
+        (windows behind this one in the ring were already computed)
+        additionally quarantines the recovered channels: the later
+        windows' device results for these lanes are void, and their rows
+        re-apply host-side at each window's own finish. Without pre rows
+        (an overflow the fit proof did not predict, so the window shared
+        a donated ring) the channels degrade to opaque, loudly."""
         from . import pump as P
         cols = parsed.cols
         lk = self.lww.lk
@@ -5750,7 +4179,7 @@ class TpuSequencerLambda(IPartitionLambda):
                 continue
             if own is not None and not own[k]:
                 # Lane freed + reallocated while in flight (see
-                # _recover_fast_merge): never touch the new owner.
+                # _finish_window): never touch the new owner.
                 continue
             r = int(job["rows"][k])
             seq = int(seq_bt[job["doc_lane"][k], job["slot"][k]])
@@ -5777,7 +4206,11 @@ class TpuSequencerLambda(IPartitionLambda):
                 "invariant break); degrading %d channel(s): %r",
                 len(keys), keys)
             for key in keys:
-                # Quarantine before dropping (see _degrade_donated_merge).
+                # Quarantine BEFORE dropping: later windows that staged
+                # this channel must void their device results for it at
+                # their finish (the opaque check then skips the
+                # re-apply), not recover against a freed/reallocated
+                # lane.
                 ch = self._chan_ord.get(key)
                 if ch is not None:
                     self._ring_fixup_lww.add(int(ch))
@@ -6175,7 +4608,7 @@ class TpuSequencerLambda(IPartitionLambda):
                             only: Optional[set] = None
                             ) -> Dict[tuple, dict]:
         """Chunked snapshots of every materialized channel — merge-tree
-        lanes (one batched device extraction per capacity bucket) AND LWW
+        lanes (one batched device extraction per page group) AND LWW
         lanes (map/cell/counter entries + counter accumulator). `only`
         restricts to the given channel keys (incremental path)."""
         self.drain()  # settle any deferred window before reading lanes
@@ -6392,7 +4825,7 @@ class TpuSequencerLambda(IPartitionLambda):
                          chunk_chars: int = 10000) -> Dict[str, dict]:
         """One read-tier refresh epoch: extract every supported document
         whose change generations advanced past its published artifact —
-        ONE batched device dispatch per capacity bucket / page group for
+        ONE batched device dispatch per page group for
         ALL of them together (extract_dispatch; clean lanes ride the
         summarize blob cache; matrix axes are merge lanes and ride the
         same dispatch) — and return the per-doc artifact bodies

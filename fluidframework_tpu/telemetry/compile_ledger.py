@@ -18,11 +18,11 @@ surface reads from the same place:
 Two feeding paths, both lock-cheap:
 
   * ``JitRetraceProbe`` calls :func:`note_call` transparently for every
-    wrapped kernel (kernel.merge_apply_batched, kernel.paged_apply,
+    wrapped kernel (kernel.paged_apply, kernel.paged_apply_keep,
     kernel.extract_gather, ...).
   * Call sites that must NOT wrap their jitted callable (the serving
     dispatches — fluidlint's donated-buffer dataflow resolves
-    ``serve_step.serve_window`` to its partial-jit wrapper by name, and
+    ``serve_step.serve_megakernel`` to its partial-jit wrapper by name, and
     a wrapper object would blind it) register the callable once with
     :func:`watch` and stamp each call with :func:`note_call`; the
     ledger reads the jit cache size itself.
@@ -145,8 +145,9 @@ class CompileLedger:
     def track(self, name: str, fn: Callable) -> "_Tracked":
         """Context manager for un-wrappable call sites::
 
-            with ledger.track("serve.window", serve_step.serve_window):
-                out = serve_step.serve_window(...)
+            with ledger.track("serve.megakernel",
+                              serve_step.serve_megakernel):
+                out = serve_step.serve_megakernel(...)
         """
         self.watch(name, fn)
         return _Tracked(self, name)

@@ -1,8 +1,8 @@
 """Device-resident telemetry planes: the host-side layout + fold.
 
-PRs 7/8/11 moved the serving hot path inside single device programs
-(fused scan bursts, paged applies on gathered page views, batched
-extract epochs), so the facts that matter — per-window op mix,
+The serving hot path runs inside single device programs (the serving
+megakernel's scanned windows, paged applies on gathered page views,
+batched extract epochs), so the facts that matter — per-window op mix,
 noop-skipped applies, overflow flags, rows reclaimed by zamboni, lane
 fill — are invisible to host spans unless a readback pays for them.
 The device programs therefore emit a compact int32 stats plane IN THEIR
@@ -18,7 +18,7 @@ Contracts (gated by ``make obs-smoke`` + tests/test_device_stats.py):
     emit stream and lane planes (the plane is an appended output, never
     an input to the op phases);
   * zero extra dispatches and zero extra host round-trips per
-    window/burst (the plane rides the flat16 the host already fetches);
+    window/ring (the plane rides the flat16 the host already fetches);
   * device-counted op totals reconcile EXACTLY with host-side counts —
     every fold takes the device vector AND a host-derived mirror, and
     both land as counters (``device.serving.*`` vs ``host.serving.*``)
@@ -57,8 +57,8 @@ SERVE_SLOTS = (
     "ticket_not_joined",
     "merge_overflow_lanes",
     "lww_overflow_lanes",
-    "noop_skipped_applies",  # burst padding skips (kernel.apply_if_any)
-    "merge_rows_live",     # post-window bucket fill (sum of lane counts)
+    "noop_skipped_applies",  # ring padding skips (all-NOOP op planes)
+    "merge_rows_live",     # post-window page-group fill (sum of counts)
     "lww_keys_live",
 )
 N_SERVE = len(SERVE_SLOTS)
@@ -76,7 +76,7 @@ PAGED_SLOTS = (
 )
 N_PAGED = len(PAGED_SLOTS)
 
-# One fused zamboni+extract dispatch (bucketed or paged).
+# One fused zamboni+extract dispatch over a page group.
 EXTRACT_SLOTS = (
     "docs",
     "rows_live",           # post-compaction live rows

@@ -8,7 +8,7 @@ persistent XLA compilation cache returns corrupt lane planes (repro:
 tests/test_mesh_serving.py warm vs cold after clearing the compile
 cache; docs/serving_pipeline.md R6). The real
 ``tpu_sequencer`` selects the non-donating ``_keep`` dispatch whenever
-a mesh is present (``donate_lane_states = mesh is None``); this fixture
+a mesh is present (``PagedMergeStore.donate = mesh is None``); this fixture
 is what the code would look like if someone "optimized" that back into
 an unconditional donating dispatch.
 

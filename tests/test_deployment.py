@@ -175,6 +175,108 @@ class TestMultiProcessPipeline:
                     p.kill()
 
 
+class TestTpuDeliWorker:
+    def test_tpu_deli_worker_serves_through_the_paged_megakernel(
+            self, tmp_path):
+        """The deployed `tpu-deli` stage, configured with nothing but its
+        name, serves wire-bytes boxcars through the native pump's fast
+        flush and the page-pool serving megakernel: the worker's own
+        monitor counts megakernel rings and their windows, and the
+        sequenced ops persist."""
+        from fluidframework_tpu.server import pump as pump_mod
+        from fluidframework_tpu.server.wire import boxcar_to_wire
+        if not pump_mod.available():
+            pytest.skip("native wirepump unavailable")
+        port = _free_port()
+        mon_port = _free_port()
+        cfg = {
+            "broker": {"host": "127.0.0.1", "port": port, "partitions": 1},
+            "storage": {"db": str(tmp_path / "fluid.sqlite"),
+                        "git": str(tmp_path / "git")},
+            "worker": {"stages": ["tpu-deli", "scriptorium"],
+                       "poll_ms": 5, "tenant": "local",
+                       "monitorPort": mon_port},
+        }
+        cfg_path = tmp_path / "config.json"
+        cfg_path.write_text(json.dumps(cfg))
+        broker = _spawn(["broker", "--config", str(cfg_path)], tmp_path)
+        procs = [broker]
+        try:
+            deadline = time.time() + 60
+            while time.time() < deadline:
+                try:
+                    socket.create_connection(("127.0.0.1", port),
+                                             timeout=0.3).close()
+                    break
+                except OSError:
+                    if broker.poll() is not None:
+                        raise AssertionError(_output(broker))
+                    time.sleep(0.1)
+            else:
+                raise AssertionError(
+                    "broker never listened\n" + _output(broker))
+            worker = _spawn(["worker", "--config", str(cfg_path)], tmp_path)
+            procs.append(worker)
+
+            log = RemoteMessageLog(f"127.0.0.1:{port}")
+            msgs = [DocumentMessage(
+                client_sequence_number=0, reference_sequence_number=-1,
+                type=MessageType.CLIENT_JOIN,
+                data=json.dumps({"clientId": "c1", "detail": {}}))]
+            for i in range(1, 6):
+                msgs.append(DocumentMessage(
+                    client_sequence_number=i, reference_sequence_number=0,
+                    type=MessageType.OPERATION,
+                    contents={"address": "s", "contents": {
+                        "address": "t", "contents": {
+                            "type": 0, "pos1": 0,
+                            "seg": {"text": f"{i}"}}}}))
+            log.send(RAW_TOPIC, "doc", boxcar_to_wire(Boxcar(
+                tenant_id="local", document_id="doc", client_id="c1",
+                contents=msgs)))
+
+            db = SqliteDatabaseManager(str(tmp_path / "fluid.sqlite"))
+            deltas = db.collection("deltas", unique_key=delta_key,
+                                   range_key=delta_key)
+            deadline = time.time() + 120
+            rows = []
+            while time.time() < deadline:
+                rows = query_deltas(deltas, "doc")
+                if len(rows) >= 6:  # join + 5 ops
+                    break
+                if worker.poll() is not None:
+                    raise AssertionError(_output(worker))
+                time.sleep(0.2)
+            assert len(rows) >= 6, \
+                f"only {len(rows)} deltas persisted\n{_output(worker)}"
+            assert [r["sequence_number"] for r in rows] == \
+                list(range(1, len(rows) + 1))
+
+            counters = {}
+            deadline = time.time() + 30
+            while time.time() < deadline:
+                try:
+                    counters = json.loads(urllib.request.urlopen(
+                        f"http://127.0.0.1:{mon_port}/health",
+                        timeout=2).read()).get("counters", {})
+                except OSError:
+                    counters = {}
+                if counters.get("serving.megakernel_rings", 0) >= 1:
+                    break
+                time.sleep(0.2)
+            assert counters.get("serving.megakernel_rings", 0) >= 1, \
+                f"{counters}\n{_output(worker)}"
+            assert counters.get("serving.megakernel_windows", 0) >= 1
+        finally:
+            for p in procs:
+                p.terminate()
+            for p in procs:
+                try:
+                    p.wait(timeout=10)
+                except subprocess.TimeoutExpired:
+                    p.kill()
+
+
 class TestHistorianProcess:
     """The standalone cache tier as a real OS process over the shared git
     directory (store mode), plus the degradation contract when it dies."""

@@ -83,10 +83,11 @@ def _stream(builder, schedule):
 # ---------------------------------------------------------------------------
 
 def _storm_wave(wave: int, docs: int, ops_per_doc: int,
-                storm_ops: int):
+                storm_ops: int, ring_wave: int = -1):
     """Raw-wire wave: doc 0 is the storm doc (deep per-wave stream →
-    multi-window flushes, capacity promotion mid-flight → the overflow
-    quarantine path), the rest type keystrokes."""
+    multi-window flushes; at `ring_wave` it also annotates one span past
+    the row's annotate ring → the rollback + host rescue path), the rest
+    type keystrokes."""
     rng = random.Random(83 + wave)
     out = []
     for d in range(docs):
@@ -108,6 +109,16 @@ def _storm_wave(wave: int, docs: int, ops_per_doc: int,
                     "address": "t", "contents": {
                         "type": 0, "pos1": 0,
                         "seg": {"text": "z" * rng.randrange(1, 3)}}}}))
+        if d == 0 and wave == ring_wave:
+            for i in range(6):  # DEFAULT_ANNO_SLOTS=4
+                contents.append(DocumentMessage(
+                    client_sequence_number=base + n_ops + i + 1,
+                    reference_sequence_number=base,
+                    type=MessageType.OPERATION,
+                    contents={"address": "s", "contents": {
+                        "address": "t", "contents": {
+                            "type": 2, "pos1": 0, "pos2": 2,
+                            "props": {f"k{i}": i}}}}))
         out.append(QueuedMessage(
             topic="rawdeltas", partition=0, offset=wave * docs + d,
             key=doc,
@@ -141,9 +152,9 @@ def _run_pipeline(waves, stats_on: bool):
     lam.drain()
     import hashlib
     h = hashlib.sha256()
-    for bucket in lam.merge.buckets:
-        for leaf in jax.tree_util.tree_leaves(bucket.state):
-            h.update(np.asarray(leaf).tobytes())
+    for leaf in jax.tree_util.tree_leaves(lam.merge.pages.pool):
+        h.update(np.asarray(leaf).tobytes())
+    h.update(repr(sorted(lam.merge.pages.tables.items())).encode())
     for leaf in jax.tree_util.tree_leaves(lam.tstate):
         h.update(np.asarray(leaf).tobytes())
     snap = counters.snapshot()
@@ -152,13 +163,14 @@ def _run_pipeline(waves, stats_on: bool):
 
 class TestServingPlane:
     def test_bit_identity_and_exact_reconcile_contended(self):
-        """Telemetry on vs off over a contended ragged fleet — storm
-        doc deep enough that the 64-row bucket overflows while later
-        windows are in flight (the mid-flight quarantine class): the
-        emit stream and the lane planes must be identical, the run must
+        """Telemetry on vs off over a contended ragged fleet — a storm
+        doc spanning several windows per flush, one of whose rings
+        overflows an annotate ring (rollback + host rescue): the emit
+        stream and the lane planes must be identical, the run must
         actually have exercised recovery, and every countable device
         slot must equal its host mirror exactly."""
-        waves = [_storm_wave(w, docs=12, ops_per_doc=8, storm_ops=48)
+        waves = [_storm_wave(w, docs=12, ops_per_doc=8, storm_ops=48,
+                             ring_wave=2)
                  for w in range(4)]
         emits_off, digest_off, snap_off = _run_pipeline(waves, False)
         emits_on, digest_on, snap_on = _run_pipeline(waves, True)
@@ -201,7 +213,7 @@ class TestPagedPlane:
         def run(stats_on):
             counters.reset()
             device_stats.set_enabled(stats_on)
-            store = MergeLaneStore(paged=True, page_rows=16)
+            store = MergeLaneStore(page_rows=16)
             store.apply({k: _stream(store.builder, s)
                          for k, s in schedules.items()})
             texts = {k: store.text(k) for k in schedules}
@@ -482,7 +494,7 @@ class TestSpanCoverage:
     def test_paged_rescue_fills_histogram(self):
         """Annotate-ring exhaustion takes the host rescue — the rescue
         must be visible as the serving.paged_rescue stage."""
-        store = MergeLaneStore(paged=True)
+        store = MergeLaneStore()
         b = store.builder
         key = ("doc", "s", "anno")
         ops = [b.insert_text(0, "abcdef", 0, GOD_CLIENT, 1)]

@@ -189,9 +189,9 @@ class TestCallGraphResolution:
 
     def test_real_tree_resolves_serve_burst_donation(self):
         """The live contract: from tpu_sequencer, `serve_step.
-        serve_burst(…)` must resolve to the partial-jit wrapper over
-        _serve_burst with donate_argnums=(0, 1, 2) — this is the
-        signature every lifecycle finding in the serving path hangs
+        serve_megakernel(…)` must resolve to the partial-jit wrapper
+        over _serve_megakernel with donate_argnums=(0, 1, 2) — this is
+        the signature every lifecycle finding in the serving path hangs
         off, so its resolution is pinned against the real tree."""
         triples = []
         for rel in ("server/serve_step.py", "server/tpu_sequencer.py"):
@@ -199,14 +199,17 @@ class TestCallGraphResolution:
             name = module_name_for_path("fluidframework_tpu/" + rel)
             triples.append((name, ast.parse(p.read_text()), str(p)))
         idx = ProgramIndex(triples)
+        call = "(a, b, c, d, e, f, g, h, i, j, k, l, m)"
         res = resolve(idx, "fluidframework_tpu.server.tpu_sequencer",
-                      "serve_step.serve_burst(a, b, c, d, e, f, g, h)")
+                      "serve_step.serve_megakernel" + call)
         assert res is not None
+        assert res.decl.name == "_serve_megakernel"
         assert res.donation.positions == {0, 1, 2}
         keep = resolve(idx, "fluidframework_tpu.server.tpu_sequencer",
-                       "serve_step.serve_window_keep(a, b, c, d, e, f)")
-        assert keep is not None and keep.decl.name == "serve_window"
-        assert keep.donation.positions == {0}  # ticket state only
+                       "serve_step.serve_megakernel_keep" + call)
+        assert keep is not None and keep.decl.name == "_serve_megakernel"
+        # Mesh pools donate nothing.
+        assert keep.donation is None or not keep.donation.positions
 
 
 # ---------------------------------------------------------------------------

@@ -61,13 +61,13 @@ def _server_digest(server) -> str:
 
 
 class Sheet:
-    """One SharedMatrix document on TpuLocalServer(paged_lanes=True): a
+    """One SharedMatrix document on a TpuLocalServer: a
     creator inserts the rows and columns before attach, two raw writers
     send storm ops, and the reference applies every op in seq order."""
 
     def __init__(self, rows=12, cols=10, seed=5, capacities=None,
                  text=False):
-        self.server = TpuLocalServer(paged_lanes=True)
+        self.server = TpuLocalServer()
         if capacities is not None:
             self.server.sequencer().lww = LwwLaneStore(capacities=capacities)
         self.loader = Loader(LocalDocumentServiceFactory(self.server))

@@ -60,9 +60,9 @@ class TestMeshServing:
         # The ticket state REALLY spans the mesh.
         lam = server.sequencer()
         assert len(lam.tstate.next_seq.sharding.device_set) == 8
-        b, lane = lam.merge.where[("m0", "default", "text")]
-        state = lam.merge.buckets[b].state
-        assert len(state.length.sharding.device_set) == 8
+        assert ("m0", "default", "text") in lam.merge.where
+        pool = lam.merge.pages.pool
+        assert len(pool.length.sharding.device_set) == 8
 
     def test_mesh_fast_path_matches_unsharded(self):
         """Identical wire-bytes traffic through a mesh lambda and an
@@ -157,7 +157,7 @@ class TestMeshServing:
         sits exactly where the rule table predicts."""
         from fluidframework_tpu.testing import shardcheck
         mesh = make_mesh(sp=1)
-        server = TpuLocalServer(mesh=mesh, paged_lanes=True)
+        server = TpuLocalServer(mesh=mesh)
         loader, c, ds = make_doc(server, "pgm")
         t = ds.create_channel("text", SharedString.TYPE)
         c.attach()
@@ -196,9 +196,9 @@ class TestMeshServing:
         assert joined == "one two", joined
 
     def test_mesh_larger_than_default_lanes(self):
-        """dp > the default 8 bucket lanes must grow-then-shard, not
-        crash (16-chip pod shape). Runs in a subprocess with 16 virtual
-        devices."""
+        """dp > the default 8 LWW bucket lanes must grow-then-shard, and
+        the page pool must round to a dp multiple, not crash (16-chip pod
+        shape). Runs in a subprocess with 16 virtual devices."""
         import subprocess
         import sys
         code = (
@@ -214,8 +214,9 @@ class TestMeshServing:
             "lam = TpuSequencerLambda(C(), emit=lambda *a: None, "
             "nack=lambda *a: None, mesh=make_mesh(sp=1))\n"
             "assert lam.lanes % 16 == 0\n"
-            "for b in lam.merge.buckets + lam.lww.buckets:\n"
+            "for b in lam.lww.buckets:\n"
             "    assert b.lanes % 16 == 0, b.lanes\n"
+            "assert lam.merge.pages.allocator.capacity % 16 == 0\n"
             "print('dp16 ok')\n")
         out = subprocess.run([sys.executable, "-c", code],
                              capture_output=True, text=True, timeout=300,
@@ -241,9 +242,10 @@ class TestMeshServing:
 
     def test_payload_collection_preserves_sharding(self):
         """Major collection (compact_payload_ids) rebuilds the origin_op/
-        anno planes from host-built arrays; on a dp mesh it must re-apply
-        the bucket's placer so the renumbered state keeps its sharding —
-        and the renumbered ids must still resolve the exact text."""
+        anno planes from host-built arrays; on a dp mesh the pool must be
+        re-placed by its partition rules so the renumbered state keeps
+        its sharding — and the renumbered ids must still resolve the
+        exact text."""
         mesh = make_mesh(sp=1)
         server = TpuLocalServer(mesh=mesh)
         loader, c, ds = make_doc(server, "mc")
@@ -252,16 +254,17 @@ class TestMeshServing:
         for i in range(60):
             t.insert_text(t.get_length(), f"w{i} ")
         store = server.sequencer().merge
-        b, lane = store.where[("mc", "default", "text")]
-        bucket = store.buckets[b]
-        assert len(bucket.state.origin_op.sharding.device_set) == 8
+        assert ("mc", "default", "text") in store.where
+        pool = store.pages.pool
+        assert len(pool.origin_op.sharding.device_set) == 8
         store.payload_compact_min_entries = 0
         assert store.compact_payload_ids() is True
         # The collection REALLY renumbered on sharded state and the
         # planes still span the mesh.
         assert store.payload_compactions >= 1
-        assert len(bucket.state.origin_op.sharding.device_set) == 8
-        assert len(bucket.state.anno.sharding.device_set) == 8
+        pool = store.pages.pool
+        assert len(pool.origin_op.sharding.device_set) == 8
+        assert len(pool.anno.sharding.device_set) == 8
         # Renumbered ids resolve: materialization and further edits work.
         assert server.sequencer().channel_text(
             "mc", "default", "text") == t.get_text()
@@ -292,11 +295,12 @@ class TestMeshServing:
             "mv", "default", "meta")["entries"]["k2"] == "post"
 
     def test_host_fold_on_sharded_lanes(self):
-        """The serving zamboni pack must work when lane states are
-        sharded over the dp mesh: the fold's device_get slices, host
-        reseed, and batched put_rows all cross the sharding boundary.
-        Sustained typing overflows the fold bucket and must pack there
-        instead of promoting, with exact text after."""
+        """The host rescue must work when the page pool is sharded over
+        the dp mesh: the rollback scatter, the host fold of the gathered
+        row, and the reseeding put_row all cross the sharding boundary.
+        Sustained typing grows the lane page by page; one window of
+        annotates past the ring's depth then forces the rescue, with
+        exact text after."""
         import random
 
         mesh = make_mesh(sp=1)
@@ -309,14 +313,20 @@ class TestMeshServing:
         for i in range(400):
             pos = rng.randrange(t.get_length() + 1)
             t.insert_text(pos, f"s{i % 10}")
-        assert store.folds > 0, "fold never fired on the mesh"
-        b, lane = store.where[("mf", "default", "text")]
-        fold_b = store.capacities.index(store.fold_min_capacity)
-        assert b <= fold_b
-        # The folded lane's bucket state REALLY spans the mesh (else
-        # this test passes without crossing any sharding boundary).
-        assert len(store.buckets[b].state.length
-                   .sharding.device_set) == 8
+        key = ("mf", "default", "text")
+        pages_grown = len(store.pages.tables[key])
+        assert pages_grown > 1
+        server.auto_pump = False
+        for j in range(6):
+            t.annotate_range(0, 5, {f"r{j}": j})
+        server.auto_pump = True
+        server.pump()
+        assert store.paged_rescues > 0, "rescue never fired on the mesh"
+        # The fold coalesced the acked typing into fewer pages.
+        assert len(store.pages.tables[key]) < pages_grown
+        # The rescued lane's pool REALLY spans the mesh (else this test
+        # passes without crossing any sharding boundary).
+        assert len(store.pages.pool.length.sharding.device_set) == 8
         assert server.sequencer().channel_text(
             "mf", "default", "text") == t.get_text()
         # Editing (incl. removes: position resolution against packed

@@ -185,10 +185,9 @@ class TestGatherRowsPow2:
 
 
 class TestDirtyEpochNeverStale:
-    def _store(self, capacities=(64,), lanes=8):
+    def _store(self, page_rows=None):
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        return MergeLaneStore(capacities=capacities,
-                              lanes_per_bucket=lanes)
+        return MergeLaneStore(page_rows=page_rows)
 
     def _text_of(self, snap):
         return "".join(e.get("text") or "" for c in snap["chunks"]
@@ -212,40 +211,44 @@ class TestDirtyEpochNeverStale:
         assert third[b] == first[b]
 
     def test_fold_crowded_marks_dirty(self):
-        """A host fold reseeds the lane's rows (coalesced segmentation):
-        the cached blob must be invalidated even though no new op
-        arrived — a missed mark_dirty here would serve a stale summary
-        with the OLD payload ids."""
-        store = self._store(capacities=(64, 256))
+        """The page compaction tick left-packs a lane's rows and releases
+        emptied pages without any new op: whatever a summarize serves
+        afterwards — the cached blob or a fresh extraction — must equal a
+        forced fresh extraction of the compacted pages."""
+        store = self._store(page_rows=16)
         key = ("d", "s", "t")
         seq = 0
-        # Grow the lane near 3/4 capacity with acked single-char inserts,
-        # then remove most of them so the fold demotes.
         for i in range(120):
             seq += 1
             store.apply({key: [store.builder.insert_text(
                 0, "x", seq - 1, 0, seq)]})
+        # Remove most of it, then advance min_seq past the remove so the
+        # tombstones are zamboni-eligible.
+        seq += 1
+        store.apply({key: [store.builder.remove(0, 110, seq - 1, 0, seq)]})
+        seq += 1
+        store.apply({key: [store.builder.insert_text(
+            0, "!", seq - 1, 0, seq, msn=seq - 1)]})
         expect = store.text(key)
         first = store.extract_all()
         assert self._text_of(first[key]) == expect
-        # Advance min_seq past everything and force the compact tick.
         seq += 1
         store.apply({key: [store.builder.insert_text(
-            len(expect), "!", seq - 1, 0, seq, msn=seq - 1)]})
+            len(expect), "?", seq - 1, 0, seq, msn=seq - 1)]})
         expect = store.text(key)
-        store.flushes_since_compact = store.compact_every
         store.compact_all()
-        if store.folds:
-            # The fold path must have advanced the change generation.
-            assert store.change_gen.get(key, 0) \
-                > store.last_summarized_gen.get(key, 0)
+        assert store.page_compactions >= 1
         after = store.extract_all()
         assert self._text_of(after[key]) == expect
+        with store._guard_lock:
+            store._snap_cache.clear()
+        assert store.extract_all()[key] == after[key]
 
     def test_rescue_lane_marks_dirty(self, monkeypatch):
-        """_rescue_lane reseeds a lane wholesale; a summarize immediately
-        after must re-extract, not serve the pre-rescue blob."""
-        store = self._store(capacities=(16,), lanes=1)
+        """_rescue_paged reseeds a lane wholesale; a summarize
+        immediately after must re-extract, not serve the pre-rescue
+        blob."""
+        store = self._store(page_rows=16)
         key = ("d", "s", "t")
         seq = 0
         for i in range(4):
@@ -254,12 +257,10 @@ class TestDirtyEpochNeverStale:
                 0, "ab", seq - 1, 0, seq)]})
         store.extract_all()  # populate the cache
         gen_before = store.change_gen.get(key, 0)
-        row = store.buckets[0].row(store.where[key][1])
-        store.buckets[0].free(store.where[key][1])
-        store.where.pop(key)
+        row = store.pages.row(key)
         seq += 1
         ops = [store.builder.insert_text(0, "Z", seq - 1, 0, seq)]
-        assert store._rescue_lane(key, row, ops)
+        assert store._rescue_paged(key, row, ops)
         assert store.change_gen.get(key, 0) > gen_before
         snap = store.extract_all()[key]
         assert self._text_of(snap).startswith("Z")

@@ -1,14 +1,13 @@
 """Paged lane memory (docs/paged_memory.md): the page allocator's
 free-list/refcount invariants, gather/scatter-by-page-id conformance
-against both the scalar oracle and the bucketed lane store, page-granular
-compaction, the annotate-ring rescue, and run-twice determinism of paged
-serving under a seeded FaultPlan.
+against the scalar oracle, page-granular compaction, the annotate-ring
+rescue, and run-twice determinism of paged serving under a seeded
+FaultPlan.
 
 The conformance bar mirrors tests/test_kernel.py: on a storm-doc ragged
-fleet (one deep document atop many keystroke documents — exactly the
-workload the bucket grid pads worst), every channel's text at every
-perspective and every assembled snapshot must be identical whether the
-rows live in capacity buckets or pages."""
+fleet (one deep document atop many keystroke documents), every channel's
+text and the flattened content of every assembled snapshot must equal
+the oracle's."""
 
 import json
 import random
@@ -20,7 +19,8 @@ from test_kernel import GOD, apply_to_oracle, random_schedule
 
 from fluidframework_tpu.mergetree import MergeTreeOracle
 from fluidframework_tpu.mergetree.constants import PAGE_ROWS
-from fluidframework_tpu.mergetree.host import GOD_CLIENT
+from fluidframework_tpu.mergetree.host import (GOD_CLIENT,
+                                               flatten_snapshot_content)
 from fluidframework_tpu.mergetree.paging import (
     BLANK_PAGE,
     PageAllocator,
@@ -153,7 +153,7 @@ def _stream(builder, schedule):
 
 def _ragged_fleet(seed, storm_ops=120, fleet=24, fleet_ops=4):
     """One storm doc + a fleet of keystroke docs, per-doc sequenced
-    schedules (the bucket grid's worst case: every bucketed lane pads
+    schedules (the worst case for any layout that pads every lane
     toward the storm doc's depth)."""
     rng = random.Random(seed)
     docs = {("doc", "s", "storm"): random_schedule(rng, 3, storm_ops)}
@@ -162,142 +162,133 @@ def _ragged_fleet(seed, storm_ops=120, fleet=24, fleet_ops=4):
     return docs
 
 
+def _oracle_content(schedule):
+    """(oracle, flattened visible content) after a test_kernel op
+    schedule — the engine-independent reference the paged store's
+    assembled snapshots are held to (flatten_snapshot_content)."""
+    oracle = MergeTreeOracle(local_client=GOD)
+    apply_to_oracle(oracle, schedule)
+    return oracle, flatten_snapshot_content(
+        {"chunks": [oracle.snapshot_segments()]})
+
+
 class TestPagedConformance:
     @pytest.mark.parametrize("seed", [1, 7, 23])
     def test_storm_fleet_matches_oracle_and_bucketed(self, seed):
+        """Every lane of the ragged fleet — text and the flattened
+        (char, props) content of its assembled snapshot — equals the
+        oracle, with no capacity ceremony: no overflow drops."""
         schedules = _ragged_fleet(seed)
-        paged = MergeLaneStore(paged=True, page_rows=16)
-        bucketed = MergeLaneStore()
-        streams_p = {k: _stream(paged.builder, s)
-                     for k, s in schedules.items()}
-        streams_b = {k: _stream(bucketed.builder, s)
-                     for k, s in schedules.items()}
-        paged.apply(streams_p)
-        bucketed.apply(streams_b)
+        paged = MergeLaneStore(page_rows=16)
+        paged.apply({k: _stream(paged.builder, s)
+                     for k, s in schedules.items()})
 
+        snaps = paged.extract_all()
         for key, schedule in schedules.items():
-            oracle = MergeTreeOracle(local_client=GOD)
-            apply_to_oracle(oracle, schedule)
+            oracle, content = _oracle_content(schedule)
             top_seq = max(op[-1] for op in schedule)
-            perspectives = [(top_seq, GOD)] + [
-                (max(0, top_seq - d), GOD) for d in (1, 3, 7)]
-            text_p = paged.text(key)
-            assert text_p == bucketed.text(key)
-            assert text_p == oracle.get_text(ref_seq=top_seq, client=GOD)
-            # Entry-level (props included) equality paged vs bucketed.
-            ep = paged.entries(key)
-            eb = bucketed.entries(key)
-            assert ep == eb
-            del perspectives  # latest-view text is the cross-engine bar
-
-        # Assembled snapshots — the wire-visible artifact — identical.
-        snaps_p = paged.extract_all()
-        snaps_b = bucketed.extract_all()
-        assert snaps_p == snaps_b
-        # The paged fleet never pays CAPACITY ceremony (folds,
-        # promotions, overflow drops). Annotate-ring exhaustion — the
-        # per-row overflow class pages cannot fix — may still rescue,
-        # but never more often than the bucketed run, which adds its
-        # capacity recoveries on top.
-        assert paged.folds == 0
+            assert paged.text(key) == oracle.get_text(ref_seq=top_seq,
+                                                      client=GOD)
+            assert flatten_snapshot_content(snaps[key]) == content, key
+            # Pages track live rows: no padding past the last page.
+            assert len(paged.pages.tables[key]) == pages_for(
+                paged.pages.counts[key], 16)
         assert paged.overflow_drops == 0
-        assert paged.fold_rescue_dispatches <= \
-            bucketed.fold_rescue_dispatches
+        assert not paged.opaque
 
-    def test_chunked_stream_rides_one_scanned_burst(self):
-        """A stream longer than the widest T bucket applies through
-        serve_step.serve_paged_burst (stacked [K, B, T] chunks, one
-        scan) and must match the bucketed chunked applier exactly."""
-        paged = MergeLaneStore(paged=True)
-        bucketed = MergeLaneStore()
+    def test_chunked_stream_rides_one_scanned_burst(self, monkeypatch):
+        """A stream longer than the widest T bucket applies through ONE
+        serve_step.serve_paged_burst dispatch (stacked [K, B, T] chunks,
+        one scan) and must match the oracle exactly."""
+        from fluidframework_tpu.server import serve_step
+        paged = MergeLaneStore()
         key = ("doc", "s", "bulk")
-        n = 700  # > max_t=256 -> K=4 chunks, padded to 4
+        n = 700  # > max_t=256 -> K=3 chunks, padded to 4
+        calls = []
+        real = serve_step.serve_paged_burst
 
-        def ops(b):
-            out = [b.insert_text(0, "seed ", 0, GOD_CLIENT, 1)]
-            for i in range(n):
-                out.append(b.insert_text(min(i, 3), "ab", i + 1,
-                                         GOD_CLIENT, i + 2))
-            return out
+        def spy(*args):
+            calls.append(int(args[5].kind.shape[0]))
+            return real(*args)
 
-        paged.apply({key: ops(paged.builder)})
-        bucketed.apply({key: ops(bucketed.builder)})
-        assert paged.text(key) == bucketed.text(key)
+        monkeypatch.setattr(serve_step, "serve_paged_burst", spy)
+        b = paged.builder
+        schedule = [("insert", 0, "seed ", 0, GOD_CLIENT, 1)]
+        for i in range(n):
+            schedule.append(("insert", min(i, 3), "ab", i + 1,
+                             GOD_CLIENT, i + 2))
+        paged.apply({key: _stream(b, schedule)})
+        assert calls == [4]
+        oracle, _ = _oracle_content(schedule)
+        assert paged.text(key) == oracle.get_text()
         assert len(paged.text(key)) == 5 + 2 * n
 
     def test_annotate_ring_exhaustion_takes_host_rescue(self):
         """>anno_slots annotates on one segment in a single window
         exhaust the per-row ring — the one overflow class pages cannot
         fix. The paged path must rollback + host-fold (rings resolve
-        into props) and end bit-identical to the bucketed rescue."""
-        paged = MergeLaneStore(paged=True)
-        bucketed = MergeLaneStore()
+        into props) and end equal to the oracle, props included."""
+        paged = MergeLaneStore()
         key = ("doc", "s", "anno")
-
-        def ops(b):
-            out = [b.insert_text(0, "abcdef", 0, GOD_CLIENT, 1)]
-            for i in range(6):  # DEFAULT_ANNO_SLOTS=4 -> ring exhausts
-                out.append(b.annotate(0, 6, {f"k{i}": i}, 1, GOD_CLIENT,
-                                      2 + i))
-            return out
-
-        paged.apply({key: ops(paged.builder)})
-        bucketed.apply({key: ops(bucketed.builder)})
+        schedule = [("insert", 0, "abcdef", 0, GOD_CLIENT, 1)]
+        for i in range(6):  # DEFAULT_ANNO_SLOTS=4 -> ring exhausts
+            schedule.append(("annotate", 0, 6, {f"k{i}": i}, 1,
+                             GOD_CLIENT, 2 + i))
+        paged.apply({key: _stream(paged.builder, schedule)})
         assert paged.paged_rescues >= 1
-        assert paged.text(key) == bucketed.text(key) == "abcdef"
-        assert paged.extract_all() == bucketed.extract_all()
+        _, content = _oracle_content(schedule)
+        assert paged.text(key) == "abcdef"
+        assert flatten_snapshot_content(paged.extract_all()[key]) \
+            == content
 
     def test_mid_burst_ring_overflow_rolls_back_and_rescues(self):
         """Ring exhaustion in a LATER chunk of a scanned burst: the
         overflow flag is sticky across the scan carry, the flagged doc
         rolls back to the retained PRE-BURST view, and the host rescue
-        re-applies the full stream — content identical to bucketed."""
-        from fluidframework_tpu.mergetree.host import (
-            flatten_snapshot_content)
+        re-applies the full stream — content equal to the oracle."""
+        schedule = []
+        seq = 0
+        for _ in range(300):  # > max_t -> K=2 scanned chunks
+            seq += 1
+            schedule.append(("insert", 0, "y", seq - 1, GOD_CLIENT, seq))
+        for i in range(6):  # ring exhausts in chunk 2
+            seq += 1
+            schedule.append(("annotate", 0, 4, {f"k{i}": i}, seq - 1,
+                             GOD_CLIENT, seq))
 
-        def ops(b):
-            out = []
-            seq = 0
-            for _ in range(300):  # > max_t -> K=2 scanned chunks
-                seq += 1
-                out.append(b.insert_text(0, "y", seq - 1, GOD_CLIENT,
-                                         seq))
-            for i in range(6):  # ring exhausts in chunk 2
-                seq += 1
-                out.append(b.annotate(0, 4, {f"k{i}": i}, seq - 1,
-                                      GOD_CLIENT, seq))
-            return out
-
-        paged = MergeLaneStore(paged=True)
-        bucketed = MergeLaneStore()
+        paged = MergeLaneStore()
         key = ("d", "s", "burst-anno")
-        paged.apply({key: ops(paged.builder)})
-        bucketed.apply({key: ops(bucketed.builder)})
+        paged.apply({key: _stream(paged.builder, schedule)})
         assert paged.paged_rescues >= 1
-        assert paged.text(key) == bucketed.text(key)
+        oracle, content = _oracle_content(schedule)
+        assert paged.text(key) == oracle.get_text()
         assert flatten_snapshot_content(paged.extract_all()[key]) \
-            == flatten_snapshot_content(bucketed.extract_all()[key])
+            == content
 
     def test_seed_then_apply_matches_bucketed(self):
         """Snapshot-seeded lanes (attach-time content) bootstrap into
-        pages and serve follow-on ops identically to bucket seeding."""
+        pages — any size, no fit check — and serve follow-on ops
+        exactly, props included."""
         entries = [{"text": "hello paged world", "props": {"x": 1}}]
-        paged = MergeLaneStore(paged=True)
-        bucketed = MergeLaneStore()
+        paged = MergeLaneStore(page_rows=4)
         key = ("doc", "s", "seeded")
         assert paged.seed(key, entries, 0, 4)
-        assert bucketed.seed(key, entries, 0, 4)
         paged.apply({key: [paged.builder.remove(0, 6, 4, GOD_CLIENT, 5)]})
-        bucketed.apply({key: [bucketed.builder.remove(0, 6, 4, GOD_CLIENT,
-                                                      5)]})
-        assert paged.text(key) == bucketed.text(key) == "paged world"
+        assert paged.text(key) == "paged world"
+        assert flatten_snapshot_content(paged.extract_all()[key]) == \
+            [(ch, (("x", 1),)) for ch in "paged world"]
+        # A seed larger than one page spans several, no opaque fallback.
+        big = ("doc", "s", "big")
+        assert paged.seed(big, [{"text": "z"}] * 9, 0, 4)
+        assert len(paged.pages.tables[big]) == pages_for(9, 4)
+        assert paged.text(big) == "z" * 9
 
     def test_fragmentation_then_compact_releases_pages(self):
         """Insert deep, remove most, advance the MSN past the removes:
         the budgeted page-granular zamboni left-packs the survivors and
         the trailing release returns the emptied pages to the pool —
         text untouched."""
-        store = MergeLaneStore(paged=True, page_rows=16)
+        store = MergeLaneStore(page_rows=16)
         key = ("doc", "s", "frag")
         b = store.builder
         ops = []
@@ -323,7 +314,7 @@ class TestPagedConformance:
         """Same (docs, pages, T) shape applied repeatedly must hit the
         jit cache: pow2 padding is the retrace bound, probed as
         kernel.paged_apply.* (the static rule's runtime cross-check)."""
-        store = MergeLaneStore(paged=True)
+        store = MergeLaneStore()
         b = store.builder
         seq = 0
 
@@ -359,10 +350,9 @@ class _Ctx:
         raise err
 
 
-def _lam(emit, paged):
+def _lam(emit):
     return TpuSequencerLambda(
-        _Ctx(), emit=emit, nack=lambda *a: None, client_timeout_s=0.0,
-        paged_lanes=paged)
+        _Ctx(), emit=emit, nack=lambda *a: None, client_timeout_s=0.0)
 
 
 def _qm(offset, doc, box):
@@ -408,9 +398,9 @@ def _waves(n_waves=5, docs=3, storm_ops=6, fleet_ops=1):
     return waves
 
 
-def _drive(paged, stall=None):
+def _drive(stall=None):
     emits = []
-    lam = _lam(lambda doc, m: emits.append(_emit_key(doc, m)), paged)
+    lam = _lam(lambda doc, m: emits.append(_emit_key(doc, m)))
     if stall is not None:
         lam.stall_hook = stall
     off = 0
@@ -424,15 +414,41 @@ def _drive(paged, stall=None):
     return lam, emits, texts
 
 
+def _scalar_emits():
+    """The same waves through the scalar DeliLambda (object boxcars):
+    the sequencing reference the device path is held to."""
+    from fluidframework_tpu.server.lambdas.deli import DeliLambda
+    out = []
+    lam = DeliLambda(_Ctx(), emit=lambda d, m: out.append(_emit_key(d, m)),
+                     nack=lambda *a: None)
+    off = 0
+    for wave in _waves():
+        for doc, box in wave:
+            lam.handler(QueuedMessage(topic="rawdeltas", partition=0,
+                                      offset=off, key=doc, value=box))
+            off += 1
+    return out
+
+
 class TestPagedServing:
     def test_paged_sequencer_emits_identical_to_bucketed(self):
-        """The serving contract: emit stream (ORDER included) and
-        materialized channel text identical across storage engines."""
-        _, emits_b, texts_b = _drive(paged=False)
-        lam, emits_p, texts_p = _drive(paged=True)
-        assert emits_p == emits_b
-        assert texts_p == texts_b
-        assert lam.merge.paged
+        """The serving contract: per-document emit streams (order
+        included) equal the scalar DeliLambda's, and each channel's
+        materialized text equals the oracle applying the sequenced ops
+        (inserts at 0 from one client: the digits newest first)."""
+        lam, emits_p, texts_p = _drive()
+        emits_s = _scalar_emits()
+        for d in ("p0", "p1", "p2"):
+            assert [e for e in emits_p if e[0] == d] == \
+                [e for e in emits_s if e[0] == d], d
+        for d, ops in (("p0", 30), ("p1", 5), ("p2", 5)):
+            oracle = MergeTreeOracle(local_client=GOD)
+            for seq, csn in [(e[1], e[4]) for e in emits_s
+                             if e[0] == d and e[3] is not None]:
+                oracle.insert_text(0, f"{csn % 10}", seq - 1, 1, seq)
+                oracle.update_seq(seq)
+            assert texts_p[d] == oracle.get_text() == "".join(
+                f"{i % 10}" for i in range(ops, 0, -1))
         assert lam.merge.paged_stats()["pages_in_use"] >= 1
 
     def test_faultplan_paged_serving_run_twice_deterministic(self):
@@ -446,7 +462,7 @@ class TestPagedServing:
             plan = faultinject.FaultPlan(seed=4242, stall=1.0,
                                          stall_range_ms=(0.05, 0.2))
             lam, emits, texts = _drive(
-                paged=True, stall=lambda: faultinject.stall(plan))
+                stall=lambda: faultinject.stall(plan))
             return (emits, texts, plan.fingerprint(),
                     lam.merge.paged_stats())
 
@@ -480,7 +496,7 @@ def _drive_mega(interpret, waves=None):
     interpret=True runs the pallas program (interpret mode on CPU),
     False the counted scan fallback path inside the same dispatch."""
     emits = []
-    lam = _lam(lambda doc, m: emits.append(_emit_key(doc, m)), True)
+    lam = _lam(lambda doc, m: emits.append(_emit_key(doc, m)))
     lam.megakernel_interpret = interpret
     off = 0
     for wave in (waves if waves is not None else _waves()):
@@ -539,8 +555,9 @@ class TestMegakernelServing:
         """Annotate-ring exhaustion inside a megakernel ring — the one
         overflow class page pre-growth cannot prevent: the flagged doc
         rolls back to its retained pre-ring view, the host rescue
-        re-applies the op stream, and the run stays bit-identical to
-        the bucketed engine."""
+        re-applies the op stream, and the run stays identical to the
+        object path (per-window applies + the store's own rescue) —
+        emits, text, and snapshot content with the annotates' props."""
         def waves():
             out = []
             csn = {d: 0 for d in range(3)}
@@ -567,19 +584,22 @@ class TestMegakernelServing:
         assert lam_p.merge.paged_rescues >= 1
 
         emits_ref = []
-        lam_b = _lam(lambda doc, m: emits_ref.append(_emit_key(doc, m)),
-                     False)
+        lam_o = _lam(lambda doc, m: emits_ref.append(_emit_key(doc, m)))
         off = 0
         for wave in waves():
             for doc, box in wave:
-                lam_b.handler_raw(_qm(off, doc, box))
+                lam_o.handler(QueuedMessage(topic="rawdeltas", partition=0,
+                                            offset=off, key=doc, value=box))
                 off += 1
-            lam_b.flush()
-        lam_b.drain()
-        texts_ref = {d: lam_b.channel_text(d, "s", "t")
+            lam_o.flush()
+        texts_ref = {d: lam_o.channel_text(d, "s", "t")
                      for d in ("a0", "a1", "a2")}
         assert emits_p == emits_ref
         assert texts_p == texts_ref
+        snap_p = lam_p.summarize_documents()[("a0", "s", "t")]
+        snap_o = lam_o.summarize_documents()[("a0", "s", "t")]
+        assert flatten_snapshot_content(snap_p) == \
+            flatten_snapshot_content(snap_o)
 
     def test_megakernel_path_fills_every_serving_sub_span(self):
         """Span coverage parity (docs/observability.md v3): the

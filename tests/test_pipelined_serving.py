@@ -1,17 +1,20 @@
-"""Deep-pipelined serving: the N-deep in-flight window ring.
+"""Pipelined serving: the megakernel ring.
 
-The ring (tpu_sequencer._ring, docs/serving_pipeline.md) lets window k+1's
-host pack/staging overlap window k's device execution and window k-1's
-narrow readback. These tests pin its safety contract:
+A pipelined fast flush stages all its windows and leaves as ONE
+serve_megakernel dispatch whose readback the next flush's drain joins
+(tpu_sequencer._ring, docs/serving_pipeline.md R10). These tests pin its
+safety contract:
 
-- multi-window backlogs with an overflow-triggered fold MID-RING must
-  produce sequence numbers and lane state bit-identical to
-  ``pipelined=False`` (the quarantine fixup path);
+- multi-window rings with a host rescue MID-RING must produce sequence
+  numbers and lane state identical to ``pipelined=False`` (per-window
+  dispatch);
+- K windows in one megakernel emit bit-identically to per-window
+  dispatch, and risky windows dispatch and drain at once;
 - adaptive window sizing only ever draws T from the fixed t_buckets grid,
-  and a warm pipeline does not retrace serve_window per flush
+  and a warm pipeline does not retrace serve_megakernel per flush
   (JitRetraceProbe regression);
-- donation bookkeeping (occupancy hints) stays consistent with the device
-  counts the narrow result reports.
+- occupancy bookkeeping stays consistent with the device counts the
+  narrow result reports.
 """
 
 import json
@@ -19,7 +22,7 @@ import json
 import numpy as np
 import pytest
 
-from fluidframework_tpu.mergetree.client import OP_INSERT
+from fluidframework_tpu.mergetree.client import OP_ANNOTATE, OP_INSERT
 from fluidframework_tpu.protocol.messages import (
     Boxcar,
     DocumentMessage,
@@ -72,6 +75,25 @@ def _insert(csn, pos, text):
                 "type": OP_INSERT, "pos1": pos, "seg": {"text": text}}}})
 
 
+def _annotate(csn, start, end, props):
+    return DocumentMessage(
+        client_sequence_number=csn, reference_sequence_number=csn - 1,
+        type=MessageType.OPERATION,
+        contents={"address": "s", "contents": {
+            "address": "t", "contents": {
+                "type": OP_ANNOTATE, "pos1": start, "pos2": end,
+                "props": props}}})
+
+
+def _lww_set(csn, key, value):
+    return DocumentMessage(
+        client_sequence_number=csn, reference_sequence_number=csn - 1,
+        type=MessageType.OPERATION,
+        contents={"address": "s", "contents": {
+            "address": "m", "contents": {
+                "type": "set", "key": key, "value": value, "pid": "p"}}})
+
+
 def _emit_key(doc_id, m):
     return (doc_id, m.sequence_number, m.minimum_sequence_number,
             m.client_id, m.client_sequence_number)
@@ -87,10 +109,13 @@ def _drive(lam, waves, emits):
     lam.drain()
 
 
-def _deep_ragged_waves(n_waves=4, docs=3, deep_ops=8, shallow_ops=2):
+def _deep_ragged_waves(n_waves=4, docs=3, deep_ops=8, shallow_ops=2,
+                       annotates=None):
     """Doc r0 types deep bursts (spans multiple T=4 windows with
     t_buckets=(1, 4)); the rest send keystrokes. Inserts land at pos 0
-    so content is order-sensitive: any ring reordering corrupts it."""
+    so content is order-sensitive: any ring reordering corrupts it.
+    `annotates` maps a wave index to a count of annotates doc r0 appends
+    over its first two characters (all on one row's ring)."""
     waves = []
     csn = {d: 0 for d in range(docs)}
     for w in range(n_waves):
@@ -102,61 +127,68 @@ def _deep_ragged_waves(n_waves=4, docs=3, deep_ops=8, shallow_ops=2):
             for _ in range(n):
                 csn[d] += 1
                 msgs.append(_insert(csn[d], 0, f"{csn[d] % 10}"))
+            if d == 0:
+                for i in range((annotates or {}).get(w, 0)):
+                    csn[d] += 1
+                    msgs.append(_annotate(csn[d], 0, 2, {f"a{i}": w}))
             wave.append((doc, Boxcar("t", doc, f"c{d}", msgs)))
         waves.append(wave)
     return waves
 
 
 def _merge_rows(lam, key):
-    """The key's device lane planes as host arrays (bit-identity probe)."""
-    b, lane = lam.merge.where[key]
-    row = lam.merge.buckets[b].row(lane)
+    """The key's live rows (the first `count` of its gathered pages) as
+    host arrays — the bit-identity probe."""
     import jax
-    return jax.device_get(row)
+    row = jax.device_get(lam.merge.pages.row(key))
+    n = int(row.count)
+    return row._replace(**{f: getattr(row, f)[:n] for f in (
+        "length", "ins_seq", "ins_client", "rem_seq")})
 
 
 class TestFoldMidRingBitIdentity:
     def test_multiwindow_overflow_fold_mid_ring_matches_sync(self):
-        """Tiny capacities force overflow folds while later windows of
-        the same multi-window backlog are still in flight; the
-        quarantine fixup must reconverge to EXACTLY the sync result:
-        same sequence numbers, same text, same device lane planes."""
-        waves = _deep_ragged_waves(n_waves=5, deep_ops=8)
+        """An annotate-ring exhaustion in the LAST window of a
+        multi-window megakernel ring rolls the doc back to the pre-ring
+        view and host-rescues it with the ops of EVERY window of the
+        ring; the result must reconverge to EXACTLY the per-window
+        (pipelined=False) run: same sequence numbers, same text, same
+        content and props, same device lane planes for the fleet."""
+        from fluidframework_tpu.mergetree.host import (
+            flatten_snapshot_content)
+        waves = _deep_ragged_waves(n_waves=5, deep_ops=8,
+                                   annotates={1: 2, 2: 6})
 
         def run(pipelined):
             emits = []
             lam = _lam(lambda d, m: emits.append(_emit_key(d, m)),
-                       merge_store=MergeLaneStore(capacities=(4, 16, 64)),
+                       merge_store=MergeLaneStore(page_rows=4),
                        t_buckets=(1, 4))
             lam.pipelined = pipelined
-            if pipelined:
-                # Force hint-risky windows through the ring: production
-                # routes predictable overflow to the sync path, but the
-                # quarantine fixup must stay correct for the overflow
-                # the hints cannot see (overlap/anno exhaustion).
-                lam.defer_risky_windows = True
             _drive(lam, waves, emits)
             return lam, emits
 
-        fix0 = counters.get("serving.ring_fixups")
+        rec0 = counters.get("serving.recovery_dispatches")
         sync_lam, sync_emits = run(False)
         ring_lam, ring_emits = run(True)
-        # The scenario actually exercised a mid-ring fold fixup.
-        assert counters.get("serving.ring_fixups") > fix0
-        # The recovery's lane compaction agrees between modes (this
-        # scenario recovers by compact->promote, so folds may be zero —
-        # but a ring path that folded differently would diverge here;
-        # promotion placement equality is locked by `where` below).
-        assert ring_lam.merge.folds == sync_lam.merge.folds
+        # The scenario actually exercised the rescue in both modes.
+        assert sync_lam.merge.paged_rescues >= 1
+        assert ring_lam.merge.paged_rescues == sync_lam.merge.paged_rescues
+        assert counters.get("serving.recovery_dispatches") > rec0
         # The STREAM is bit-identical, order included: an out-of-order
         # drain or a misattached emit_args would reorder across windows
         # while keeping the same multiset.
         assert sync_emits == ring_emits
+        snaps_s = sync_lam.summarize_documents()
+        snaps_r = ring_lam.summarize_documents()
         for d in range(3):
             key = (f"r{d}", "s", "t")
             assert sync_lam.channel_text(*key) == \
                 ring_lam.channel_text(*key)
-            assert sync_lam.merge.where[key] == ring_lam.merge.where[key]
+            assert flatten_snapshot_content(snaps_s[key]) == \
+                flatten_snapshot_content(snaps_r[key])
+            if d == 0:
+                continue  # segmentation is the rescue's own fold
             a = _merge_rows(sync_lam, key)
             b = _merge_rows(ring_lam, key)
             for name in ("length", "ins_seq", "ins_client", "rem_seq",
@@ -167,21 +199,27 @@ class TestFoldMidRingBitIdentity:
                     err_msg=f"{key} plane {name} diverged")
 
     def test_natural_gate_routes_risky_windows_sync(self):
-        """With the hook OFF, hint-risky windows drain the ring and run
-        the cheap sync recovery — the stream still matches sync mode."""
-        waves = _deep_ragged_waves(n_waves=4, deep_ops=8)
+        """Risky windows (non-insert merge traffic) dispatch with the
+        windows staged before them and drain at once, so their likely
+        rescue runs with nothing behind it — the stream still matches
+        the per-window run, and the rings outnumber the flushes."""
+        waves = _deep_ragged_waves(n_waves=4, deep_ops=8,
+                                   annotates={1: 1, 3: 1})
 
         def run(pipelined):
             emits = []
             lam = _lam(lambda d, m: emits.append(_emit_key(d, m)),
-                       merge_store=MergeLaneStore(capacities=(4, 16, 64)),
+                       merge_store=MergeLaneStore(page_rows=4),
                        t_buckets=(1, 4))
             lam.pipelined = pipelined
             _drive(lam, waves, emits)
             return lam, emits
 
         sync_lam, sync_emits = run(False)
+        rings0 = counters.get("serving.megakernel_rings")
         ring_lam, ring_emits = run(True)
+        assert counters.get("serving.megakernel_rings") - rings0 > \
+            len(waves)
         assert sync_emits == ring_emits  # order included
         for d in range(3):
             key = (f"r{d}", "s", "t")
@@ -191,12 +229,14 @@ class TestFoldMidRingBitIdentity:
 
 class TestRingDepth:
     def test_ring_runs_deeper_than_one(self):
-        """Clean keystroke waves must actually pipeline: occupancy climbs
-        past one in-flight window and every deferred window drains."""
+        """Clean multi-window flushes must actually pipeline: each leaves
+        as one megakernel carrying several windows, so occupancy climbs
+        past one in-flight window, and every deferred window drains."""
         counters.gauge("serving.ring_peak_occupancy", 0.0)
-        waves = _deep_ragged_waves(n_waves=6, deep_ops=2, shallow_ops=2)
+        waves = _deep_ragged_waves(n_waves=6, deep_ops=8, shallow_ops=2)
         emits = []
-        lam = _lam(lambda d, m: emits.append(_emit_key(d, m)))
+        lam = _lam(lambda d, m: emits.append(_emit_key(d, m)),
+                   t_buckets=(1, 4))
         lam.pipelined = True
         _drive(lam, waves, emits)
         assert counters.get("serving.ring_peak_occupancy") > 1
@@ -254,41 +294,61 @@ class TestAdaptiveWindowSizing:
 
     def test_warm_pipeline_does_not_retrace_serve_window(self):
         """JitRetraceProbe-style regression: after warm-up, further
-        flushes with the same traffic shape must not grow serve_window's
-        compile cache (adaptive sizing stays on the warmed grid)."""
+        flushes with the same traffic shape must not grow
+        serve_megakernel's compile cache (adaptive sizing stays on the
+        warmed grid)."""
         from fluidframework_tpu.server import serve_step
         waves = _deep_ragged_waves(n_waves=8, deep_ops=2, shallow_ops=2)
         lam = _lam()
         lam.pipelined = True
         _drive(lam, waves[:5], [])
+
         def cache_size():
             try:
-                return serve_step.serve_window._cache_size()
+                return serve_step.serve_megakernel._cache_size()
             except TypeError:
-                return serve_step.serve_window._cache_size
+                return serve_step.serve_megakernel._cache_size
         warm = cache_size()
         _drive(lam, waves[5:], [])
         assert cache_size() == warm, \
-            "serve_window retraced on a warm traffic shape"
+            "serve_megakernel retraced on a warm traffic shape"
 
 
 class TestOccupancyHints:
     def test_hints_track_device_counts_after_drain(self):
-        """The narrow result's occupancy planes keep the confirmed base
-        exact: after a full drain, count_hint matches the device count
-        plane and nothing is left pending."""
+        """The narrow result keeps the host's occupancy exact: after a
+        full drain, every LWW lane's count_hint matches the device's
+        occupied key slots with nothing left pending, and every merge
+        doc's host count mirror matches the rows its pages hold (the
+        exact int32 scalar plane)."""
         lam = _lam()
         lam.pipelined = True
         waves = _deep_ragged_waves(n_waves=3, deep_ops=3, shallow_ops=3)
+        csn = 100
+        for w, wave in enumerate(waves):
+            msgs = [] if w else [_join("cm")]
+            for i in range(5):
+                csn += 1
+                msgs.append(_lww_set(csn, f"k{w}.{i}", i))
+            wave.append(("m0", Boxcar("t", "m0", "cm", msgs)))
         _drive(lam, waves, [])
-        for bucket in lam.merge.buckets:
-            if not any(k is not None for k in bucket.used):
-                continue
-            counts = np.asarray(bucket.state.count).astype(np.int64)
+        checked = 0
+        for bucket in lam.lww.buckets:
             live = [i for i, k in enumerate(bucket.used) if k is not None]
+            if not live:
+                continue
+            slots = np.count_nonzero(np.asarray(bucket.state.key) >= 0,
+                                     axis=-1)
             np.testing.assert_array_equal(bucket.count_hint[live],
-                                          counts[live])
+                                          slots[live])
             assert not bucket.hint_pending[live].any()
+            checked += len(live)
+        assert checked >= 1
+        pg = lam.merge.pages
+        for d in range(3):
+            key = (f"r{d}", "s", "t")
+            row = np.asarray(pg.row(key).length)
+            assert pg.counts[key] == int(np.count_nonzero(row)), key
 
     def test_donated_windows_counted(self):
         counters.reset()
@@ -299,23 +359,42 @@ class TestOccupancyHints:
         assert counters.get("serving.ring_donated_windows") > 0
 
     def test_mesh_placement_disables_lane_state_donation(self):
-        """jax 0.4.37: the donated dp-sharded serve_window executable
-        returns corrupt lane planes when reloaded warm from the
-        persistent compilation cache (cold compiles are correct) —
-        mesh placements must stay on serve_window_keep until a jax
-        upgrade clears the repro (docs/serving_pipeline.md R6)."""
+        """jax 0.4.37: a donated dp-sharded executable returns corrupt
+        lane planes when reloaded warm from the persistent compilation
+        cache (cold compiles are correct) — mesh-placed pools must stay
+        on serve_megakernel_keep until a jax upgrade clears the repro
+        (docs/serving_pipeline.md R6)."""
         from fluidframework_tpu.parallel.mesh import make_mesh
-        assert _lam().donate_lane_states is True
-        assert _lam(mesh=make_mesh(sp=1)).donate_lane_states is False
+        from fluidframework_tpu.server import serve_step
+        assert _lam().merge.pages.donate is True
+        mesh_lam = _lam(mesh=make_mesh(sp=1))
+        assert mesh_lam.merge.pages.donate is False
+        calls = []
+        real = serve_step.serve_megakernel_keep
+
+        def spy(*a):
+            calls.append(1)
+            return real(*a)
+
+        serve_step.serve_megakernel_keep = spy
+        try:
+            mesh_lam.handler_raw(_qm(0, "d0", Boxcar("t", "d0", "c0", [
+                _join("c0"), _insert(1, 0, "a")])))
+            mesh_lam.flush()
+            mesh_lam.drain()
+        finally:
+            serve_step.serve_megakernel_keep = real
+        assert calls, "mesh ring did not take the non-donating program"
+        assert mesh_lam.channel_text("d0", "s", "t") == "a"
 
 
 def _keystroke_waves(n_waves=10, docs=4, ops=3, bad_flush=None,
                      bad_pos=None):
-    """Shallow per-doc keystroke waves (one window per flush) so staged
-    windows accumulate into scan bursts. `bad_flush` injects one insert
-    at an impossible position (`bad_pos` beyond the doc's length) on an
-    extra channel-owning doc — structurally unpredictable overflow the
-    occupancy-hint fit proof cannot see."""
+    """Per-doc keystroke waves; with t_buckets=(1, 4) and ops > 4 a
+    flush spans several windows, which one megakernel ring carries.
+    `bad_flush` injects one insert at an impossible position (`bad_pos`
+    beyond the doc's length) on an extra channel-owning doc —
+    structurally unpredictable overflow no fit proof can see."""
     waves = []
     csn = {d: 0 for d in range(docs)}
     bad_csn = 0
@@ -339,49 +418,41 @@ def _keystroke_waves(n_waves=10, docs=4, ops=3, bad_flush=None,
 
 
 class TestFusedBursts:
-    """The fused serving burst (docs/serving_pipeline.md R8): staged
-    windows leave as ONE lax.scan per burst, bit-identical to the sync
-    and per-window ring paths — emit order, lane planes, and recovery
-    semantics included."""
+    """K windows in ONE serve_megakernel ring (docs/serving_pipeline.md
+    R10) are bit-identical to per-window dispatch — emit order, lane
+    planes, and recovery semantics included."""
 
-    def _run(self, waves, pipelined, bursts=True, risky_hook=False,
-             stall=None, **lam_kw):
+    def _run(self, waves, pipelined, stall=None, **lam_kw):
         emits = []
+        lam_kw.setdefault("t_buckets", (1, 4))
         lam = _lam(lambda d, m: emits.append(_emit_key(d, m)), **lam_kw)
         lam.pipelined = pipelined
-        lam.fused_bursts = bursts
-        if risky_hook:
-            lam.defer_risky_windows = True
         if stall is not None:
             lam.stall_hook = stall
         _drive(lam, waves, emits)
         return lam, emits
 
     def test_burst_bit_identical_to_sync_and_ring(self):
-        """Clean multi-flush keystroke traffic: scanned bursts must
-        reproduce the sync path EXACTLY — stream order, text, and the
-        device lane planes — and actually fuse more than one window per
-        dispatch."""
-        waves = _keystroke_waves(n_waves=10)
-        counters.reset()
+        """Clean multi-window keystroke traffic: megakernel rings must
+        reproduce the per-window (pipelined=False) run EXACTLY — stream
+        order, text, and the device lane planes — and actually carry
+        more than one window per dispatch."""
+        waves = _keystroke_waves(n_waves=10, ops=8)
         sync_lam, sync_emits = self._run(waves, pipelined=False)
-        ring_lam, ring_emits = self._run(waves, pipelined=True,
-                                         bursts=False)
         counters.reset()
-        burst_lam, burst_emits = self._run(waves, pipelined=True)
+        ring_lam, ring_emits = self._run(waves, pipelined=True)
         assert counters.get("serving.bursts") > 0
-        assert counters.get("serving.burst_windows") >= \
-            2 * counters.get("serving.bursts")
-        assert sync_emits == burst_emits  # order included
-        assert ring_emits == burst_emits
+        assert counters.get("serving.burst_windows") > \
+            counters.get("serving.bursts")
+        assert sync_emits == ring_emits  # order included
         for d in range(4):
             key = (f"k{d}", "s", "t")
             assert sync_lam.channel_text(*key) == \
-                burst_lam.channel_text(*key)
-            assert sync_lam.merge.where[key] == \
-                burst_lam.merge.where[key]
+                ring_lam.channel_text(*key)
+            assert sync_lam.merge.pages.counts[key] == \
+                ring_lam.merge.pages.counts[key]
             a = _merge_rows(sync_lam, key)
-            b = _merge_rows(burst_lam, key)
+            b = _merge_rows(ring_lam, key)
             for name in ("length", "ins_seq", "ins_client", "rem_seq",
                          "count", "min_seq", "seq"):
                 np.testing.assert_array_equal(
@@ -390,58 +461,52 @@ class TestFusedBursts:
                     err_msg=f"{key} plane {name} diverged")
 
     def test_mid_burst_overflow_quarantine(self):
-        """An insert at an impossible position (beyond the doc's
-        visible length) flags overflow on a window every fit proof
-        cleared — mid-burst, with sibling windows behind it in the SAME
-        scan. The donated degrade must quarantine the channel, void the
-        later windows' device results for it, and keep the emitted
-        stream identical to the sync path (which degrades the same
-        window the same way)."""
-        waves = _keystroke_waves(n_waves=8, bad_flush=3, bad_pos=500)
+        """An insert at an impossible position (beyond the doc's visible
+        length) flags overflow on a window no fit proof can see —
+        mid-ring, with sibling windows in the SAME megakernel. The flag
+        is sticky, so the ring's last window rolls the doc back to its
+        pre-ring view and rescues it with the whole ring's ops, exactly
+        as the per-window run rescues its own window: the emitted stream
+        and the doc's fate (rescued or opaque) match, and the healthy
+        fleet is untouched."""
+        waves = _keystroke_waves(n_waves=8, ops=8, bad_flush=3,
+                                 bad_pos=500)
+        sync_lam, sync_emits = self._run(waves, pipelined=False)
         counters.reset()
-        _, sync_emits = self._run(waves, pipelined=False)
-        sync_degrades = counters.get("sequencer.donated_overflow")
-        counters.reset()
-        burst_lam, burst_emits = self._run(waves, pipelined=True)
+        ring_lam, ring_emits = self._run(waves, pipelined=True)
         assert counters.get("serving.bursts") > 0
-        assert counters.get("sequencer.donated_overflow") > 0
-        assert counters.get("sequencer.donated_overflow") == \
-            sync_degrades
-        # The degraded channel's later rows re-applied host-side (the
-        # quarantine fixup) instead of trusting the scan's results.
-        assert counters.get("serving.ring_fixups") > 0
-        assert sync_emits == burst_emits  # order included
-        # The healthy fleet is untouched by the neighbor's degrade.
-        sync_lam, _ = self._run(waves, pipelined=False)
+        assert counters.get("serving.recovery_dispatches") > 0
+        assert ring_lam.merge.paged_rescues == sync_lam.merge.paged_rescues
+        assert ring_lam.merge.paged_rescues >= 1
+        assert ring_lam.merge.overflow_drops == \
+            sync_lam.merge.overflow_drops
+        assert sync_emits == ring_emits  # order included
         for d in range(4):
             key = (f"k{d}", "s", "t")
             assert sync_lam.channel_text(*key) == \
-                burst_lam.channel_text(*key)
-        assert (("kbad", "s", "t") in burst_lam.merge.opaque) == \
-            (("kbad", "s", "t") in sync_lam.merge.opaque)
+                ring_lam.channel_text(*key)
+        bad = ("kbad", "s", "t")
+        assert (bad in ring_lam.merge.opaque) == \
+            (bad in sync_lam.merge.opaque)
+        assert ring_lam.channel_text(*bad) == sync_lam.channel_text(*bad)
 
     def test_defer_risky_windows_forces_burst_breakup(self):
-        """The chaos hook defers hint-risky windows per-window (they
-        keep pre states for the forced rollback) — a risky window
-        landing mid-accumulation must BREAK the burst: staged windows
-        flush as their own scan, the risky window rides the ring, and
-        the stream still matches sync."""
-        waves = _deep_ragged_waves(n_waves=8, deep_ops=8)
+        """A risky window landing mid-accumulation BREAKS the ring: the
+        windows staged before it dispatch with it and drain at once, the
+        windows after it start a new ring — more rings than flushes —
+        and the stream still matches the per-window run."""
+        waves = _deep_ragged_waves(n_waves=8, deep_ops=8,
+                                   annotates={2: 1, 5: 1})
+        _, sync_emits = self._run(waves, pipelined=False)
         counters.reset()
-        _, sync_emits = self._run(
-            waves, pipelined=False,
-            merge_store=MergeLaneStore(capacities=(4, 16, 64)),
-            t_buckets=(1, 4))
-        counters.reset()
-        _, burst_emits = self._run(
-            waves, pipelined=True, risky_hook=True,
-            merge_store=MergeLaneStore(capacities=(4, 16, 64)),
-            t_buckets=(1, 4))
-        assert counters.get("serving.burst_breaks") > 0
-        assert sync_emits == burst_emits  # order included
+        _, ring_emits = self._run(waves, pipelined=True)
+        assert counters.get("serving.megakernel_rings") > len(waves)
+        assert counters.get("serving.burst_windows") > \
+            counters.get("serving.megakernel_rings")
+        assert sync_emits == ring_emits  # order included
 
     def test_faultplan_stall_during_burst_is_deterministic(self):
-        """A FaultPlan device stall firing while bursts accumulate must
+        """A FaultPlan device stall firing while rings accumulate must
         reproduce bit-identically from its seed: same fault trace
         fingerprint, same emitted stream, run twice."""
         from fluidframework_tpu.testing import faultinject
@@ -449,7 +514,7 @@ class TestFusedBursts:
         def once():
             plan = faultinject.FaultPlan(seed=1234, stall=1.0,
                                          stall_range_ms=(0.1, 0.4))
-            waves = _keystroke_waves(n_waves=8)
+            waves = _keystroke_waves(n_waves=8, ops=8)
             counters.reset()
             _, emits = self._run(
                 waves, pipelined=True,
@@ -465,31 +530,41 @@ class TestFusedBursts:
 
     def test_burst_lowering_failure_falls_back_per_window(
             self, monkeypatch):
-        """A burst scan that fails to lower (counted + logged) must
-        fall back to dispatching its windows individually — job lists
-        untouched, donated buffers intact, stream identical to sync."""
+        """A multi-window ring whose pallas phases fail to lower retries
+        the SAME ring with the scan op-phase (counted, sticky): no
+        window is lost or re-ordered, and the stream equals the
+        per-window run."""
         from fluidframework_tpu.server import serve_step
-        waves = _keystroke_waves(n_waves=8)
-        counters.reset()
+        waves = _keystroke_waves(n_waves=8, ops=8)
         _, sync_emits = self._run(waves, pipelined=False)
+        real = serve_step.serve_megakernel
+        ks = []
+
+        def refuse_pallas(tstate, pool, lww, tx, *rest):
+            if rest[-2]:  # fused modes; the scan retry passes False
+                raise RuntimeError("ring lowering refused")
+            ks.append(int(tx.shape[0]))
+            return real(tstate, pool, lww, tx, *rest)
+
+        monkeypatch.setattr(serve_step, "serve_megakernel", refuse_pallas)
         counters.reset()
-
-        def boom(*a, **k):
-            raise RuntimeError("burst lowering refused")
-
-        monkeypatch.setattr(serve_step, "serve_burst", boom)
-        _, emits = self._run(waves, pipelined=True)
-        assert counters.get("serving.burst_fallbacks") > 0
-        assert counters.get("serving.bursts") == 0
+        emits = []
+        lam = _lam(lambda d, m: emits.append(_emit_key(d, m)),
+                   t_buckets=(1, 4))
+        lam.pipelined = True
+        lam.megakernel_interpret = True
+        _drive(lam, waves, emits)
+        assert counters.get("serving.megakernel_fallbacks") == 1
+        assert max(ks) >= 2  # the retried rings carried several windows
         assert sync_emits == emits  # order included
 
     def test_occupancy_hints_count_staged_burst_windows(self):
-        """K staged/scanned windows must read as ring-fill K, not 1 —
-        the PR 6 admission controller's fill term would otherwise see a
-        long scan step as a calm, mostly-empty ring."""
-        lam = _lam()
+        """A K-window ring must read as ring-fill K, not 1 — the
+        admission controller's fill term would otherwise see a long scan
+        step as a calm, mostly-empty ring."""
+        lam = _lam(t_buckets=(1, 4))
         lam.pipelined = True
-        waves = _keystroke_waves(n_waves=6)
+        waves = _keystroke_waves(n_waves=6, ops=15)
         off = 0
         fills = []
         for wave in waves:
@@ -498,11 +573,10 @@ class TestFusedBursts:
                 off += 1
             lam.flush()
             fills.append(lam.occupancy_hints()["ring_occupancy"])
-        # Windows accumulate across flushes: fill must exceed the
-        # one-entry illusion while a multi-window burst is in flight.
-        assert max(fills) >= 3
-        assert fills == sorted(fills[:fills.index(max(fills)) + 1]) \
-            + fills[fills.index(max(fills)) + 1:]
+        # Each flush's 15 ops per doc (16 with the first flush's join)
+        # span four T=4 windows, all in flight in the one ring the next
+        # flush joins.
+        assert fills == [4] * len(waves), fills
         lam.drain()
         assert lam.occupancy_hints()["ring_occupancy"] == 0
         assert not lam._staged and not lam._ring
@@ -516,8 +590,7 @@ class TestMegakernelRing:
 
     def _run_paged(self, waves, interpret=False, **lam_kw):
         emits = []
-        lam = _lam(lambda d, m: emits.append(_emit_key(d, m)),
-                   paged_lanes=True, **lam_kw)
+        lam = _lam(lambda d, m: emits.append(_emit_key(d, m)), **lam_kw)
         lam.pipelined = True
         lam.megakernel_interpret = interpret
         _drive(lam, waves, emits)
@@ -529,12 +602,12 @@ class TestMegakernelRing:
         the same ring (retry with the scan op-phase — still one
         dispatch), count serving.megakernel_fallbacks, pin the degrade
         sticky so later rings skip the doomed mode, and leave the
-        stream identical to the bucketed engine."""
+        stream identical to the per-window (pipelined=False) run."""
         from fluidframework_tpu.server import serve_step
         waves = _keystroke_waves(n_waves=8)
         b_emits = []
-        bucketed = _lam(lambda d, m: b_emits.append(_emit_key(d, m)))
-        _drive(bucketed, waves, b_emits)
+        sync = _lam(lambda d, m: b_emits.append(_emit_key(d, m)))
+        _drive(sync, waves, b_emits)
 
         real = serve_step.serve_megakernel
 

@@ -1102,6 +1102,6 @@ class TestWholeTreeGate:
         model = ProgramContext(contexts).concurrency()
         roots = {r.root_id for r in model.roots}
         assert any("summarize_documents_async.work" in r for r in roots)
-        assert any("_dispatch_burst_chunk.fetch" in r for r in roots)
+        assert any("_dispatch_megakernel_chunk.fetch" in r for r in roots)
         assert any(r.startswith("http:") and "ServiceMonitor" in r
                    for r in roots)

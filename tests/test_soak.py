@@ -217,14 +217,11 @@ class TestServingSoak:
             _Ctx(), emit=lambda d, m: eb.append(key(d, m)),
             nack=lambda d, c, n: nb.append((d, c, n.content.code)),
             client_timeout_s=0.0)
-        # Half the trials run the fast path through the in-flight window
-        # ring (docs/serving_pipeline.md), a quarter of those forcing
-        # hint-risky windows through it (the quarantine fixup path) —
-        # random burst schedules are exactly where ring reordering or a
-        # stale-lane staging bug would surface as a diff.
+        # Half the trials run the fast path through the pipelined
+        # megakernel ring (docs/serving_pipeline.md R10) — random burst
+        # schedules are exactly where ring reordering or a stale-lane
+        # staging bug would surface as a diff.
         B.pipelined = trial % 2 == 1
-        if B.pipelined and rng.random() < 0.25:
-            B.defer_risky_windows = True
         # Runtime lockset verification (fluidlint v3's dynamic half):
         # the pipelined store runs the soak with the statically inferred
         # summarize-guard discipline asserted on every access.
@@ -279,8 +276,7 @@ class TestMeshPlacementSoak:
         mesh = make_mesh(sp=1)
         server, loader, chans = _soak_session(
             SharedString.TYPE,
-            server_cls=lambda: TpuLocalServer(mesh=mesh,
-                                              paged_lanes=True))
+            server_cls=lambda: TpuLocalServer(mesh=mesh))
         checked = 0
         for _ in range(20):
             ch = rng.choice(chans)
@@ -566,8 +562,8 @@ class TestWireFuzzSoak:
 
 @soak
 class TestMaintenanceSoak:
-    """The serving maintenance machinery (host fold, block aging,
-    payload-id collection) at its most hostile cadences — every knob at
+    """The serving maintenance machinery (page compaction, host rescue,
+    block aging, payload-id collection) at its most hostile cadences — every knob at
     minimum — interleaved with restarts and async summaries. Any
     id/lane bookkeeping slip shows up as divergence from the client
     replicas or a crash."""
@@ -586,7 +582,7 @@ class TestMaintenanceSoak:
             st.block_age_ticks = 1
             st.payload_compact_every = rng.choice((1, 2))
             st.payload_compact_min_entries = 0
-            st.fold_budget_per_tick = rng.choice((1, 4))
+            st.compact_budget_per_tick = rng.choice((1, 4))
             return st
 
         store = tune()
@@ -615,8 +611,8 @@ class TestMaintenanceSoak:
                 # restart() rebuilds the lambda with a FRESH store:
                 # bank the old one's counters and re-apply the hostile
                 # knobs to the new one.
-                activity += store.folds + store.payload_compactions \
-                    + store.blocks_aged
+                activity += store.page_compactions \
+                    + store.payload_compactions + store.blocks_aged
                 server._deli_mgr.restart()
                 store = tune()
         for th in threads:
@@ -625,7 +621,7 @@ class TestMaintenanceSoak:
         assert server.sequencer().channel_text(
             "doc", "default", "ch") == chans[0].get_text()
         # Maintenance actually exercised (not silently gated off).
-        activity += store.folds + store.payload_compactions \
+        activity += store.page_compactions + store.payload_compactions \
             + store.blocks_aged
         assert activity > 0
 

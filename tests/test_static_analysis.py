@@ -1149,10 +1149,12 @@ class TestRuntimeCounters:
 
     def test_sequencer_batched_apply_is_probed(self):
         from fluidframework_tpu.server import tpu_sequencer
-        assert isinstance(tpu_sequencer._apply_keep_batched,
-                          counters.JitRetraceProbe)
-        assert tpu_sequencer._apply_keep_batched.name == \
-            "kernel.merge_apply_batched"
+        for probe, name in (
+                (tpu_sequencer._apply_paged_probe, "kernel.paged_apply"),
+                (tpu_sequencer._apply_paged_keep_probe,
+                 "kernel.paged_apply_keep")):
+            assert isinstance(probe, counters.JitRetraceProbe)
+            assert probe.name == name
 
 
 # ---------------------------------------------------------------------------

@@ -55,6 +55,20 @@ class TestTpuServingE2E:
         assert text.get_text() == t2.get_text() == t3.get_text()
         assert "world" in text.get_text()
 
+    def test_paged_lanes_keyword_accepts_only_true(self):
+        """Merge lanes always live in the page pool: the keyword the
+        benchmark still passes is accepted as True and refused as
+        False."""
+        server = TpuLocalServer(paged_lanes=True)
+        loader, c1, ds1 = make_doc(server)
+        c1.attach()
+        text = ds1.create_channel("text", SharedString.TYPE)
+        text.insert_text(0, "paged")
+        assert server.sequencer().channel_text(
+            "doc", "default", "text") == "paged"
+        with pytest.raises(ValueError):
+            TpuLocalServer(paged_lanes=False)
+
     def test_server_materializes_document_state_on_device(self):
         """The serving win: the sequencer's device merge lanes hold the
         authoritative document text, byte-equal to every client replica."""
@@ -386,7 +400,7 @@ class TestBatchedSummarization:
         return server, texts
 
     def test_batched_extraction_matches_live_text(self):
-        """One device pass per bucket reproduces every document's text."""
+        """One device pass per page group reproduces every document's text."""
         server, texts = self._server_with_text()
         snaps = server.sequencer().summarize_documents()
         for doc, t in texts.items():
@@ -445,13 +459,88 @@ class TestBatchedSummarization:
         assert t.get_text() != frozen
 
 
+def _annotate_burst(builder, start, end, ref, seq, n=6, client=0,
+                    msn=None):
+    """n annotates over one range in ONE window: more than the row's
+    annotate ring holds (DEFAULT_ANNO_SLOTS=4), so the apply overflows
+    and the lane takes the host rescue (MergeLaneStore._rescue_paged).
+    Returns (ops, last seq)."""
+    ops = []
+    for i in range(n):
+        seq += 1
+        ops.append(builder.annotate(start, end, {f"k{i}": seq}, ref,
+                                    client, seq,
+                                    msn=ref if msn is None else msn))
+    return ops, seq
+
+
+def _raw_typing_run(flushes, per_flush, seed, lam_setup=None):
+    """Single-client typing at random positions through the native
+    pump's fast path (handler_raw -> the serving megakernel). Returns
+    (lambda, expected text)."""
+    import json as _json
+
+    from fluidframework_tpu.mergetree.client import OP_INSERT
+    from fluidframework_tpu.server import pump as pump_mod
+    from fluidframework_tpu.server.log import QueuedMessage
+    from fluidframework_tpu.server.tpu_sequencer import TpuSequencerLambda
+    from fluidframework_tpu.server.wire import boxcar_to_wire
+
+    if not pump_mod.available():
+        pytest.skip("native wirepump unavailable")
+
+    class Ctx:
+        def checkpoint(self, *_):
+            pass
+
+        def error(self, err, restart=False):
+            raise err
+
+    lam = TpuSequencerLambda(Ctx(), emit=lambda *a: None,
+                             nack=lambda *a: None, client_timeout_s=0.0)
+    if lam_setup is not None:
+        lam_setup(lam)
+    rng = random.Random(seed)
+    expect = ""
+    csn = 0
+    for f in range(flushes):
+        msgs = [] if f else [DocumentMessage(
+            0, -1, MessageType.CLIENT_JOIN,
+            data=_json.dumps({"clientId": "c0", "detail": {}}))]
+        for _ in range(per_flush):
+            csn += 1
+            pos = rng.randrange(len(expect) + 1)
+            txt = f"b{csn % 10}"
+            expect = expect[:pos] + txt + expect[pos:]
+            msgs.append(DocumentMessage(
+                client_sequence_number=csn,
+                reference_sequence_number=csn - 1,
+                type=MessageType.OPERATION,
+                contents={"address": "s", "contents": {
+                    "address": "t", "contents": {
+                        "type": OP_INSERT, "pos1": pos,
+                        "seg": {"text": txt}}}}))
+        lam.handler_raw(QueuedMessage(
+            topic="rawdeltas", partition=0, offset=f, key="doc",
+            value=boxcar_to_wire(Boxcar("t", "doc", "c0", msgs))))
+        lam.flush()
+    lam.drain()
+    return lam, expect
+
+
 class TestHostFold:
-    """The serving zamboni pack (MergeLaneStore._fold_crowded): acked
-    adjacent rows coalesce host-side so long-lived documents stay in the
-    small fast buckets instead of climbing capacities whose apply cost
-    scales with C (reference mergeTree.ts:1289 scour/pack)."""
+    """The paged lane's host paths: an annotate-ring overflow rolls the
+    document back and folds it on the host (MergeLaneStore.
+    _rescue_paged — acked runs coalesce, rings resolve into props) into
+    exactly the pages it needs, and the compaction tick
+    (_compact_tick_paged) left-packs fragmented documents and returns
+    the emptied pages (reference mergeTree.ts:1289 scour/pack)."""
 
     def test_sustained_typing_stays_in_small_bucket(self):
+        """Sustained typing grows a lane by appending pages, and only as
+        many as its live rows need: trailing pages release at every
+        apply, so the page table never pads past the last live row."""
+        from fluidframework_tpu.mergetree.paging import pages_for
         server = TpuLocalServer()
         loader, c1, ds1 = make_doc(server)
         c1.attach()
@@ -461,15 +550,15 @@ class TestHostFold:
             pos = rng.randrange(text.get_length() + 1)
             text.insert_text(pos, f"x{i % 10}")
         store = server.sequencer().merge
+        pg = store.pages
         key = ("doc", "default", "text")
-        b, _ = store.where[key]
-        fold_b = store.capacities.index(store.fold_min_capacity)
-        assert store.folds > 0, "fold never fired"
-        assert b <= fold_b, (
-            f"folded lane should never pass the fold bucket {fold_b}, "
-            f"got {b}")
+        assert len(pg.tables[key]) > 1, "lane never grew a page"
+        assert len(pg.tables[key]) == pages_for(pg.counts[key],
+                                                pg.page_rows)
+        assert pg.page_fill_frac() > 0.5
+        assert key not in store.opaque and store.overflow_drops == 0
         assert server.sequencer().channel_text(*key) == text.get_text()
-        # Ops after a fold must resolve positions against the packed rows.
+        # Ops keep resolving positions against the grown lane.
         for i in range(40):
             pos = rng.randrange(text.get_length() + 1)
             if text.get_length() > 10 and rng.random() < 0.4:
@@ -478,6 +567,8 @@ class TestHostFold:
             else:
                 text.insert_text(pos, "Y")
         assert server.sequencer().channel_text(*key) == text.get_text()
+        assert len(pg.tables[key]) == pages_for(pg.counts[key],
+                                                pg.page_rows)
 
     def test_fold_preserves_props_and_segmentation_boundaries(self):
         server = TpuLocalServer()
@@ -490,9 +581,17 @@ class TestHostFold:
             if i % 7 == 0 and text.get_length() > 8:
                 start = rng.randrange(text.get_length() - 6)
                 text.annotate_range(start, start + 4, {"b": i % 3})
+            if i == 130:
+                # One window of annotates over one span exhausts its
+                # ring: the lane takes the host rescue mid-document.
+                server.auto_pump = False
+                for j in range(6):
+                    text.annotate_range(3, 9, {f"r{j}": j})
+                server.auto_pump = True
+                server.pump()
         store = server.sequencer().merge
         key = ("doc", "default", "text")
-        assert store.folds > 0
+        assert store.paged_rescues > 0, "rescue never fired"
         assert server.sequencer().channel_text(*key) == text.get_text()
         # The materialized snapshot must carry identical (text, props)
         # runs to the client replica's own snapshot.
@@ -515,40 +614,40 @@ class TestHostFold:
         assert flat(server_runs) == flat(client_runs)
 
     def test_fold_frees_superseded_payload_generation(self):
-        """Each fold re-seeds the lane with fresh payload ids; the
+        """Each rescue re-seeds the lane with fresh payload ids; the
         previous generation (including the whole-document folded string)
         must return to the PayloadTable free-list — otherwise a
-        long-lived document retains O(doc_size x folds) dead strings."""
+        long-lived document retains O(doc_size x rescues) dead
+        strings."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        store = MergeLaneStore(capacities=(8, 64), lanes_per_bucket=1)
-        store.fold_min_capacity = 64
+        store = MergeLaneStore()
+        b = store.builder
         key = ("d", "s", "t")
         seq = 0
 
-        def drive(batches, txt):
+        def drive(txt):
             nonlocal seq
-            for _ in range(batches):
-                ops = []
-                for _ in range(6):
-                    seq += 1
-                    ops.append(store.builder.insert_text(
-                        0, txt, seq - 1, 0, seq, msn=seq - 1))
-                store.apply({key: ops})
+            ops = []
+            for _ in range(6):
+                seq += 1
+                ops.append(b.insert_text(0, txt, seq - 1, 0, seq,
+                                         msn=seq - 1))
+            burst, seq = _annotate_burst(b, 0, 2, seq, seq)
+            store.apply({key: ops + burst})
 
-        drive(12, "ab")
-        assert store.folds >= 1, "fold never fired"
-        assert store.fold_rows_reclaimed > 0
+        drive("ab")
+        assert store.paged_rescues == 1, "rescue never fired"
         gen1 = list(store._fold_payloads[key])
         freed = []
         orig_free = store.payloads.free
         store.payloads.free = lambda i: (freed.append(i), orig_free(i))
-        drive(12, "cd")
-        assert store.folds >= 2
+        drive("cd")
+        assert store.paged_rescues == 2
         gen2 = set(store._fold_payloads[key])
         assert gen2 != set(gen1)
-        # Every gen1 id was freed by the next fold (or carried forward).
+        # Every gen1 id was freed by the next rescue (or carried forward).
         assert set(gen1) <= set(freed) | gen2, (gen1, freed, gen2)
-        assert store.text(key) == "cd" * 72 + "ab" * 72
+        assert store.text(key) == "cd" * 6 + "ab" * 6
 
     def test_inline_fold_equivalence_and_non_ascii_arena(self):
         """extract_entries(fold=True) must equal
@@ -575,8 +674,7 @@ class TestHostFold:
                     text.annotate_range(start, start + 3, {"k": i % 2})
             store = server.sequencer().merge
             key = ("doc", "default", "text")
-            b, lane = store.where[key]
-            row = _jax.device_get(store.buckets[b].row(lane))
+            row = _jax.device_get(store.pages.row(key))
             mseq = int(row.min_seq)
             folded = extract_entries(row, store.payloads, mseq, fold=True)
             perrow = coalesce_entries(
@@ -588,9 +686,10 @@ class TestHostFold:
 
     def test_payload_id_compaction_renumbers_and_shrinks(self):
         """Major collection: the payload-table LIST grows one slot per
-        ingested op; compact_payload_ids must renumber the live ids,
-        shrink the table to live size, and leave every read path and
-        subsequent editing exact."""
+        ingested op; once removed rows leave the pages (zamboni),
+        compact_payload_ids must renumber the live ids, shrink the table
+        to live size, and leave every read path and subsequent editing
+        exact."""
         server = TpuLocalServer()
         loader, c1, ds1 = make_doc(server)
         c1.attach()
@@ -599,7 +698,11 @@ class TestHostFold:
         for i in range(500):
             pos = rng.randrange(text.get_length() + 1)
             text.insert_text(pos, f"p{i % 10}")
+        text.remove_text(0, text.get_length() - 20)
+        for i in range(4):  # the client's refs move the MSN past it
+            text.insert_text(0, "m")
         store = server.sequencer().merge
+        store.compact_all()
         before = len(store.payloads.entries)
         assert store.compact_payload_ids()
         after = len(store.payloads.entries)
@@ -607,7 +710,7 @@ class TestHostFold:
         assert not store._blocks and not store._lane_blocks
         key = ("doc", "default", "text")
         assert server.sequencer().channel_text(*key) == text.get_text()
-        # Renumbered generation tracking still frees on the next fold.
+        # Renumbered generation tracking still frees on the next rescue.
         gen = store._fold_payloads.get(key)
         assert gen is None or all(i < after for i in gen)
         for i in range(200):  # editing continues exactly post-renumber
@@ -632,13 +735,12 @@ class TestHostFold:
 
     def test_fold_preserves_overlap_removers(self):
         """Overlap-remove clients (rem_clients slots 1+) must survive the
-        fold's extract->reseed cycle: an op from the SECOND remover at a
-        ref below the first remove's seq must still see the segment as
+        rescue's extract->reseed cycle: an op from the SECOND remover at
+        a ref below the first remove's seq must still see the segment as
         removed — losing the overlap shifts its positions and diverges
         the lane from the clients."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        store = MergeLaneStore(capacities=(64,), lanes_per_bucket=1)
-        store.fold_min_capacity = 64
+        store = MergeLaneStore()
         key = ("d", "s", "t")
         b = store.builder
         # 50 acked rows below the window: "ABCDEFGH" + 49 tail fillers.
@@ -653,22 +755,14 @@ class TestHostFold:
         removed = [e for e in store.entries(key) if "removedSeq" in e]
         assert removed and removed[0].get("removedOverlapClients") == [2], \
             removed
-        # Crowd past capacity with in-window fillers appended at the
-        # inserting client's view end (client 0 at ref 50 still sees
-        # CDE): the overflow fold packs the 50 acked rows while the
-        # removed row stays in-window.
-        seq = 52
-        vlen = 8 + 49  # client-0 view length at ref 50
-        while store.folds == 0:
-            chunk = []
-            for _ in range(6):
-                seq += 1
-                chunk.append(b.insert_text(vlen, "z", 50, 0, seq, msn=50))
-                vlen += 1
-            store.apply({key: chunk})
-            assert seq < 600, "fold never fired"
+        # Client 0 (at ref 50 it still sees CDE) annotates "AB" past the
+        # ring's depth in one window: the rescue reseeds the whole lane
+        # while the removed row stays in-window.
+        burst, seq = _annotate_burst(b, 0, 2, 50, 52, msn=50)
+        store.apply({key: burst})
+        assert store.paged_rescues >= 1, "rescue never fired"
         # Client 2 edits at ref 50 (below both removes): it must see
-        # [2,5) as removed (its own remove survived the fold), so its
+        # [2,5) as removed (its own remove survived the rescue), so its
         # view is AB+FGH... and view-pos 3 lands after F — not inside
         # the tombstoned CDE.
         seq += 1
@@ -678,22 +772,20 @@ class TestHostFold:
 
     def test_collection_defers_during_chunked_apply(self):
         """A single apply() with a stream longer than the largest
-        T-bucket chunks into successive windows whose compact ticks
-        could hit the collection cadence — renumbering then would
-        corrupt the un-applied tail's op_ids (reproduced as IndexError
-        pre-fix). The collection must wait for the apply to finish."""
+        T-bucket chunks into one scanned burst whose compact tick could
+        hit the collection cadence — renumbering then would corrupt an
+        un-applied tail's op_ids. The collection must wait for the apply
+        to finish."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        store = MergeLaneStore(capacities=(8, 64, 1024),
-                               lanes_per_bucket=1,
-                               t_buckets=(1, 4, 16, 64))
-        store.fold_min_capacity = 64
-        store.compact_every = 1          # tick at every window
+        store = MergeLaneStore(t_buckets=(1, 4, 16, 64))
+        store.compact_every = 1          # tick at every apply
         store.payload_compact_every = 1  # collection eligible every tick
         store.payload_compact_min_entries = 0
         key = ("d", "s", "t")
         ops = [store.builder.insert_text(0, "xy", s, 0, s + 1, msn=s)
                for s in range(300)]      # >> max_t=64: many chunks
         store.apply({key: ops})          # must not crash nor corrupt
+        assert store.payload_compactions == 0, "collected mid-apply"
         assert store.text(key) == "xy" * 300
         # At the next safe boundary the collection still runs.
         assert store.compact_payload_ids() is True
@@ -701,70 +793,66 @@ class TestHostFold:
 
     def test_extract_guard_defers_frees_and_collection(self):
         """While an async summary worker may still resolve the shared
-        payload table, fold frees must defer (a recycled id would
+        payload table, rescue frees must defer (a recycled id would
         materialize the WRONG text into the in-flight snapshot) and the
         major collection must refuse to renumber; both proceed after
         release."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        store = MergeLaneStore(capacities=(8, 64), lanes_per_bucket=1)
-        store.fold_min_capacity = 64
+        store = MergeLaneStore()
+        b = store.builder
         key = ("d", "s", "t")
         seq = 0
 
-        def drive(batches):
+        def drive():
             nonlocal seq
-            for _ in range(batches):
-                ops = []
-                for _ in range(6):
-                    seq += 1
-                    ops.append(store.builder.insert_text(
-                        0, "ab", seq - 1, 0, seq, msn=seq - 1))
-                store.apply({key: ops})
+            ops = []
+            for _ in range(6):
+                seq += 1
+                ops.append(b.insert_text(0, "ab", seq - 1, 0, seq,
+                                         msn=seq - 1))
+            burst, seq = _annotate_burst(b, 0, 2, seq, seq)
+            store.apply({key: ops + burst})
 
-        drive(12)
-        assert store.folds >= 1
+        drive()
+        assert store.paged_rescues >= 1
         store.extract_guard_acquire()
         # Snapshot content under guard (what the async worker reads).
         text_before = store.text(key)
         assert store.compact_payload_ids() is False, \
             "collection must defer under an extract guard"
-        drive(12)  # folds fire; their frees must defer, not recycle
-        assert store._deferred_frees, "fold frees should have deferred"
-        assert store.text(key) == "ab" * 144
+        drive()  # the rescue fires; its frees must defer, not recycle
+        assert store._deferred_frees, "rescue frees should have deferred"
+        assert store.text(key) == "ab" * 12
         store.extract_guard_release()
         assert store.compact_payload_ids() is True
         assert not store._deferred_frees  # table rebuilt wholesale
-        drive(2)  # editing continues exactly post-release+renumber
-        assert store.text(key) == "ab" * 156
-        assert text_before == "ab" * 72
+        drive()  # editing continues exactly post-release+renumber
+        assert store.text(key) == "ab" * 18
+        assert text_before == "ab" * 6
 
     def test_arena_blocks_age_out(self):
         """Fast-path arena blocks pin the flush's raw wire buffers; once
-        every referencing lane folds (or the block ages), the registry
+        they age past block_age_ticks compaction ticks, the registry
         must let them go — a long-lived server must not retain its whole
         raw ingest history in host memory."""
-        server = TpuLocalServer()
-        loader, c1, ds1 = make_doc(server)
-        c1.attach()
-        text = ds1.create_channel("text", SharedString.TYPE)
-        store = server.sequencer().merge
-        store.block_age_ticks = 2  # age fast for the test
-        rng = random.Random(31)
-        for i in range(600):
-            pos = rng.randrange(text.get_length() + 1)
-            text.insert_text(pos, f"b{i % 10}")
+        def setup(lam):
+            lam.merge.block_age_ticks = 2  # age fast for the test
+
+        lam, expect = _raw_typing_run(flushes=60, per_flush=10, seed=31,
+                                      lam_setup=setup)
+        store = lam.merge
         live = len(store._blocks)
-        assert store.folds > 0 or store.blocks_aged > 0
-        # Registry stays bounded: folds release refs and aging drains
-        # stragglers, so live blocks ~ the last few compact windows.
+        assert store.blocks_aged > 0
+        # Registry stays bounded: aging drains blocks past their ticks,
+        # so live blocks ~ the last few compact windows.
         assert live <= store.block_age_ticks * store.compact_every + 4, live
-        key = ("doc", "default", "text")
-        assert server.sequencer().channel_text(*key) == text.get_text()
+        key = ("doc", "s", "t")
+        assert lam.channel_text(*key) == expect
         # Content survives aging: materialized payloads resolve the same.
         snap = store.extract_all()[key]
         joined = "".join(e.get("text", "") for chunk in snap["chunks"]
                          for e in chunk if e.get("removedSeq") is None)
-        assert joined == text.get_text()
+        assert joined == expect
 
     def test_fold_survives_restart(self):
         server = TpuLocalServer()
@@ -775,7 +863,12 @@ class TestHostFold:
         for i in range(300):
             pos = rng.randrange(text.get_length() + 1)
             text.insert_text(pos, f"z{i % 10}")
-        assert server.sequencer().merge.folds > 0
+        server.auto_pump = False
+        for j in range(6):
+            text.annotate_range(0, 5, {f"r{j}": j})
+        server.auto_pump = True
+        server.pump()
+        assert server.sequencer().merge.paged_rescues > 0
         server._deli_mgr.restart()  # rebuild from checkpoint + log replay
         for i in range(40):
             pos = rng.randrange(text.get_length() + 1)
@@ -786,45 +879,45 @@ class TestHostFold:
 
 class TestOverflowRecovery:
     def test_lane_promotes_through_buckets(self):
-        """A document that outgrows its capacity bucket mid-batch recovers
-        by compaction/promotion with no flag leaks and correct text
+        """A document that outgrows its first page mid-batch grows by
+        APPENDING pages — rows never move, so the early page ids stay a
+        prefix of the table — with no overflow and correct text
         (SURVEY.md §7 hard parts 1/3)."""
         server = TpuLocalServer()
-        # Pin the host fold off: this test exercises the overflow
-        # recovery/promotion cascade specifically, and with folding on a
-        # single-client acked stream packs at the fold bucket forever
-        # (that behavior has its own tests in TestHostFold).
-        server.sequencer().merge.FOLD_NUM = 10 ** 9
-        server.sequencer().merge.fold_min_capacity = 10 ** 9
         loader, c1, ds1 = make_doc(server)
         c1.attach()
         text = ds1.create_channel("text", SharedString.TYPE)
+        store = server.sequencer().merge
+        key = ("doc", "default", "text")
         rng = random.Random(3)
-        # Interleave inserts at random positions: splits force segment-count
-        # growth far past the first bucket (64).
+        early = None
+        # Interleave inserts at random positions: splits force
+        # segment-count growth far past the first page (64 rows).
         for i in range(300):
             pos = rng.randrange(text.get_length() + 1)
             text.insert_text(pos, f"x{i % 10}")
-        store = server.sequencer().merge
-        key = ("doc", "default", "text")
-        b, lane = store.where[key]
-        assert b > 0, "lane never promoted past the first capacity bucket"
-        assert not bool(np.asarray(
-            store.buckets[b].state.overflow)[lane]), "overflow flag leaked"
+            if i == 20:
+                early = list(store.pages.tables[key])
+        table = store.pages.tables[key]
+        assert len(table) > len(early) >= 1, "lane never grew a page"
+        assert table[:len(early)] == early
+        assert key not in store.opaque and store.overflow_drops == 0
         assert server.sequencer().channel_text(*key) == text.get_text()
 
     def test_freed_merge_lane_zeroed_before_reuse(self):
-        """A freed lane (drop/promotion) hands CLEAN state to the next
-        channel that allocates it — the previous channel's segments must
-        not leak into the new channel's materialization."""
+        """A freed lane's pages (drop) hand CLEAN rows to the next
+        channel that allocates them — the previous channel's segments
+        must not leak into the new channel's materialization."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
-        store = MergeLaneStore(capacities=(8,), lanes_per_bucket=1)
+        store = MergeLaneStore()
         a, b = ("d", "s", "a"), ("d", "s", "b")
         store.apply({a: [store.builder.insert_text(0, "SECRET", 0, 0, 1)]})
         assert store.text(a) == "SECRET"
-        store.drop(a)  # degraded: lane freed
+        pages_a = list(store.pages.tables[a])
+        store.drop(a)  # degraded: pages freed
         store.apply({b: [store.builder.insert_text(0, "clean", 0, 0, 2)]})
-        assert store.where[b] == (0, 0), "expected the recycled lane"
+        assert store.pages.tables[b][0] in pages_a, \
+            "expected the recycled page"
         assert store.text(b) == "clean"
         snap = store.extract_all()[b]
         joined = "".join(e.get("text") or ""
@@ -848,8 +941,9 @@ class TestOverflowRecovery:
         assert store.snapshot(b)["entries"] == {"mine": "v"}
 
     def test_compaction_avoids_promotion_for_transient_growth(self):
-        """Insert/remove churn inside the collab window stays in-bucket via
-        zamboni compaction (tombstones freed once min_seq passes)."""
+        """Insert/remove churn inside the collab window stays within one
+        page via zamboni compaction (tombstones freed once min_seq
+        passes)."""
         server = TpuLocalServer()
         loader, c1, ds1 = make_doc(server)
         c1.attach()
@@ -860,9 +954,9 @@ class TestOverflowRecovery:
         store = server.sequencer().merge
         store.compact_all()
         key = ("doc", "default", "text")
-        b, lane = store.where[key]
-        count = int(np.asarray(store.buckets[b].state.count)[lane])
+        count = store.pages.counts[key]
         assert count <= 4, f"zamboni left {count} live segments"
+        assert len(store.pages.tables[key]) == 1
         assert text.get_text() == ""
 
 
@@ -1070,29 +1164,32 @@ class TestSnapshotSeededLanes:
         assert server.sequencer().channel_text(
             "snap-doc", "default", "text") == text.get_text()
 
-    def test_bucket_exhaustion_degrades_to_opaque_not_crash(self):
-        """A channel that outgrows the LARGEST capacity bucket loses its
+    def test_bucket_exhaustion_degrades_to_opaque_not_crash(
+            self, monkeypatch):
+        """A channel whose host rescue cannot hold its content loses its
         server-side materialization (opaque) but sequencing continues for
         it and for every other document — no partition pump crash.
 
-        A SECOND connected client that never advances its refSeq pins the
-        MSN at its join, so every segment stays contended (inside the
-        collab window) — the host-fold rescue cannot coalesce contended
-        rows, making exhaustion genuine. (Acked single-client growth is
-        now RESCUED by the fold instead: TestAnnotateRingRescue.)"""
-        from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
+        Pages grow without bound; the one limit a paged lane can pass is
+        the rescue's capacity ladder (catchup.CAPACITY_BUCKETS), shrunk
+        here so passing it is cheap. Every insert carries its own props,
+        so the rescue's fold cannot coalesce the rows, making exhaustion
+        genuine; one window of annotates past the ring's depth then
+        forces the rescue. (Content that folds small survives:
+        TestAnnotateRingRescue.)"""
+        from fluidframework_tpu.mergetree import catchup
+        monkeypatch.setattr(catchup, "CAPACITY_BUCKETS", (16, 32))
         server = TpuLocalServer()
-        # Shrink the buckets so exhaustion is cheap to reach.
-        server.sequencer().merge = MergeLaneStore(capacities=(4, 8))
         loader, c1, ds1 = make_doc(server, "grow-doc")
         text = ds1.create_channel("text", SharedString.TYPE)
         c1.attach()
-        # The MSN-pinning laggard: joins, then never sends another ref.
-        stalled = Loader(
-            LocalDocumentServiceFactory(server)).resolve("grow-doc")
-        stalled.delta_manager.disconnect = lambda: None  # keep it joined
-        for i in range(30):  # far beyond 8 segment slots
-            text.insert_text(0, f"{i},")
+        for i in range(40):  # distinct rows far beyond the 32 ladder
+            text.insert_text(0, f"{i},", {"n": i})
+        server.auto_pump = False
+        for j in range(6):
+            text.annotate_range(0, 4, {f"k{j}": j})
+        server.auto_pump = True
+        server.pump()
         assert server.sequencer().merge.overflow_drops >= 1
         assert server.sequencer().channel_text(
             "grow-doc", "default", "text") is None
@@ -1205,9 +1302,9 @@ class TestSnapshotSeededLanes:
         assert k2.value == 3_000_000_005
 
     def test_mass_overflow_batch_promotes_all_lanes(self):
-        """A burst overflowing MANY lanes at once recovers via the batched
-        compact->rerun->group-promote path with identical results to the
-        per-lane recovery."""
+        """A burst growing MANY lanes past their first page at once
+        appends their pages in the same batched apply — no recovery, no
+        drop — and every lane materializes its client's text."""
         server = TpuLocalServer()
         loader, c1, ds1 = make_doc(server, "burst")
         texts = [ds1.create_channel(f"t{i}", SharedString.TYPE)
@@ -1215,7 +1312,7 @@ class TestSnapshotSeededLanes:
         c1.attach()
         server.auto_pump = False
         for i, tx in enumerate(texts):
-            for j in range(80):  # ~80+ segments: overflows the 64 bucket
+            for j in range(80):  # ~80+ segments: past one 64-row page
                 tx.insert_text(0, f"{i}.{j},")
         server.auto_pump = True
         server.pump()
@@ -1224,8 +1321,8 @@ class TestSnapshotSeededLanes:
         for i, tx in enumerate(texts):
             mat = sq.channel_text("burst", "default", f"t{i}")
             assert mat == tx.get_text(), f"t{i}"
-            b, _lane = sq.merge.where[("burst", "default", f"t{i}")]
-            assert sq.merge.capacities[b] > 64  # promoted out of bucket 0
+            key = ("burst", "default", f"t{i}")
+            assert len(sq.merge.pages.tables[key]) > 1  # past one page
 
 
 class TestKeystrokeTraceStress:
@@ -1273,7 +1370,7 @@ class TestAnnotateRingRescue:
         """>anno_slots annotates accumulating on one span across flushes
         overflow the per-segment ring; capacity promotion can't widen
         rings, so the lane must take the host-fold rescue
-        (MergeLaneStore._rescue_lane) instead of going opaque."""
+        (MergeLaneStore._rescue_paged) instead of going opaque."""
         server = TpuLocalServer()
         loader, c1, ds1 = make_doc(server, "anno")
         t = ds1.create_channel("text", SharedString.TYPE)

@@ -399,7 +399,7 @@ class TestPipelinedDrain:
     def test_pipelined_recovery_still_converges(self):
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
         B = _lam(lambda *a: None, lambda *a: None,
-                 merge_store=MergeLaneStore(capacities=(4, 16, 64)))
+                 merge_store=MergeLaneStore(page_rows=4))
         B.pipelined = True
         csn = 0
         for w in range(4):
@@ -412,7 +412,8 @@ class TestPipelinedDrain:
                               raw=True))
             B.flush()
         B.drain()
-        assert B.merge.where[("pp", "s", "t")][0] > 0  # promoted
+        # The lane grew page by page across the deferred flushes.
+        assert len(B.merge.pages.tables[("pp", "s", "t")]) > 1
         assert B.channel_text("pp", "s", "t") == "".join(
             f"{i%10}" for i in range(24, 0, -1))
 
@@ -482,10 +483,13 @@ class TestInternSyncAcrossPaths:
 
 class TestFastOverflowRecovery:
     def test_promotion_through_buckets_on_fast_path(self):
+        """A lane that outgrows its page mid-flush grows by appending
+        pages before the megakernel dispatches (flush_lane_for), so the
+        fast path needs no overflow recovery for growth."""
         from fluidframework_tpu.server.tpu_sequencer import MergeLaneStore
         eb = []
         B = _lam(lambda d, m: eb.append(1), lambda *a: None,
-                 merge_store=MergeLaneStore(capacities=(4, 16, 64)))
+                 merge_store=MergeLaneStore(page_rows=4))
         msgs = [_join("c0")]
         for i in range(1, 25):
             msgs.append(_merge_op(i, {"type": OP_INSERT, "pos1": 0,
@@ -495,8 +499,8 @@ class TestFastOverflowRecovery:
         B.flush()
         key = ("grow", "s", "t")
         assert key in B.merge.where
-        b, lane = B.merge.where[key]
-        assert b > 0, "lane never promoted"
+        assert len(B.merge.pages.tables[key]) > 1, "lane never grew"
+        assert B.merge.paged_rescues == 0 and not B.merge.opaque
         text = B.channel_text("grow", "s", "t")
         assert text == "".join(f"{i%10}" for i in range(24, 0, -1))
 
@@ -605,8 +609,8 @@ class TestPumpRestart:
 
 class TestFusedServeConformance:
     def test_fused_window_matches_scan_window(self, monkeypatch):
-        """serve_window(fused=True) — the VMEM-resident merge apply on
-        the serving fast path — is bit-indistinguishable from the scan
+        """serve_megakernel(fused=True) — the VMEM-resident merge apply
+        on the serving fast path — is bit-indistinguishable from the scan
         kernel: same emits, same materialized channels. CPU runs the
         Pallas body in interpret mode; on TPU the same test exercises
         Mosaic via tools/tpu_conformance."""
@@ -671,6 +675,7 @@ class TestNarrowResultPacking:
         stamp exact msns."""
         import jax.numpy as jnp
 
+        from fluidframework_tpu.mergetree.paging import PagedMergeStore
         from fluidframework_tpu.server import serve_step
         from fluidframework_tpu.server import ticket_kernel as tk
 
@@ -693,9 +698,13 @@ class TestNarrowResultPacking:
         cols[1, 0] = [7, 7]
         cols[2, 0] = [6, 7]
         cols[3, 0] = [4, 49_000]
-        out = serve_step.serve_window(tstate, jnp.asarray(cols),
-                                      [], [], [], [], False)
-        _, _, _, flat16, msn32 = out
+        # A one-window megakernel ring with no page groups or LWW
+        # buckets: just the ticket pass and the narrow packing.
+        pool = PagedMergeStore(pages=2).pool
+        out = serve_step.serve_megakernel_keep(
+            tstate, pool, (), jnp.asarray(cols)[None], (), (), (), (),
+            (), (), (), False, False)
+        flat16, msn32 = out[3][0], out[4][0]
         flat = np.asarray(flat16)
         bt = B * T
         p = 3 * bt
@@ -794,7 +803,7 @@ class TestServingRunPacking:
 
 class TestFusedRunsServeConformance:
     def test_fused_runs_window_matches_scan(self, monkeypatch):
-        """serve_window with fused=True AND run-packed bursts: the Mosaic
+        """serve_megakernel with fused=True AND run-packed bursts: the Mosaic
         INSERT_RUN variant path (interpret mode on CPU) must match the
         scan+runs path message-for-message and byte-for-byte."""
         import functools
@@ -848,11 +857,14 @@ class TestFusedRunsServeConformance:
 class TestFusedDegrade:
     def test_lowering_failure_degrades_in_policy_order(self, monkeypatch):
         """A fused-path failure at a production shape degrades without
-        data loss: runs windows drop PACKING first, and if fused still
-        fails, the lane falls to the scan path — same results as the
-        object oracle either way."""
+        data loss: the megakernel retries the same ring with the scan
+        op-phase (counted serving.megakernel_fallbacks), the fused mode
+        stays off from then on, and run packing — which the scan
+        op-phase serves too — stays on; same results as the object
+        oracle."""
         from fluidframework_tpu.mergetree import pallas_apply
         from fluidframework_tpu.server import serve_step
+        from fluidframework_tpu.telemetry import counters
 
         def boom(*a, **k):
             raise RuntimeError("mosaic says no")
@@ -861,8 +873,11 @@ class TestFusedDegrade:
         # Earlier tests may have CACHED fused traces for these shapes —
         # a cache hit would skip tracing and never call the patched
         # function, making this test order-dependent.
-        if hasattr(serve_step.serve_window, "clear_cache"):
-            serve_step.serve_window.clear_cache()
+        for fn in (serve_step.serve_megakernel,
+                   serve_step.serve_megakernel_keep):
+            if hasattr(fn, "clear_cache"):
+                fn.clear_cache()
+        fallbacks = counters.get("serving.megakernel_fallbacks")
 
         def burst(doc, cid, k=10):
             msgs = [_join(cid)]
@@ -893,6 +908,8 @@ class TestFusedDegrade:
         B.flush()
         A.drain()
         B.drain()
-        assert B.pack_runs is False, "packing should drop first"
-        assert B._fused_serve is False, "then fused forfeits"
+        assert counters.get("serving.megakernel_fallbacks") - \
+            fallbacks == 1
+        assert B._fused_serve is False, "fused forfeits, sticky"
+        assert B.pack_runs is True, "the scan op-phase keeps packing"
         assert_equivalent(A, B, (ea, eb), (na, nb), [("d0", "s", "t")])
