@@ -41,8 +41,11 @@ from .host import (
     OpBuilder,
     PayloadTable,
     PENDING_ORDER_BASE,
+    chunk_ends,
+    segment_props,
 )
 from .oppack import HostOp, PackedOps, pack_single
+from .runs import Run, encode_payload
 from .state import DocState, make_state
 
 # Merge-tree wire op types (client.py, reference ops.ts:29).
@@ -108,7 +111,6 @@ def wire_to_host_ops(builder: OpBuilder, op: dict, seq: int, ref_seq: int,
             # so — unlike items — they are modelable on the SERVER path
             # too (reference permutationvector.ts:126 PermutationVector
             # extends Client).
-            from .runs import Run
             return [builder.insert_text(op["pos1"], Run.decode(seg["run"]),
                                         ref_seq, client, seq, msn=msn)]
         raise Unmodelable("insert payload is not text/marker/items")
@@ -173,7 +175,6 @@ def seed_host_cols(entries: Sequence[dict], payloads: PayloadTable,
     # full [capacity, anno_slots] host round-trip per seed otherwise.
     anno = np.full((n, anno_slots), -1, np.int32) if pending_ids else None
     from .oracle import Items
-    from .runs import Run
     try:
         _seed_fill(entries, payloads, cols, rem_client, anno, anno_slots,
                    pending_ids, added, allow_runs, allow_items,
@@ -440,7 +441,6 @@ def coalesce_entries(entries: Sequence[dict]) -> List[dict]:
     without it a keystroke-granularity tail fragments the row space one
     char per op and outgrows every capacity bucket."""
     from .oracle import Items
-    from .runs import Run
 
     out: List[dict] = []
     for e in entries:
@@ -642,42 +642,48 @@ def _col_from_b64(data: str, dtype, n: int) -> np.ndarray:
     return arr
 
 
-def _pack_seq_col(entries: Sequence[dict], field: str, base_seq: int):
-    """One seq-family column: int16 delta vs base_seq, _NARROW_ABSENT for
-    entries without the field, escapes for deltas past the int16 window."""
-    n = len(entries)
-    col = np.full(n, _NARROW_ABSENT, np.int16)
-    escapes: List[List[int]] = []
-    for i, e in enumerate(entries):
-        v = e.get(field)
-        if v is None:
-            continue
-        d = base_seq - int(v)
-        if -_NARROW_MAX <= d <= _NARROW_MAX:
-            col[i] = d
-        else:
-            col[i] = _NARROW_ESCAPE
-            escapes.append([i, int(v)])
-    return col, escapes
+def _narrow_col(delta: np.ndarray, present: np.ndarray,
+                value: np.ndarray):
+    """One int16 column: `delta` where present and inside the int16
+    window, _NARROW_ABSENT where absent, _NARROW_ESCAPE past the window
+    with [index, value] on the escape list (int64 inputs)."""
+    col = np.full(delta.shape[0], _NARROW_ABSENT, np.int16)
+    fits = present & (np.abs(delta) <= _NARROW_MAX)
+    col[fits] = delta[fits]
+    escape = present & ~fits
+    col[escape] = _NARROW_ESCAPE
+    idx = np.flatnonzero(escape)
+    return col, [[i, v] for i, v in zip(idx.tolist(),
+                                        value[idx].tolist())]
 
 
-def _pack_client_col(entries: Sequence[dict], field: str):
-    """One client-index column (values already small ints — table
-    indices): int16 with the same escape discipline."""
-    n = len(entries)
-    col = np.full(n, _NARROW_ABSENT, np.int16)
-    escapes: List[List[int]] = []
-    for i, e in enumerate(entries):
-        v = e.get(field)
-        if v is None:
-            continue
-        v = int(v)
-        if -_NARROW_MAX <= v <= _NARROW_MAX:
-            col[i] = v
-        else:
-            col[i] = _NARROW_ESCAPE
-            escapes.append([i, v])
-    return col, escapes
+def _entry_field(entries: Sequence[dict], field: str):
+    """(present mask, int64 values) of one optional integer field."""
+    vals = [e.get(field) for e in entries]
+    present = np.array([v is not None for v in vals], bool)
+    return present, np.array([0 if v is None else int(v) for v in vals],
+                             np.int64)
+
+
+def _narrow_blob(n: int, base_seq: int, kinds, lens, text: str, seq, rem,
+                 cli, rcl, props, overlap, payloads) -> dict:
+    """The narrow blob itself; each of seq/rem/cli/rcl is a
+    (column, escapes) pair."""
+    return {
+        "v": CATCHUP_WIRE_VERSION,
+        "n": n,
+        "base": int(base_seq),
+        "kinds": _b64_col(kinds),
+        "lens": _b64_col(lens),
+        "text": text,
+        "seq": _b64_col(seq[0]), "seqX": seq[1],
+        "rem": _b64_col(rem[0]), "remX": rem[1],
+        "cli": _b64_col(cli[0]), "cliX": cli[1],
+        "rcl": _b64_col(rcl[0]), "rclX": rcl[1],
+        "props": props,
+        "overlap": overlap,
+        "payloads": payloads,
+    }
 
 
 def pack_entries_narrow(entries: Sequence[dict], base_seq: int) -> dict:
@@ -688,7 +694,8 @@ def pack_entries_narrow(entries: Sequence[dict], base_seq: int) -> dict:
     ValueError — a catch-up artifact never carries another client's
     unacked edits. Text payloads concatenate into one string (sliced
     back by the per-entry length column); non-string payloads (wire-
-    encoded Items/Run dicts) ride an explicit escape list."""
+    encoded Items/Run dicts) ride an explicit escape list. Seq fields
+    ride as int16 deltas against base_seq, client fields as themselves."""
     n = len(entries)
     kinds = np.zeros(n, np.int8)
     lens = np.zeros(n, np.int32)
@@ -717,25 +724,122 @@ def pack_entries_narrow(entries: Sequence[dict], base_seq: int) -> dict:
         if e.get("removedOverlapClients"):
             overlap.append([i, [int(c)
                                 for c in e["removedOverlapClients"]]])
-    seq_col, seq_x = _pack_seq_col(entries, "seq", base_seq)
-    rem_col, rem_x = _pack_seq_col(entries, "removedSeq", base_seq)
-    cli_col, cli_x = _pack_client_col(entries, "client")
-    rcl_col, rcl_x = _pack_client_col(entries, "removedClient")
-    return {
-        "v": CATCHUP_WIRE_VERSION,
-        "n": n,
-        "base": int(base_seq),
-        "kinds": _b64_col(kinds),
-        "lens": _b64_col(lens),
-        "text": "".join(texts),
-        "seq": _b64_col(seq_col), "seqX": seq_x,
-        "rem": _b64_col(rem_col), "remX": rem_x,
-        "cli": _b64_col(cli_col), "cliX": cli_x,
-        "rcl": _b64_col(rcl_col), "rclX": rcl_x,
-        "props": props,
-        "overlap": overlap,
-        "payloads": payload_escapes,
+    cols = []
+    for field, is_seq in (("seq", True), ("removedSeq", True),
+                          ("client", False), ("removedClient", False)):
+        present, value = _entry_field(entries, field)
+        cols.append(_narrow_col(base_seq - value if is_seq else value,
+                                present, value))
+    return _narrow_blob(n, base_seq, kinds, lens, "".join(texts), *cols,
+                        props, overlap, payload_escapes)
+
+
+def _wire_clients(client: np.ndarray, present: np.ndarray,
+                  ordinals: np.ndarray) -> np.ndarray:
+    """Client ordinals -> indices into the document's client table (the
+    position in its sorted `ordinals`); negative ids stay as they are.
+    An ordinal >= 0 with no table entry raises KeyError, as
+    translate_entry_clients does."""
+    value = client.astype(np.int64)
+    mapped = present & (value >= 0)
+    pos = np.searchsorted(ordinals, value)
+    known = pos < ordinals.shape[0]
+    known[known] = ordinals[pos[known]] == value[known]
+    missing = mapped & ~known
+    if missing.any():
+        raise KeyError(int(value[missing][0]))
+    return np.where(mapped, pos, value)
+
+
+def pack_extracted_narrow(packed, payloads: PayloadTable, doc: int,
+                          min_seq: int, seq: int, base_seq: int,
+                          ordinals: np.ndarray,
+                          chunk_chars: int = 10000) -> Tuple[dict, dict]:
+    """One lane's catch-up channel (header, narrow blob) straight from a
+    fetched batched extraction (kernel.fetch_extracted): exactly what
+    host.assemble_snapshot's header and
+    pack_entries_narrow(translate_entry_clients(entries, mapping),
+    base_seq) give for the same lane, without a dict per segment. The
+    seq and client columns come from the extraction's columns by mask;
+    `ordinals` is the document's client ordinals, sorted (an ordinal's
+    wire index is its position there). The one per-row step left is
+    resolving each row's payload and slicing its text; props resolve
+    only for rows whose payload has props or whose annotate ring holds
+    an op id."""
+    (origin_op, origin_off, length, anno, ins_seq, ins_client,
+     rem_seq, rem_client, counts) = packed
+    n = int(counts[doc])
+    ins = ins_seq[doc, :n].astype(np.int64)
+    rem = rem_seq[doc, :n].astype(np.int64)
+    has_ins = ins > min_seq
+    has_rem = rem != DEV_NO_REMOVE
+    cli = _wire_clients(ins_client[doc, :n], has_ins, ordinals)
+    rcl = _wire_clients(rem_client[doc, :n], has_rem, ordinals)
+
+    ops = origin_op[doc, :n].tolist()
+    get = payloads.get
+    lens = [0] * n
+    sizes = [0] * n
+    markers: List[int] = []
+    texts: List[str] = []
+    payload_escapes: List[List[Any]] = []
+    with_props: List[int] = []
+    for i, (op, off, ln) in enumerate(zip(ops, origin_off[doc, :n].tolist(),
+                                          length[doc, :n].tolist())):
+        payload = get(op)
+        if payload.props:
+            with_props.append(i)
+        if payload.kind == SEG_MARKER:
+            markers.append(i)
+            continue
+        text = payload.text
+        if isinstance(text, str):
+            part = text[off:off + ln]
+            texts.append(part)
+            lens[i] = sizes[i] = len(part)
+            continue
+        if isinstance(text, Run):
+            if off + ln > text.length:  # offsets are >= 0: Run's clamp
+                off = min(off, text.length)
+                ln = text.length - off
+            width = ln
+            wire = {"run": [text.base[0], text.base[1], text.start + off,
+                            ln]}
+        else:
+            part = text[off:off + ln]
+            width = len(part)
+            wire = encode_payload(part)
+        lens[i] = -1
+        sizes[i] = width
+        payload_escapes.append([i, wire])
+
+    props: List[List[Any]] = []
+    anno_rows = anno[doc, :n]
+    annotated = np.flatnonzero((anno_rows >= 0).any(axis=-1))
+    for i in np.union1d(np.asarray(with_props, np.int64),
+                        annotated).tolist():
+        resolved = segment_props(get(ops[i]).props, anno_rows[i].tolist(),
+                                 payloads)
+        if resolved:
+            props.append([i, resolved])
+
+    kinds = np.zeros(n, np.int8)
+    kinds[markers] = 1
+    size = np.asarray(sizes, np.int64)
+    visible = np.where(kinds == 1, 1, size)[~has_rem]
+    header = {
+        "sequenceNumber": seq,
+        "minimumSequenceNumber": min_seq,
+        "totalLength": int(visible.sum()),
+        "chunkCount": len(chunk_ends(size, chunk_chars)),
     }
+    blob = _narrow_blob(
+        n, base_seq, kinds, np.asarray(lens, np.int32), "".join(texts),
+        _narrow_col(base_seq - ins, has_ins, ins),
+        _narrow_col(base_seq - rem, has_rem, rem),
+        _narrow_col(cli, has_ins, cli), _narrow_col(rcl, has_rem, rcl),
+        props, [], payload_escapes)
+    return header, blob
 
 
 def unpack_entries_narrow(blob: dict) -> List[dict]:

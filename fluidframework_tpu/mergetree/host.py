@@ -334,6 +334,32 @@ def extract_text(state: DocState, payloads: PayloadTable,
     return "".join(parts)
 
 
+def segment_props(base: Optional[dict], anno_row, payloads: PayloadTable
+                  ) -> dict:
+    """A segment's resolved properties: its insert payload's props, then
+    every annotate op id >= 0 of its `anno` ring applied in seq order
+    (pending annotates after every acked one); a None value clears its
+    key."""
+    props = dict(base) if base else {}
+    chain = []
+    for op_id in anno_row:
+        if op_id < 0:
+            continue
+        ann = payloads.get(op_id)
+        seq = ann.seq
+        if seq == DEV_UNASSIGNED:
+            seq = PENDING_ORDER_BASE + op_id
+        chain.append((seq, ann.props))
+    chain.sort(key=lambda kv: kv[0])
+    for _, pset in chain:
+        for key, value in pset.items():
+            if value is None:
+                props.pop(key, None)
+            else:
+                props[key] = value
+    return props
+
+
 def assemble_entries(packed, payloads: PayloadTable, doc: int,
                      min_seq: int = 0) -> List[dict]:
     """Full-fidelity snapshot entries for one document from the batched
@@ -354,24 +380,7 @@ def assemble_entries(packed, payloads: PayloadTable, doc: int,
         else:
             off = int(origin_off[doc, i])
             entry["text"] = payload.text[off:off + int(length[doc, i])]
-        props = dict(payload.props) if payload.props else {}
-        chain = []
-        for op_id in anno[doc, i]:
-            op_id = int(op_id)
-            if op_id < 0:
-                continue
-            ann = payloads.get(op_id)
-            seq = ann.seq
-            if seq == DEV_UNASSIGNED:
-                seq = PENDING_ORDER_BASE + op_id
-            chain.append((seq, ann.props))
-        chain.sort(key=lambda kv: kv[0])
-        for _, pset in chain:
-            for key, value in pset.items():
-                if value is None:
-                    props.pop(key, None)
-                else:
-                    props[key] = value
+        props = segment_props(payload.props, anno[doc, i].tolist(), payloads)
         if props:
             entry["props"] = props
         if int(ins_seq[doc, i]) > min_seq:
@@ -419,18 +428,32 @@ def chunk_entries(entries: List[dict], chunk_chars: int = 10000
     """Split snapshot entries into body chunks of ~chunk_chars characters
     (reference SnapshotV1 header + 10k-char chunks, snapshotV1.ts:33-40)."""
     chunks: List[List[dict]] = []
-    cur: List[dict] = []
-    size = 0
-    for e in entries:
-        cur.append(e)
-        size += max(1, len(e.get("text") or ""))
-        if size >= chunk_chars:
-            chunks.append(cur)
-            cur = []
-            size = 0
-    if cur or not chunks:
-        chunks.append(cur)
+    start = 0
+    for end in chunk_ends([len(e.get("text") or "") for e in entries],
+                          chunk_chars):
+        chunks.append(entries[start:end])
+        start = end
     return chunks
+
+
+def chunk_ends(sizes, chunk_chars: int = 10000) -> List[int]:
+    """Where chunk_entries cuts entries of these text sizes: the end
+    (exclusive) of each chunk. Each entry weighs max(1, size), a chunk
+    closes once its weight reaches chunk_chars, and there is at least
+    one chunk."""
+    weight = np.cumsum(np.maximum(np.asarray(sizes, np.int64), 1))
+    n = weight.shape[0]
+    ends: List[int] = []
+    reached = 0
+    while True:
+        j = int(np.searchsorted(weight, reached + max(chunk_chars, 1)))
+        if j >= n:
+            break
+        ends.append(j + 1)
+        reached = int(weight[j])
+    if not ends or ends[-1] < n:
+        ends.append(n)
+    return ends
 
 
 def flatten_snapshot_content(snap: dict) -> List[Tuple[str, tuple]]:

@@ -66,22 +66,30 @@ def id_key(stable_id: Tuple[int, int, int]) -> str:
     return f"{stable_id[0]}.{stable_id[1]}.{stable_id[2]}"
 
 
-def encode_entry_payloads(entries: List[dict]) -> List[dict]:
-    """JSON-safe copies of snapshot entries: Run payloads become
+def encode_payload(value):
+    """A segment payload's JSON-safe wire form: a Run becomes
     {"run": [nonce, counter, start, length]} (PermutationVector.snapshot
-    wire form) and Items payloads {"items": [...]} (sharedSequence
-    SubSequence wire form). Plain text passes through unchanged."""
+    wire form), Items {"items": [...]} (sharedSequence SubSequence wire
+    form); anything else (plain text) is returned unchanged."""
     from .oracle import Items
 
+    if isinstance(value, Run):
+        return {"run": value.encode()}
+    if isinstance(value, Items):
+        return {"items": value.encode()}
+    return value
+
+
+def encode_entry_payloads(entries: List[dict]) -> List[dict]:
+    """JSON-safe copies of snapshot entries (encode_payload on each
+    entry's text, copying only the entries it changes)."""
     out = []
     for e in entries:
         text = e.get("text")
-        if isinstance(text, Run):
+        wire = encode_payload(text)
+        if wire is not text:
             e = dict(e)
-            e["text"] = {"run": text.encode()}
-        elif isinstance(text, Items):
-            e = dict(e)
-            e["text"] = {"items": text.encode()}
+            e["text"] = wire
         out.append(e)
     return out
 

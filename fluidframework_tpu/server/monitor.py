@@ -225,7 +225,8 @@ class ServiceMonitor:
 
     def watch_summaries(self, name: str, merge_store) -> None:
         """Probe over a MergeLaneStore's incremental-summarization state:
-        dirty lane count (channels past their summarize epoch), cached
+        dirty lane count (channels past their summarize epoch, which only
+        a summarize advances: a catch-up refresh leaves it), cached
         blob count, and the summarize.* process counters rolled into a
         blob-cache hit rate — the health-report view of the dirty-epoch
         extraction path."""

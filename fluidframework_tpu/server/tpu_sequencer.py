@@ -864,7 +864,8 @@ class MergeLaneStore:
 
     # -- batched summary extraction ----------------------------------------
     def extract_dispatch(self, only: Optional[set] = None,
-                         chunk_chars: int = 10000) -> tuple:
+                         chunk_chars: int = 10000,
+                         blob_cache: bool = True) -> tuple:
         """Phase 1 (device, async): dirty lanes group by their pow2 page
         bucket and each group runs ONE fused zamboni+extract over
         gathered page views (kernel.compact_extract_paged — compaction
@@ -879,7 +880,9 @@ class MergeLaneStore:
         extraction entirely and return their previous blobs via the
         second element, so extraction compute AND the D2H transfer scale
         with the dirty count, not the fleet size. `only` further
-        restricts the keys considered. Counts adopt synchronously — the
+        restricts the keys considered; `blob_cache=False` extracts every
+        one of them (the read tier's refresh, which packs straight from
+        the columns). Counts adopt synchronously — the
         host scalar mirrors are authoritative and the next apply's page
         pre-growth proof reads them — then trailing pages release: a
         summarize pass IS these lanes' zamboni. Returns (jobs,
@@ -895,7 +898,7 @@ class MergeLaneStore:
                 continue
             if only is not None and key not in only:
                 continue
-            hit = snap_view.get(key)
+            hit = snap_view.get(key) if blob_cache else None
             if hit is not None and hit[0] == self.change_gen.get(key, 0) \
                     and hit[1] == chunk_chars:
                 cached[key] = hit[2]
@@ -1000,6 +1003,23 @@ class MergeLaneStore:
                         self.last_summarized_gen.get(key, 0), gens[key])
             increment("summarize.dirty_docs", len(lanes))
             increment("summarize.blob_cache.misses", len(lanes))
+        return out
+
+    def extract_fetch(self, jobs: List[tuple]) -> Dict[tuple, tuple]:
+        """The read tier's phase 2 (host): D2H transfer of each job's
+        extraction, returning every dispatched lane's
+        (fetched columns, row in them, seq, min_seq) for
+        catchup.pack_extracted_narrow. No snapshot dict is built, so
+        neither the blob cache nor the summarize epoch moves: a later
+        summarize extracts these lanes for itself."""
+        out: Dict[tuple, tuple] = {}
+        for packed, lanes, seq_dev, min_seq_dev, _gens in jobs:
+            packed = kernel.fetch_extracted(packed)
+            seqs = np.asarray(seq_dev)
+            min_seqs = np.asarray(min_seq_dev)
+            for lane, key in lanes:
+                out[key] = (packed, lane, int(seqs[lane]),
+                            int(min_seqs[lane]))
         return out
 
     def extract_all(self, chunk_chars: int = 10000,
@@ -4826,13 +4846,14 @@ class TpuSequencerLambda(IPartitionLambda):
         """One read-tier refresh epoch: extract every supported document
         whose change generations advanced past its published artifact —
         ONE batched device dispatch per page group for
-        ALL of them together (extract_dispatch; clean lanes ride the
-        summarize blob cache; matrix axes are merge lanes and ride the
-        same dispatch) — and return the per-doc artifact bodies
-        {doc_id: {"seq", "gen", "clients", "channels"}}. Channel entries
-        are narrow-wire packed (mergetree.catchup.pack_entries_narrow)
-        with client fields translated from this lambda's interned
-        ordinals to indices into the per-doc wire-client table. Server
+        ALL of them together (extract_dispatch, every lane of a dirty
+        document; matrix axes are merge lanes and ride the same
+        dispatch) — and return the per-doc artifact bodies
+        {doc_id: {"seq", "gen", "clients", "channels"}}. Channels ride
+        the narrow wire (mergetree.catchup), built straight from the
+        extraction's columns (pack_extracted_narrow) with client fields
+        translated from this lambda's interned ordinals to indices into
+        the per-doc wire-client table. Server
         cost is proportional to DIRTY documents, never to connecting
         clients; the caller (TpuLocalServer.refresh_catchup / an
         external publisher) joins in the protocol half and publishes."""
@@ -4865,41 +4886,43 @@ class TpuSequencerLambda(IPartitionLambda):
         want = {k for d in refresh for k in by_doc[d]
                 if k in self.merge.where}
         with tracing.span("catchup.extract", docs=len(refresh)) as sp:
-            jobs, cached = self.merge.extract_dispatch(
-                only=want, chunk_chars=chunk_chars)
+            jobs, _ = self.merge.extract_dispatch(
+                only=want, chunk_chars=chunk_chars, blob_cache=False)
             increment("catchup.refresh_dispatches", len(jobs))
-            snaps = self.merge.extract_assemble(jobs, chunk_chars, cached)
+            lanes = self.merge.extract_fetch(jobs)
             sp.set(dispatches=len(jobs))
         with tracing.span("catchup.pack"):
-            return self._catchup_pack(refresh, by_doc, snaps)
+            return self._catchup_pack(refresh, by_doc, lanes, chunk_chars)
 
     def _catchup_pack(self, refresh: Dict[str, int],
                       by_doc: Dict[str, List[tuple]],
-                      snaps: Dict[tuple, dict]) -> Dict[str, dict]:
-        """Per refreshed document: client fields translated to wire-table
-        indices and every channel's entries narrow-packed. A matrix is one
-        channel: its axes narrow-packed (Run payloads ride the packer's
-        payload list) beside its cell map, under a ``matrix`` header."""
-        from ..mergetree.catchup import (pack_entries_narrow,
-                                         translate_entry_clients)
+                      lanes: Dict[tuple, tuple],
+                      chunk_chars: int) -> Dict[str, dict]:
+        """Per refreshed document: its wire-client table and every
+        channel's narrow blob, packed from the lane's fetched columns
+        (pack_extracted_narrow). A matrix is one channel: its axes
+        packed beside its cell map, under a ``matrix`` header."""
+        from ..mergetree.catchup import pack_extracted_narrow
 
         out: Dict[str, dict] = {}
         for doc_id, doc_gen in refresh.items():
             dl = self.docs[doc_id]
+            doc_seq = self.document_seq(doc_id)
+            ordinals = np.array(sorted(dl.ordinals), np.int64)
             # Ordinal -> client-table index, via the wire ids this lane
-            # interned; entries fail the translation (KeyError) only for
+            # interned; a lane fails the translation (KeyError) only for
             # ordinal spaces the publisher cannot disambiguate, which
             # excludes the doc this epoch (fallback stays correct).
-            clients = [dl.ordinals[o] for o in sorted(dl.ordinals)]
-            mapping = {o: i for i, o in enumerate(sorted(dl.ordinals))}
-            doc_seq = self.document_seq(doc_id)
+            clients = [dl.ordinals[o] for o in ordinals.tolist()]
 
             def pack(key):
-                snap = snaps[key]
-                entries = [e for chunk in snap["chunks"] for e in chunk]
-                return dict(snap["header"]), pack_entries_narrow(
-                    translate_entry_clients(entries, mapping),
-                    base_seq=doc_seq)
+                packed, lane, seq, min_seq = lanes[key]
+                channel = pack_extracted_narrow(
+                    packed, self.merge.payloads, lane, min_seq=min_seq,
+                    seq=seq, base_seq=doc_seq, ordinals=ordinals,
+                    chunk_chars=chunk_chars)
+                increment("catchup.columnar_lanes")
+                return channel
 
             channels: List[list] = []
             try:
